@@ -376,74 +376,82 @@ def _ladder_sweep(params, smoke):
     return rows
 
 
-# Runs inside a subprocess: the parent bench process keeps its default
-# single-device view, while the sweep sees 8 host devices (same pattern as
-# tests/serving/test_sharded.py).  The meshless PagedServeEngine run is the
-# token-parity reference; every mesh row reports whether the 2D data x model
-# composition reproduced it token-for-token (the gather-based-TP contract),
-# plus per-device pool bytes — the column that shrinks as the model axis
-# cuts the kv-head-sharded pool.
-_SHARDED_SWEEP_CODE = """
-import dataclasses, json
-import jax
-import numpy as np
-from benchmarks.bench_serving import (SCFG, SERVE_CFG, _drive,
-                                      _shared_prefix_requests)
-from repro.models import init_params
-from repro.serving.engine import PagedServeEngine
-from repro.serving.replica import ReplicaConfig, ReplicatedServeEngine
+# Mesh shapes of the sharded sweep, (data, model).  The meshless
+# PagedServeEngine run is the token-parity reference; every mesh row reports
+# whether the 2D data x model composition reproduced it token-for-token (the
+# gather-based-TP contract), plus per-device pool bytes — the column that
+# shrinks as the model axis cuts the kv-head-sharded pool.
+SHARDED_MESHES = [(1, 1), (2, 1), (1, 2), (2, 2)]
 
-scfg = dataclasses.replace(SCFG, num_blocks=48)
-params = init_params(SERVE_CFG, jax.random.PRNGKey(0))
 
-def traffic():
-    return _shared_prefix_requests(np.random.default_rng(29), N_REQ, MAX_NEW_T,
-                                   prefix_len=32, groups=2)
+def _sharded_rows(n_req, max_new):
+    """The sweep over the meshes that fit this process's devices."""
+    from repro.launch.mesh import make_mesh
+    from repro.serving.replica import ReplicaConfig, ReplicatedServeEngine
+    scfg = dataclasses.replace(SCFG, num_blocks=48)
+    params = init_params(SERVE_CFG, jax.random.PRNGKey(0))
 
-def outputs(eng):
-    return {int(r.uid): [int(t) for t in r.generated] for r in eng.finished}
+    def traffic():
+        return _shared_prefix_requests(np.random.default_rng(29), n_req,
+                                       max_new, prefix_len=32, groups=2)
 
-ref = PagedServeEngine(params, SERVE_CFG, scfg)
-_drive(ref, traffic(), 4.0)
-want = outputs(ref)
+    def outputs(eng):
+        return {int(r.uid): [int(t) for t in r.generated]
+                for r in eng.finished}
 
-for d, m in [(1, 1), (2, 1), (1, 2), (2, 2)]:
-    mesh = jax.make_mesh((d, m), ("data", "model"))
-    eng = ReplicatedServeEngine(
-        params, SERVE_CFG, scfg,
-        ReplicaConfig(n_replicas=d, policy="round_robin"), mesh=mesh)
-    wall = _drive(eng, traffic(), 4.0)
-    mt = eng.metrics()
-    per = mt["per_replica"]
-    print(json.dumps({
-        "point": "mesh_%dx%d" % (d, m),
-        "data_shards": d,
-        "model_shards": m,
-        "tokens_per_s": round(mt["tokens_per_s"], 2),
-        "cache_bytes": sum(p["cache_nbytes"] for p in per),
-        "cache_bytes_per_device": max(p["cache_nbytes_per_device"]
-                                      for p in per),
-        "tokens_match": outputs(eng) == want,
-        "wall_s": round(wall, 2),
-    }))
-"""
+    ref = PagedServeEngine(params, SERVE_CFG, scfg)
+    _drive(ref, traffic(), 4.0)
+    want = outputs(ref)
+    rows = []
+    for d, m in SHARDED_MESHES:
+        if d * m > len(jax.devices()):
+            continue
+        eng = ReplicatedServeEngine(
+            params, SERVE_CFG, scfg,
+            ReplicaConfig(n_replicas=d, policy="round_robin"),
+            mesh=make_mesh((d, m), ("data", "model")))
+        wall = _drive(eng, traffic(), 4.0)
+        mt = eng.metrics()
+        per = mt["per_replica"]
+        rows.append({
+            "point": "mesh_%dx%d" % (d, m),
+            "data_shards": d,
+            "model_shards": m,
+            "tokens_per_s": round(mt["tokens_per_s"], 2),
+            "cache_bytes": sum(p["cache_nbytes"] for p in per),
+            "cache_bytes_per_device": max(p["cache_nbytes_per_device"]
+                                          for p in per),
+            "tokens_match": outputs(eng) == want,
+            "wall_s": round(wall, 2),
+        })
+    return rows
 
 
 def _sharded_sweep(smoke):
     """2D ``data x model`` mesh-shape sweep {1x1, 2x1, 1x2, 2x2}: tokens/s,
     per-device pool bytes, and token parity against the unsharded engine —
-    the serving counterpart of the distributed train benches."""
+    the serving counterpart of the distributed train benches.
+
+    On an accelerator the sweep runs in this process over its devices (a
+    child could not reach a chip this process holds).  On the CPU it runs
+    in a child that sees 8 host devices, so this process keeps its default
+    single-device view (same pattern as tests/serving/test_sharded.py)."""
     import json
     import os
     import subprocess
     import sys
     n = 6 if smoke else N_REQUESTS
     max_new = 4 if smoke else MAX_NEW
+    if jax.default_backend() != "cpu":
+        return _sharded_rows(n, max_new)
     env = dict(os.environ)
     env.update({"PYTHONPATH": "src",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
                 "JAX_PLATFORMS": "cpu"})
-    code = f"N_REQ, MAX_NEW_T = {n}, {max_new}\n" + _SHARDED_SWEEP_CODE
+    code = ("import json\n"
+            "from benchmarks.bench_serving import _sharded_rows\n"
+            f"for row in _sharded_rows({n}, {max_new}):\n"
+            "    print(json.dumps(row))\n")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=1800, env=env)
     if r.returncode != 0:

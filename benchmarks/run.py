@@ -13,6 +13,10 @@ import sys
 import time
 import traceback
 
+import jax
+
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = [
     ("perplexity_table1_4", "benchmarks.bench_perplexity"),
     ("throughput_table2", "benchmarks.bench_throughput"),
@@ -198,10 +202,12 @@ def obs_overhead_gate(path: str = "experiments/bench/serving_obs.csv",
 
 
 def pallas_interpret_gate():
-    """Smoke-mode gate: re-run the paged kernel parity subset with
-    REPRO_FORCE_PALLAS=1 (pallas kernels in interpret mode on a CPU host),
-    so the bench loop exercises the real kernel bodies — not just the jnp
-    oracles the default CPU path falls back to."""
+    """Smoke-mode gate on a CPU host: re-run the paged kernel parity subset
+    with REPRO_FORCE_PALLAS=1 (pallas kernels in interpret mode), so the
+    bench loop exercises the real kernel bodies — not just the jnp oracles
+    the default CPU path falls back to.  It runs pytest in a child process,
+    which on an accelerator host could not reach the chip this process
+    holds; there the benches already run the compiled kernels."""
     import os
     import subprocess
     env = dict(os.environ)
@@ -223,6 +229,7 @@ def main() -> None:
                    help="pass smoke=True to benches that support it "
                         "(tiny workloads, tier-1-loop friendly)")
     args = p.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0
@@ -285,7 +292,7 @@ def main() -> None:
         if err:
             failures += 1
             print(err, file=sys.stderr)
-    if args.smoke and "kernels" in ran:
+    if args.smoke and "kernels" in ran and jax.default_backend() == "cpu":
         err = pallas_interpret_gate()
         if err:
             failures += 1

@@ -17,6 +17,7 @@ from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLM
 from repro.distributed import axis_rules, plan_remesh, build_mesh
 from repro.distributed.sharding import param_spec
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import ModelConfig, init_params
 from repro.models.config import LayerSpec
@@ -52,7 +53,7 @@ def main():
     mgr = CheckpointManager(CKPT, keep=2)
 
     print("[phase 1] mesh (4 data x 2 model) — 8 chips")
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = make_mesh((4, 2), ("data", "model"))
     params = init_params(cfg, jax.random.PRNGKey(0))
     params = jax.device_put(params, shardings_for(mesh1, params))
     opt = init_state(params, ocfg)

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import QuantPolicy, quantize_tree, tree_nbytes
 from repro.core.methods.smoothquant import apply_fold_to_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.steps import make_train_step
 from repro.models import ModelConfig, forward_train, init_params
@@ -55,6 +56,7 @@ def main():
                          "+ multiple-choice tasks through the same engine "
                          "(scoring mode) and print the quality scorecard")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.dense and args.score:
         ap.error("--score needs the paged engine (drop --dense)")
     if args.dense and args.replicas > 1:

@@ -22,7 +22,7 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.qtensor import absmax_scale, int_range
@@ -68,7 +68,7 @@ def make_int8_allreduce(mesh: Mesh, axis: str = "data"):
     shard; the sum happens post-dequant in fp32.
     """
     @partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-             check_rep=False)
+             check_vma=False)
     def allreduce(g_local):
         q, scale = _quantize_leaf(g_local)
         qs = jax.lax.all_gather(q, axis)                 # (P, ...) int8 on wire

@@ -22,6 +22,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.launch.mesh import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class RemeshPlan:
@@ -73,5 +75,4 @@ def plan_remesh(n_available: int, *, old_data: int, old_model: int,
 def build_mesh(plan: RemeshPlan, devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     n = int(np.prod(plan.shape))
-    dev = np.asarray(devices[:n]).reshape(plan.shape)
-    return Mesh(dev, plan.axis_names)
+    return make_mesh(plan.shape, plan.axis_names, devices=devices[:n])

@@ -19,7 +19,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.online import EmaScaleState
@@ -81,7 +81,7 @@ def reduce_ema_states(states: Sequence[EmaScaleState], *,
         m = jax.device_put(m, NamedSharding(mesh, P(axis)))
 
         @partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
-                 out_specs=(P(), P()), check_rep=False)
+                 out_specs=(P(), P()), check_vma=False)
         def _reduce(dl, ml):
             return jax.lax.pmax(dl[0], axis), jax.lax.pmean(ml[0], axis)
 
@@ -107,7 +107,7 @@ def make_synced_quant_step(mesh: Mesh, *, alpha: float = 0.9, bits: int = 8,
     out_spec = (P(axes), P())
 
     @partial(shard_map, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-             check_rep=False)
+             check_vma=False)
     def step(x, state):
         reduce_fn = lambda s: jax.lax.pmax(s, axes)
         q, new_state = async_quant_update(x, state, alpha=alpha, bits=bits,

@@ -62,12 +62,13 @@ def batch_nll(logits, labels) -> float:
 _DENSE_FNS: Dict[ModelConfig, any] = {}
 
 
-def _dense_logits_fn(cfg: ModelConfig):
+def dense_logits(params, cfg: ModelConfig, tokens) -> jax.Array:
+    """(B, S) tokens -> (B, S, V) logits of one dense ``forward_train``."""
     fn = _DENSE_FNS.get(cfg)
     if fn is None:
         fn = jax.jit(lambda p, t: forward_train(p, t, cfg)[0])
         _DENSE_FNS[cfg] = fn
-    return fn
+    return fn(params, tokens)
 
 
 def dense_sequence_logprobs(params, cfg: ModelConfig, target,
@@ -83,7 +84,7 @@ def dense_sequence_logprobs(params, cfg: ModelConfig, target,
     s = int(t.shape[-1])
     if not 1 <= score_from < s:
         raise ValueError(f"score_from={score_from} outside [1, {s})")
-    logits = _dense_logits_fn(cfg)(params, jnp.asarray(t)[None])
+    logits = dense_logits(params, cfg, jnp.asarray(t)[None])
     rows = logits[0, score_from - 1:s - 1]
     return gold_logprobs(rows, t[score_from:])
 

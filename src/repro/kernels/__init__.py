@@ -10,9 +10,9 @@ from . import ops, ref
 from .fused_quant import fused_quant
 from .w8a8_matmul import w8a8_matmul
 from .kv_decode_attention import kv_decode_attention
-from .ops import qdot, decode_attention, quantize_rowwise
+from .ops import qdot, decode_attention
 
 __all__ = [
     "ops", "ref", "fused_quant", "w8a8_matmul", "kv_decode_attention",
-    "qdot", "decode_attention", "quantize_rowwise",
+    "qdot", "decode_attention",
 ]

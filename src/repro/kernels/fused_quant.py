@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .paged_attention import VMEM_LIMIT_BYTES
+
 
 def _kernel(x_ref, q_ref, s_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                    # (bm, K)
@@ -25,17 +27,31 @@ def _kernel(x_ref, q_ref, s_ref, *, eps: float):
     s_ref[...] = scale
 
 
+def _block_rows(m: int, k: int, itemsize: int, block_m: int) -> int:
+    """Rows per grid step so that the (bm, K) working set fits VMEM.
+
+    Per element: the double-buffered input (2 * itemsize) and int8 output
+    (2 * 1) blocks plus ~8 bytes of f32 temporaries.  A power of two (so a
+    multiple of the 8-row tile) from 8 up to ``block_m``, or all of ``m``
+    when that is smaller.  On a v5e this keeps f32 K=4864 at 128 rows, where
+    256 rows exhaust VMEM."""
+    fit = VMEM_LIMIT_BYTES // (k * (2 * itemsize + 2 + 8))
+    bm = 8
+    while bm * 2 <= min(block_m, fit):
+        bm *= 2
+    return m if m <= bm else bm
+
+
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def fused_quant(x: jax.Array, *, block_m: int = 256, eps: float = 1e-8,
                 interpret: bool = False):
     """x: (M, K) -> (q int8 (M, K), scale f32 (M, 1)).
 
-    block_m rows per grid step; K kept whole in VMEM (K*block_m*4B must fit —
-    for K=8192, bm=256 that is 8 MiB fp32 working set, within v5e VMEM after
-    double-buffering at bm=128; callers shrink bm for very wide K).
+    At most ``block_m`` rows per grid step, fewer where K is wide: K stays
+    whole in VMEM (see ``_block_rows``).
     """
     m, k = x.shape
-    bm = min(block_m, m)
+    bm = _block_rows(m, k, x.dtype.itemsize, block_m)
     grid = (-(-m // bm),)
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),

@@ -41,6 +41,7 @@ def _codes_f32(raw, bits: int):
 def _kernel(len_ref, q_ref, ks_ref, kz_ref, k_ref, v_ref, vs_ref, vz_ref,
             o_ref, m_ref, l_ref, acc_ref, *, n_chunks: int, chunk: int,
             scale: float):
+    b_idx = pl.program_id(0)
     s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
@@ -55,7 +56,7 @@ def _kernel(len_ref, q_ref, ks_ref, kz_ref, k_ref, v_ref, vs_ref, vz_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, C)
 
-    length = len_ref[0]
+    length = len_ref[b_idx]
     pos = s_idx * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < length, s, NEG_INF)
 
@@ -112,24 +113,27 @@ def kv_decode_attention(q: jax.Array,
 
     kernel = functools.partial(_kernel, n_chunks=n_chunks, chunk=chunk,
                                scale=1.0 / (d ** 0.5))
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # length (B,) -> SMEM
         grid=(b, kh, n_chunks),
         in_specs=[
-            pl.BlockSpec((1,), lambda bb, hh, ss: (bb,)),                       # length
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss: (bb, hh, 0, 0)),      # q
-            pl.BlockSpec((1, 1, 1, d), lambda bb, hh, ss: (bb, hh, 0, 0)),      # ks
-            pl.BlockSpec((1, 1, 1, d), lambda bb, hh, ss: (bb, hh, 0, 0)),      # kz
-            pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, ss: (bb, hh, ss, 0)), # k
-            pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, ss: (bb, hh, ss, 0)), # v
-            pl.BlockSpec((1, 1, chunk, 1), lambda bb, hh, ss: (bb, hh, ss, 0)), # vs
-            pl.BlockSpec((1, 1, chunk, 1), lambda bb, hh, ss: (bb, hh, ss, 0)), # vz
+            pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss, ln: (bb, hh, 0, 0)),      # q
+            pl.BlockSpec((1, 1, 1, d), lambda bb, hh, ss, ln: (bb, hh, 0, 0)),      # ks
+            pl.BlockSpec((1, 1, 1, d), lambda bb, hh, ss, ln: (bb, hh, 0, 0)),      # kz
+            pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, ss, ln: (bb, hh, ss, 0)), # k
+            pl.BlockSpec((1, 1, chunk, d), lambda bb, hh, ss, ln: (bb, hh, ss, 0)), # v
+            pl.BlockSpec((1, 1, chunk, 1), lambda bb, hh, ss, ln: (bb, hh, ss, 0)), # vs
+            pl.BlockSpec((1, 1, chunk, 1), lambda bb, hh, ss, ln: (bb, hh, ss, 0)), # vz
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss: (bb, hh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss, ln: (bb, hh, 0, 0)),
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, d), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), jnp.float32),
         interpret=interpret,
     )(length, q_r, ks_r, kz_r, k_r, v_r, vs_r, vz_r)
     return out.reshape(b, h, d)

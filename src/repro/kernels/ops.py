@@ -11,6 +11,11 @@ core.quantize_tree; dispatch covers
 
 Pallas execution is enabled when running on real TPU (or forced with
 REPRO_FORCE_PALLAS=1, interpret mode — used by integration tests).
+
+Under a multi-device mesh every kernel launch runs inside ``shard_map``: the
+TPU compiler cannot partition a Mosaic kernel on its own.  GQA attention
+splits over kv heads and the W8A8 GEMM over its output columns where the
+tensor-parallel axis divides them; any other launch runs replicated.
 """
 from __future__ import annotations
 
@@ -21,7 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.qtensor import QTensor
@@ -41,46 +46,57 @@ def _use_pallas() -> Optional[dict]:
     return None
 
 
-def _tp_plan(kh: int, h: int):
-    """Tensor-parallel routing plan for the GQA paged kernels.
+def _split_axis(logical: str, *dims: int):
+    """Mesh axis for splitting a kernel launch along ``logical`` when the
+    active rules bind it to live mesh axes of product ``n > 1`` and every
+    dim in ``dims`` divides by ``n``; else None.
 
-    When serving rules bind ``kv_heads`` to live mesh axes with product
-    ``n > 1`` and both head counts divide, each device should run the paged
-    kernel over *its own* head slice — attention is head-local, and the q
-    head block [i*H/n, (i+1)*H/n) attends exactly kv heads
+    For the GQA paged kernels (``kv_heads``): attention is head-local, and
+    the q head block [i*H/n, (i+1)*H/n) attends exactly kv heads
     [i*KH/n, (i+1)*KH/n) (heads are grouped kv-major), so the per-shard
-    launches compute the same floats as one wide launch.  Returns
-    ``(mesh, axis)`` or None (unsharded / oracle / non-divisible — the
-    caller falls back to the shard-oblivious single launch)."""
+    launches compute the same floats as one wide launch."""
     from repro.distributed import sharding as shd
     mesh = shd.active_mesh()
     if mesh is None:
         return None
-    axes = shd.resolve("kv_heads")
-    if not axes:
+    axes = shd.resolve(logical)
+    n = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    if n <= 1 or any(d % n for d in dims):
         return None
-    n = int(np.prod([mesh.shape[a] for a in axes]))
-    if n <= 1 or kh % n or h % n:
-        return None
-    return mesh, (axes[0] if len(axes) == 1 else tuple(axes))
+    return axes[0] if len(axes) == 1 else tuple(axes)
 
 
-def quantize_rowwise(x2d: jax.Array):
-    """(M, K) -> (int8 codes, (M,1) scales); Pallas on TPU."""
-    pk = _use_pallas()
-    if pk is not None:
-        return fused_quant(x2d, **pk)
-    return ref.fused_quant_ref(x2d)
+def _on_mesh(fn, in_specs, out_specs):
+    """``fn`` under ``shard_map`` over the active mesh, or ``fn`` itself
+    when no multi-device mesh is bound.  ``in_specs=None`` runs the launch
+    replicated: every operand whole on every device."""
+    from repro.distributed import sharding as shd
+    mesh = shd.active_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    if in_specs is None:
+        in_specs, out_specs = P(), P()
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def _w8a8(x2d: jax.Array, qw: QTensor, out_dtype):
-    q_x, x_scale = quantize_rowwise(x2d)
     w_scale = qw.scale.reshape(1, -1)
     pk = _use_pallas()
-    if pk is not None:
-        return w8a8_matmul(q_x, x_scale, qw.values, w_scale,
-                           out_dtype=out_dtype, **pk)
-    return ref.w8a8_matmul_ref(q_x, x_scale, qw.values, w_scale, out_dtype)
+    if pk is None:
+        q_x, x_scale = ref.fused_quant_ref(x2d)
+        return ref.w8a8_matmul_ref(q_x, x_scale, qw.values, w_scale,
+                                   out_dtype)
+
+    def launch(x, w, ws):
+        q_x, x_scale = fused_quant(x, **pk)
+        return w8a8_matmul(q_x, x_scale, w, ws, out_dtype=out_dtype, **pk)
+    # each device quantizes the whole activation and multiplies its slice of
+    # the output columns: full contraction locally, so the columns are the
+    # floats of one wide launch
+    ax = _split_axis("ffn", qw.values.shape[1])
+    specs = None if ax is None else (P(), P(None, ax), P(None, ax))
+    return _on_mesh(launch, specs, P(None, ax))(x2d, qw.values, w_scale)
 
 
 def qdot(x: jax.Array, w, out_dtype=None) -> jax.Array:
@@ -117,9 +133,9 @@ def decode_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
     """
     pk = _use_pallas()
     if pk is not None:
-        return kv_decode_attention(q, k_vals, k_scale, k_zero,
-                                   v_vals, v_scale, v_zero, length,
-                                   chunk=chunk, **pk)
+        fn = partial(kv_decode_attention, chunk=chunk, **pk)
+        return _on_mesh(fn, None, None)(q, k_vals, k_scale, k_zero, v_vals,
+                                        v_scale, v_zero, length)
     if os.environ.get("REPRO_FLASH_DECODE") == "1":
         return flash_decode_ref(q, k_vals, k_scale, k_zero,
                                 v_vals, v_scale, v_zero, length, chunk=2048)
@@ -134,22 +150,18 @@ def paged_decode_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
     dense engine's oracle — golden-parity contract)."""
     pk = _use_pallas()
     if pk is not None:
-        fn = partial(paged_kv_decode_attention, **pk)
-        tp = _tp_plan(k_vals.shape[-2], q.shape[-2])
-        if tp is not None:
-            mesh, ax = tp
-            fn = shard_map(
-                fn, mesh=mesh,
-                in_specs=(P(None, ax, None),        # q (B,H,D)
-                          P(None, None, ax, None),  # k_vals (N,T,KH,D)
-                          P(None, ax, None),        # k_scale (B,KH,D)
-                          P(None, ax, None),        # k_zero
-                          P(None, None, ax, None),  # v_vals
-                          P(None, None, ax, None),  # v_scale (N,T,KH,1)
-                          P(None, None, ax, None),  # v_zero
-                          P(None, None), P(None)),  # block_tables, lengths
-                out_specs=P(None, ax, None),
-                check_rep=False)
+        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        specs = None if ax is None else (
+            P(None, ax, None),          # q (B,H,D)
+            P(None, None, ax, None),    # k_vals (N,T,KH,D)
+            P(None, ax, None),          # k_scale (B,KH,D)
+            P(None, ax, None),          # k_zero
+            P(None, None, ax, None),    # v_vals
+            P(None, None, ax, None),    # v_scale (N,T,KH,1)
+            P(None, None, ax, None),    # v_zero
+            P(None, None), P(None))     # block_tables, lengths
+        fn = _on_mesh(partial(paged_kv_decode_attention, **pk), specs,
+                      P(None, ax, None))
         return fn(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
                   block_tables, lengths)
     return ref.paged_kv_decode_attention_ref(q, k_vals, k_scale, k_zero,
@@ -165,22 +177,18 @@ def paged_verify_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
     the greedy spec-decode golden contract.  q: (B,G,H,D) -> (B,G,H,D)."""
     pk = _use_pallas()
     if pk is not None:
-        fn = partial(pa.paged_kv_verify_attention, **pk)
-        tp = _tp_plan(k_vals.shape[-2], q.shape[-2])
-        if tp is not None:
-            mesh, ax = tp
-            fn = shard_map(
-                fn, mesh=mesh,
-                in_specs=(P(None, None, ax, None),  # q (B,G,H,D)
-                          P(None, None, ax, None),  # k_vals
-                          P(None, ax, None),        # k_scale (B,KH,D)
-                          P(None, ax, None),        # k_zero
-                          P(None, None, ax, None),  # v_vals
-                          P(None, None, ax, None),  # v_scale
-                          P(None, None, ax, None),  # v_zero
-                          P(None, None), P(None)),  # block_tables, lengths
-                out_specs=P(None, None, ax, None),
-                check_rep=False)
+        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        specs = None if ax is None else (
+            P(None, None, ax, None),    # q (B,G,H,D)
+            P(None, None, ax, None),    # k_vals
+            P(None, ax, None),          # k_scale (B,KH,D)
+            P(None, ax, None),          # k_zero
+            P(None, None, ax, None),    # v_vals
+            P(None, None, ax, None),    # v_scale
+            P(None, None, ax, None),    # v_zero
+            P(None, None), P(None))     # block_tables, lengths
+        fn = _on_mesh(partial(pa.paged_kv_verify_attention, **pk), specs,
+                      P(None, None, ax, None))
         return fn(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
                   block_tables, lengths)
     return ref.paged_kv_verify_attention_ref(q, k_vals, k_scale, k_zero,
@@ -202,10 +210,11 @@ def mla_paged_verify_attention(q_nope, q_rope, w_uk, w_uv, c_vals, c_scale,
         q_lat = jnp.stack(
             [jnp.einsum("bhd,rhd->bhr", q_nope[:, j].astype(f32),
                         w_uk.astype(f32)) for j in range(g)], axis=1)
-        o_lat = pa.mla_paged_verify_attention(
+        fn = partial(pa.mla_paged_verify_attention,
+                     qk_nope_dim=q_nope.shape[-1], **pk)
+        o_lat = _on_mesh(fn, None, None)(
             q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, block_tables, lengths, qk_nope_dim=q_nope.shape[-1],
-            **pk)
+            kr_zero, block_tables, lengths)
         return jnp.stack(
             [jnp.einsum("bhr,rhd->bhd", o_lat[:, j], w_uv.astype(f32))
              for j in range(g)], axis=1)
@@ -222,24 +231,20 @@ def paged_prefix_chunk_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
     oracle elsewhere).  q: (1,C,H,D) -> (1,C,H,D) f32."""
     pk = _use_pallas()
     if pk is not None:
-        fn = partial(pa.paged_prefix_chunk_attention, **pk)
-        tp = _tp_plan(k_vals.shape[-2], q.shape[-2])
-        if tp is not None:
-            mesh, ax = tp
-            fn = shard_map(
-                fn, mesh=mesh,
-                in_specs=(P(None, None, ax, None),  # q (1,C,H,D)
-                          P(None, None, ax, None),  # k_vals (N,T,KH,D)
-                          P(ax, None),              # k_scale[slot] (KH,D)
-                          P(ax, None),              # k_zero[slot]
-                          P(None, None, ax, None),  # v_vals
-                          P(None, None, ax, None),  # v_scale
-                          P(None, None, ax, None),  # v_zero
-                          P(None, None, ax, None),  # k_chunk (1,C,KH,D)
-                          P(None, None, ax, None),  # v_chunk
-                          P(None), P()),            # block_row, ctx
-                out_specs=P(None, None, ax, None),
-                check_rep=False)
+        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        specs = None if ax is None else (
+            P(None, None, ax, None),    # q (1,C,H,D)
+            P(None, None, ax, None),    # k_vals (N,T,KH,D)
+            P(ax, None),                # k_scale[slot] (KH,D)
+            P(ax, None),                # k_zero[slot]
+            P(None, None, ax, None),    # v_vals
+            P(None, None, ax, None),    # v_scale
+            P(None, None, ax, None),    # v_zero
+            P(None, None, ax, None),    # k_chunk (1,C,KH,D)
+            P(None, None, ax, None),    # v_chunk
+            P(None), P())               # block_row, ctx
+        fn = _on_mesh(partial(pa.paged_prefix_chunk_attention, **pk), specs,
+                      P(None, None, ax, None))
         return fn(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
                   k_chunk, v_chunk, block_row, ctx)
     return ref.paged_prefix_chunk_attention_ref(q, k_vals, k_scale, k_zero,
@@ -256,10 +261,11 @@ def mla_paged_prefix_chunk_attention(q_lat, q_rope, c_vals, c_scale, c_zero,
     q_lat: (1,C,H,rkv) -> o_lat (1,C,H,rkv) f32 (caller applies W_uv)."""
     pk = _use_pallas()
     if pk is not None:
-        return pa.mla_paged_prefix_chunk_attention(
+        fn = partial(pa.mla_paged_prefix_chunk_attention,
+                     qk_nope_dim=qk_nope_dim, **pk)
+        return _on_mesh(fn, None, None)(
             q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, c_chunk, kr_chunk, block_row, ctx,
-            qk_nope_dim=qk_nope_dim, **pk)
+            kr_zero, c_chunk, kr_chunk, block_row, ctx)
     return ref.mla_paged_prefix_chunk_attention_ref(
         q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero,
         c_chunk, kr_chunk, block_row, ctx, qk_nope_dim=qk_nope_dim)

@@ -76,6 +76,42 @@ def _prescale_q(q: jax.Array, d: int) -> jax.Array:
     return jax.lax.optimization_barrier(q.astype(jnp.float32) / rsqrt)
 
 
+def _chunk_live(shape, mt: int, ctx, group: int) -> jax.Array:
+    """Live score columns of a chunk-prefill launch: pool columns below
+    ``ctx``, and chunk columns causal within the chunk (row ``r`` is chunk
+    position ``r // group``).  Written as and/or of comparisons: Mosaic
+    cannot lower a select between two boolean vectors."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (((col < mt) & (col < ctx))
+            | ((col >= mt) & (col - mt <= row // group)))
+
+
+# The TPU compiler's default scoped-VMEM limit for one kernel on a v5e.
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """Bytes of a (rows, cols) f32 value in VMEM, padded to (8, 128) tiles."""
+    return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
+
+
+def one_shot_vmem_bytes(rows: int, cols: int, scratch_widths,
+                        io_widths) -> int:
+    """VMEM working set of one program of the verify / chunk kernels.
+
+    ``cols`` dequantized key positions are buffered per scratch width (K
+    and V, or latent and rope), one (rows, cols) score value is live, and
+    the (rows, width) query and output blocks are double-buffered.  Unlike
+    the streaming decode kernel this grows with the block table, so the
+    engine checks it at build time (``Scheduler``) instead of failing to
+    compile mid-serve; tests/kernels/test_tpu_compile.py holds the estimate
+    to the compiler's verdict on either side of the line."""
+    return (sum(_tile_bytes(cols, w) for w in scratch_widths)
+            + _tile_bytes(rows, cols)
+            + 2 * sum(_tile_bytes(rows, w) for w in io_widths))
+
+
 # ---------------------------------------------------------------------------
 # Multi-token spec-decode verify
 # ---------------------------------------------------------------------------
@@ -234,10 +270,10 @@ def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
         in_specs=[
             pl.BlockSpec((1, rows, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
             pl.BlockSpec((1, rows, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, rkv), lambda bb, mm, bt, ln: (bb, 0)),
-            pl.BlockSpec((1, rkv), lambda bb, mm, bt, ln: (bb, 0)),
-            pl.BlockSpec((1, dr), lambda bb, mm, bt, ln: (bb, 0)),
-            pl.BlockSpec((1, dr), lambda bb, mm, bt, ln: (bb, 0)),
+            pl.BlockSpec((1, 1, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((1, 1, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((1, 1, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((1, 1, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
             pl.BlockSpec((1, t, rkv_p), lambda bb, mm, bt, ln: (bt[bb, mm], 0, 0)),
             pl.BlockSpec((1, t, dr_p), lambda bb, mm, bt, ln: (bt[bb, mm], 0, 0)),
         ],
@@ -250,8 +286,8 @@ def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rkv), jnp.float32),
         interpret=interpret,
-    )(block_tables, lengths, ql_r, qr_r, c_scale, c_zero, kr_scale, kr_zero,
-      c_vals, kr_vals)
+    )(block_tables, lengths, ql_r, qr_r, c_scale[:, None], c_zero[:, None],
+      kr_scale[:, None], kr_zero[:, None], c_vals, kr_vals)
     return o_lat.reshape(b, gq, h, rkv)
 
 
@@ -279,9 +315,7 @@ def _chunk_kernel(br_ref, ctx_ref, q_ref, ks_ref, kz_ref, k_ref, v_ref,
         kf, vf = kf_ref[...], vf_ref[...]
         s = jax.lax.dot_general(qg, kf, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        live = jnp.where(col < mt, col < ctx_ref[0], col - mt <= row // group)
+        live = _chunk_live(s.shape, mt, ctx_ref[0], group)
         w = _softmax_rows(jnp.where(live, s, NEG_INF))
         o_ref[0] = jax.lax.dot_general(w, vf, (((1,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
@@ -329,8 +363,8 @@ def paged_prefix_chunk_attention(q: jax.Array,
         grid=(kh, m),
         in_specs=[
             pl.BlockSpec((1, rows, d), lambda hh, mm, br, cx: (hh, 0, 0)),
-            pl.BlockSpec((1, d), lambda hh, mm, br, cx: (hh, 0)),
-            pl.BlockSpec((1, d), lambda hh, mm, br, cx: (hh, 0)),
+            pl.BlockSpec((1, 1, d), lambda hh, mm, br, cx: (hh, 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda hh, mm, br, cx: (hh, 0, 0)),
             pl.BlockSpec((1, 1, t, dp), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
             pl.BlockSpec((1, 1, t, dp), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
             pl.BlockSpec((1, 1, t, 1), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
@@ -347,8 +381,8 @@ def paged_prefix_chunk_attention(q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kh, rows, d), jnp.float32),
         interpret=interpret,
-    )(block_row, ctx_arr, q_r, k_scale, k_zero, k_r, v_r, vs_r, vz_r,
-      kc_r, vc_r)
+    )(block_row, ctx_arr, q_r, k_scale[:, None], k_zero[:, None], k_r, v_r,
+      vs_r, vz_r, kc_r, vc_r)
     return out.reshape(kh, c, g, d).transpose(1, 0, 2, 3).reshape(1, c, h, d)
 
 
@@ -375,9 +409,7 @@ def _mla_chunk_kernel(br_ref, ctx_ref, ql_ref, qr_ref, cs_ref, cz_ref,
         s_rope = jax.lax.dot_general(qr_ref[...], krf, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
         s = (s_lat + s_rope) * scale
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        live = jnp.where(col < mt, col < ctx_ref[0], col - mt <= row // heads)
+        live = _chunk_live(s.shape, mt, ctx_ref[0], heads)
         w = _softmax_rows(jnp.where(live, s, NEG_INF))
         o_ref[...] = jax.lax.dot_general(w, cf, (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)

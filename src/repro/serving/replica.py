@@ -44,8 +44,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.obs import SERVING_HISTS, MetricsRegistry, clock
 from repro.serving.scheduler import (Scheduler, SchedulerConfig,
@@ -64,7 +65,7 @@ def _replica_submesh(mesh, i: int):
     if "data" not in ax:
         return mesh
     dev = np.take(mesh.devices, [i], axis=ax.index("data"))
-    return Mesh(dev, mesh.axis_names)
+    return Mesh(dev, mesh.axis_names, axis_types=mesh.axis_types)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -278,7 +279,10 @@ class ReplicatedServeEngine:
         shared = reduce_ema_states([r.scale_state for r in self.replicas],
                                    mesh=self.mesh)
         for r in self.replicas:
-            r.scale_state = shared
+            # replicated on the replica's own devices: its next sampling step
+            # must not mix its submesh with the whole mesh
+            r.scale_state = shared if r.mesh is None else jax.device_put(
+                shared, NamedSharding(r.mesh, P()))
         self.scale_syncs += 1
         return shared
 

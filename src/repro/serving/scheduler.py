@@ -126,6 +126,59 @@ def ensure_paged_supported(cfg: ModelConfig) -> None:
             f"paged serving does not support {cfg.name}: {reason}")
 
 
+def _kernel_vmem_bytes(cfg: ModelConfig, scfg: "SchedulerConfig",
+                       blocks: int) -> Dict[str, int]:
+    """VMEM per program of each one-shot paged kernel this engine launches
+    (prefix-chunk prefill, plus verify under spec decoding) at a block-table
+    width of ``blocks``."""
+    from repro.kernels.paged_attention import one_shot_vmem_bytes
+    kinds = {s.mixer for s in cfg.layer_pattern}
+    cols = blocks * scfg.block_size
+    c = scfg.prefill_chunk
+    g1 = scfg.spec.gamma + 1 if scfg.spec is not None else 0
+    need: Dict[str, int] = {}
+    if "attn" in kinds:
+        d, grp = cfg.hd, cfg.n_heads // cfg.kv_heads
+        need["prefix-chunk"] = one_shot_vmem_bytes(c * grp, cols + c,
+                                                   (d, d), (d, d))
+        if g1:
+            need["verify"] = one_shot_vmem_bytes(g1 * grp, cols,
+                                                 (d, d), (d, d))
+    if "mla" in kinds:
+        rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        h = cfg.n_heads
+        need["mla prefix-chunk"] = one_shot_vmem_bytes(
+            c * h, cols + c, (rkv, dr), (rkv, dr, rkv))
+        if g1:
+            need["mla verify"] = one_shot_vmem_bytes(
+                g1 * h, cols, (rkv, dr), (rkv, dr, rkv))
+    return need
+
+
+def ensure_kernels_fit(cfg: ModelConfig, scfg: "SchedulerConfig") -> None:
+    """Refuse, at engine build, a block table the verify / prefix-chunk
+    kernels cannot buffer in VMEM — the TPU compiler would otherwise reject
+    the first step that launches them, mid-serve."""
+    from repro.kernels.paged_attention import VMEM_LIMIT_BYTES
+    need = _kernel_vmem_bytes(cfg, scfg, scfg.max_blocks_per_req)
+    over = {k: v for k, v in need.items() if v > VMEM_LIMIT_BYTES}
+    if not over:
+        return
+    fit = scfg.max_blocks_per_req
+    while fit > 0 and max(_kernel_vmem_bytes(cfg, scfg, fit).values()) \
+            > VMEM_LIMIT_BYTES:
+        fit -= 1
+    kern, nbytes = max(over.items(), key=lambda kv: kv[1])
+    raise ValueError(
+        f"{cfg.name}: max_blocks_per_req * block_size = "
+        f"{scfg.max_blocks_per_req * scfg.block_size} tokens needs "
+        f"{nbytes / 2**20:.1f} MiB of VMEM in the {kern} attention kernel "
+        f"(prefill_chunk={scfg.prefill_chunk}), over the "
+        f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB a TPU kernel may use; at most "
+        f"max_blocks_per_req={fit} ({fit * scfg.block_size} tokens) fits — "
+        f"lower max_blocks_per_req or prefill_chunk")
+
+
 @dataclasses.dataclass(frozen=True)
 class SchedulerConfig:
     block_size: int = 16                 # tokens per KV block
@@ -404,6 +457,7 @@ class Scheduler:
         is traced under ``axis_rules(mesh, rules)`` so activation
         constraints in the model code become real collective boundaries."""
         ensure_paged_supported(cfg)
+        ensure_kernels_fit(cfg, scfg)
         if scfg.ladder and scfg.codec != "int8":
             raise ValueError(
                 "the bit ladder demotes int8 blocks to packed int4; it "

@@ -19,7 +19,8 @@ from repro.distributed.compression import compress_decompress, init_error_state
 
 def _run_subprocess(code: str):
     r = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
+        [sys.executable, "-c",
+         "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
              "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
@@ -34,17 +35,17 @@ def test_scale_sync_consistency_8dev():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.scale_sync import (global_absmax,
                                                   sync_scale_allgather,
                                                   make_synced_quant_step)
         from repro.core.online import EmaScaleState
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (64, 32)) * \\
             jnp.arange(1, 65)[:, None]          # shard-dependent ranges
 
         @partial(shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                 check_rep=False)
+                 check_vma=False)
         def both(xs):
             local = jnp.max(jnp.abs(xs))
             via_pmax = global_absmax(xs, ("data",))
@@ -73,7 +74,7 @@ def test_reduce_ema_states_mesh_matches_host_8dev():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.online import EmaScaleState
         from repro.distributed.scale_sync import reduce_ema_states
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         states = [EmaScaleState(delta=jnp.asarray(1.0 + i),
                                 mu=jnp.asarray(float(i)),
                                 step=jnp.asarray(i + 1, jnp.int32))
@@ -92,7 +93,7 @@ def test_int8_allreduce_8dev():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.compression import make_int8_allreduce
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (64, 128))
         ar = make_int8_allreduce(mesh)
         out = np.asarray(ar(g))

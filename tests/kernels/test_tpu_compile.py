@@ -1,0 +1,184 @@
+"""Compile every main-path Pallas kernel for a v5e at real widths.
+
+Interpret mode checks neither the TPU tiling rules nor the VMEM limit; the
+TPU compiler, which is installed here, does — for a chip that is described
+and not attached.  Widths: qwen2-0.5b (14 heads over 2 KV heads,
+head_dim 64, d_ff 4864, vocab 151936), qwen3-1.7b (16 over 8, head_dim
+128, d_model 2048, d_ff 6144) and minicpm3-4b's MLA latent (rank 256,
+rope 32, 40 heads).  The topology is described inside a fixture, never at
+import: only one process may load the TPU library at a time.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import minicpm3_4b, qwen2_0_5b, qwen3_1_7b
+from repro.serving.scheduler import (SchedulerConfig, _kernel_vmem_bytes,
+                                     ensure_kernels_fit)
+from repro.serving.spec_decode import SpecConfig
+
+kd = importlib.import_module("repro.kernels.kv_decode_attention")
+pa = importlib.import_module("repro.kernels.paged_attention")
+fq = importlib.import_module("repro.kernels.fused_quant")
+wm = importlib.import_module("repro.kernels.w8a8_matmul")
+
+I8, I32, F32, BF16 = jnp.int8, jnp.int32, jnp.float32, jnp.bfloat16
+B, N, T, G, C = 8, 512, 16, 5, 64        # batch, pool blocks, block, verify, chunk
+WIDTHS = {"qwen2": (14, 2, 64), "qwen3": (16, 8, 128)}   # heads, kv heads, hd
+RKV, DR, DN, HM = 256, 32, 64, 40                          # minicpm3 MLA
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dt=F32: jax.ShapeDtypeStruct(dims, dt,
+                                                     sharding=one_chip)
+
+
+def _gqa_pool(S, kh, d, bits, per_slot):
+    dp = d if bits == 8 else d // 2
+    scale = (B, kh, d) if per_slot else (kh, d)
+    return (S((N, T, kh, dp), I8), S(scale), S(scale), S((N, T, kh, dp), I8),
+            S((N, T, kh, 1)), S((N, T, kh, 1)))
+
+
+def _decode(S, model, bits, m=32):
+    h, kh, d = WIDTHS[model]
+    return (kd.paged_kv_decode_attention, S((B, h, d)),
+            *_gqa_pool(S, kh, d, bits, True), S((B, m), I32), S((B,), I32))
+
+
+def _verify(S, model, bits, m=32):
+    h, kh, d = WIDTHS[model]
+    return (pa.paged_kv_verify_attention, S((B, G, h, d)),
+            *_gqa_pool(S, kh, d, bits, True), S((B, m), I32), S((B,), I32))
+
+
+def _chunk(S, model, bits, m=32, c=C):
+    h, kh, d = WIDTHS[model]
+    return (pa.paged_prefix_chunk_attention, S((1, c, h, d)),
+            *_gqa_pool(S, kh, d, bits, False), S((1, c, kh, d)),
+            S((1, c, kh, d)), S((m,), I32), S((), I32))
+
+
+def _mla_verify(S, bits, m=32):
+    rp, drp = (RKV, DR) if bits == 8 else (RKV // 2, DR // 2)
+
+    def fn(*a):
+        return pa.mla_paged_verify_attention(*a, qk_nope_dim=DN)
+    return (fn, S((B, G, HM, RKV)), S((B, G, HM, DR)), S((N, T, rp), I8),
+            S((B, RKV)), S((B, RKV)), S((N, T, drp), I8), S((B, DR)),
+            S((B, DR)), S((B, m), I32), S((B,), I32))
+
+
+def _mla_chunk(S, bits, m=32, c=32):
+    rp, drp = (RKV, DR) if bits == 8 else (RKV // 2, DR // 2)
+
+    def fn(*a):
+        return pa.mla_paged_prefix_chunk_attention(*a, qk_nope_dim=DN)
+    return (fn, S((1, c, HM, RKV)), S((1, c, HM, DR)), S((N, T, rp), I8),
+            S((RKV,)), S((RKV,)), S((N, T, drp), I8), S((DR,)), S((DR,)),
+            S((1, c, RKV)), S((1, c, DR)), S((m,), I32), S((), I32))
+
+
+def _dense_decode(S, model, s=517):
+    h, kh, d = WIDTHS[model]
+    return (kd.kv_decode_attention, S((B, h, d)), S((B, s, kh, d), I8),
+            S((B, 1, kh, d)), S((B, 1, kh, d)), S((B, s, kh, d), I8),
+            S((B, s, kh, 1)), S((B, s, kh, 1)), S((B,), I32))
+
+
+def _quant(S, dt):
+    return fq.fused_quant, S((512, 4864), dt)
+
+
+def _gemm(S, m, k, n):
+    return (wm.w8a8_matmul, S((m, k), I8), S((m, 1)), S((k, n), I8),
+            S((1, n)))
+
+
+CASES = {
+    **{f"paged_decode_{mo}_int{b}": (_decode, mo, b)
+       for mo in WIDTHS for b in (8, 4)},
+    **{f"verify_{mo}_int{b}": (_verify, mo, b)
+       for mo in WIDTHS for b in (8, 4)},
+    **{f"prefix_chunk_{mo}_int{b}": (_chunk, mo, b)
+       for mo in WIDTHS for b in (8, 4)},
+    **{f"mla_verify_int{b}": (_mla_verify, b) for b in (8, 4)},
+    **{f"mla_prefix_chunk_int{b}": (_mla_chunk, b) for b in (8, 4)},
+    **{f"dense_decode_{mo}": (_dense_decode, mo) for mo in WIDTHS},
+    "fused_quant_f32_4864": (_quant, F32),
+    "fused_quant_bf16_4864": (_quant, BF16),
+    "w8a8_decode_qwen2_up": (_gemm, 8, 896, 4864),
+    "w8a8_decode_qwen2_down": (_gemm, 8, 4864, 896),
+    "w8a8_prefill_qwen2_vocab": (_gemm, 512, 896, 151936),
+    "w8a8_decode_qwen3_up": (_gemm, 8, 2048, 6144),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, shape):
+    build, *args = CASES[case]
+    fn, *shapes = build(shape, *args)
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# Either side of the VMEM line of each one-shot kernel: the engine's build-
+# time estimate (ensure_kernels_fit) and the compiler must agree.
+LINE = [
+    # kernel, config, scheduler fields, blocks accepted, blocks refused
+    ("prefix-chunk", qwen2_0_5b.FULL, dict(prefill_chunk=64), 256, 512),
+    ("prefix-chunk", qwen3_1_7b.FULL, dict(prefill_chunk=128), 256, 512),
+    ("verify", qwen2_0_5b.FULL,
+     dict(prefill_chunk=16, spec=SpecConfig(gamma=4)), 512, 1024),
+    ("mla prefix-chunk", minicpm3_4b.FULL, dict(prefill_chunk=32), 64, 128),
+]
+
+
+def _launch(kernel, cfg, scfg, blocks, S):
+    if kernel == "mla prefix-chunk":
+        return _mla_chunk(S, 8, blocks, scfg.prefill_chunk)
+    model = "qwen2" if cfg.hd == 64 else "qwen3"
+    assert WIDTHS[model] == (cfg.n_heads, cfg.kv_heads, cfg.hd)
+    if kernel == "prefix-chunk":
+        return _chunk(S, model, 8, blocks, scfg.prefill_chunk)
+    return _verify(S, model, 8, blocks)
+
+
+@pytest.mark.parametrize("kernel,cfg,fields,ok_blocks,bad_blocks", LINE,
+                         ids=[f"{k}-{c.name}" for k, c, *_ in LINE])
+def test_vmem_guard_agrees_with_compiler(kernel, cfg, fields, ok_blocks,
+                                         bad_blocks, shape):
+    for blocks, fits in ((ok_blocks, True), (bad_blocks, False)):
+        scfg = SchedulerConfig(block_size=T, max_blocks_per_req=blocks,
+                               **fields)
+        need = _kernel_vmem_bytes(cfg, scfg, blocks)[kernel]
+        assert (need <= pa.VMEM_LIMIT_BYTES) == fits, (blocks, need)
+        fn, *shapes = _launch(kernel, cfg, scfg, blocks, shape)
+        if fits:
+            jax.jit(fn).lower(*shapes).compile()
+        else:
+            with pytest.raises(ValueError, match="VMEM"):
+                ensure_kernels_fit(cfg, scfg)
+            with pytest.raises(Exception, match="(?i)vmem"):
+                jax.jit(fn).lower(*shapes).compile()

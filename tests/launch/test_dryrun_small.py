@@ -25,6 +25,7 @@ def test_tiny_mesh_train_and_decode():
         from repro.launch.steps import (input_specs, make_train_step,
                                         make_serve_step, SHAPES)
         from repro.launch import hlo_analysis as ha
+        from repro.launch.mesh import make_mesh
         from repro.optim import AdamWConfig
 
         # shrink the shape table for the tiny run
@@ -35,8 +36,8 @@ def test_tiny_mesh_train_and_decode():
         }
 
         for multi in (False, True):
-            mesh = (jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-                    if multi else jax.make_mesh((2, 2), ("data", "model")))
+            mesh = (make_mesh((2, 2, 2), ("pod", "data", "model"))
+                    if multi else make_mesh((2, 2), ("data", "model")))
             for arch in ("qwen3-1.7b", "phi3.5-moe-42b-a6.6b"):
                 cfg = get_smoke_config(arch)
                 with axis_rules(mesh):

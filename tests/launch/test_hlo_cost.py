@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.launch.hlo_cost import HloCostModel, analyze
+from repro.launch.mesh import make_mesh
 
 
 def _body(c, w):
@@ -58,7 +59,7 @@ def test_nested_scan_with_grad():
 
 def test_collectives_scaled_by_trip_count():
     import numpy as np
-    mesh = jax.make_mesh((1,), ("data",))   # single device: psum still lowers
+    mesh = make_mesh((1,), ("data",))   # single device: psum still lowers
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     hlo = """

@@ -33,6 +33,7 @@ COMMON = """
 import dataclasses
 import jax
 import numpy as np
+from repro.launch.mesh import make_mesh
 from repro.models import ModelConfig, init_params
 from repro.models.config import LayerSpec
 from repro.serving.engine import PagedServeEngine, Request
@@ -68,7 +69,7 @@ def test_sharded_gqa_tp_parity_and_pool_shrink():
                           n_heads=4, n_kv_heads=2, d_ff=128, attn_chunk=16)
         params = init_params(CFG, jax.random.PRNGKey(0))
         base = serve_paged(params, CFG)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         tp = serve_paged(params, CFG, mesh=mesh)
         assert outputs(base) == outputs(tp), "TP perturbed greedy output"
         mb, mt = base.metrics(), tp.metrics()
@@ -116,7 +117,7 @@ def test_sharded_replicated_2x2_spec_preempt_parity():
             return eng
 
         base = serve(None)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         tp = serve(mesh)
         assert outputs(base) == outputs(tp), "2D mesh perturbed greedy output"
         mt = tp.metrics()
@@ -142,7 +143,7 @@ def test_sharded_mla_tp_parity():
                           attn_chunk=16)
         params = init_params(CFG, jax.random.PRNGKey(1))
         base = serve_paged(params, CFG)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         tp = serve_paged(params, CFG, mesh=mesh)
         assert outputs(base) == outputs(tp), "MLA TP perturbed greedy output"
         mt = tp.metrics()
@@ -163,7 +164,7 @@ def test_sharded_moe_parity():
                           attn_chunk=16)
         params = init_params(CFG, jax.random.PRNGKey(2))
         base = serve_paged(params, CFG)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         tp = serve_paged(params, CFG, mesh=mesh)
         assert outputs(base) == outputs(tp), "MoE TP perturbed greedy output"
         print("MOE_TP_OK")
@@ -201,7 +202,7 @@ def test_sharded_hybrid_ssm_parity():
             return eng
 
         base = serve(None)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         tp = serve(mesh)
         assert outputs(base) == outputs(tp), "hybrid TP perturbed output"
         print("HYB_TP_OK")
@@ -219,7 +220,7 @@ def test_sharded_gqa_pallas_shard_map_parity():
                           n_heads=4, n_kv_heads=2, d_ff=128, attn_chunk=16)
         params = init_params(CFG, jax.random.PRNGKey(0))
         base = serve_paged(params, CFG, n=2, max_new=6)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         tp = serve_paged(params, CFG, mesh=mesh, n=2, max_new=6)
         assert outputs(base) == outputs(tp), "pallas shard_map diverged"
         print("PALLAS_TP_OK")
