@@ -15,10 +15,9 @@ shifts time from Load+GEMM into a small Quant term (Table 5's 24.1->10.8 ms
 Load and 38.4->19.5 ms GEMM at <5 ms Quant).
 
 All component timings flow through the obs tracer's span machinery
-(``common.timeit``), and a second table decomposes one *served* request
-stream into the scheduler's phase spans (schedule / device_step / consume)
-straight from a traced :class:`~repro.serving.engine.PagedServeEngine` run —
-the serving-side analogue of Eq. 12, with no hand-rolled perf_counter pairs.
+(``common.timeit``).  The serving-side split of a step into the scheduler's
+phases is measured on the chip, from the engine's own spans
+(``bench/phases.py`` and the benchmark's per-layer metrics).
 """
 from __future__ import annotations
 
@@ -29,7 +28,6 @@ import numpy as np
 from repro.core.online import EmaScaleState, async_quant_update
 from repro.core.qtensor import quantize_symmetric
 from repro.kernels import ref
-from repro.obs import Tracer
 
 from .common import emit, timeit
 
@@ -86,55 +84,7 @@ def run():
                      comm_ms="-", sync_ms="-",
                      total_ms=round(rows[1]["total_ms"] / rows[0]["total_ms"], 3)))
     emit(rows, "experiments/bench/latency_breakdown.csv")
-    rows += _serving_phase_split()
     return rows
-
-
-def _serving_phase_split():
-    """Scheduler-phase latency decomposition from tracer span data.
-
-    Drives a small paged engine with the tracer on and aggregates each
-    phase's span durations: ``schedule`` (host admission + scheduling),
-    ``device_step`` (dispatch of the fused jitted step), ``consume``
-    (blocking on logits + sampling/retirement).  schedule + device + consume
-    covers a step's wall; per-step means land in
-    experiments/bench/latency_phases.csv."""
-    from repro.models import init_params
-    from repro.serving.engine import PagedServeEngine, Request
-    from .bench_serving import SCFG, SERVE_CFG
-
-    params = init_params(SERVE_CFG, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(11)
-
-    def drive(tr):
-        eng = PagedServeEngine(params, SERVE_CFG, SCFG, tracer=tr)
-        for i in range(8):
-            eng.add_request(Request(
-                uid=i, prompt=rng.integers(
-                    0, SERVE_CFG.vocab_size, size=48).astype(np.int32),
-                max_new_tokens=8))
-        eng.run()
-        return eng
-
-    drive(None)                         # warm the jit caches off-trace
-    tr = Tracer()
-    eng = drive(tr)
-    phases = {}
-    for e in tr.events:
-        if e.dur is not None and e.kind in ("schedule", "device_step",
-                                            "consume"):
-            phases.setdefault(e.kind, []).append(e.dur)
-    steps = max(eng.stats["steps"], 1)
-    ms = lambda ts: round(float(np.sum(ts)) / steps * 1e3, 3)
-    row = dict(method="paged_serving",
-               schedule_ms=ms(phases.get("schedule", [0.0])),
-               device_step_ms=ms(phases.get("device_step", [0.0])),
-               consume_ms=ms(phases.get("consume", [0.0])),
-               steps=steps)
-    row["total_ms"] = round(row["schedule_ms"] + row["device_step_ms"]
-                            + row["consume_ms"], 3)
-    emit([row], "experiments/bench/latency_phases.csv")
-    return [row]
 
 
 if __name__ == "__main__":

@@ -157,31 +157,25 @@ def scorecard_gate(out_dir: str = "experiments/scorecard"):
     return None
 
 
-def obs_overhead_gate(path: str = "experiments/bench/serving_obs.csv",
+def obs_trace_gate(path: str = "experiments/bench/serving_obs.csv",
                       trace_path: str = "experiments/bench/serving_trace.json"):
-    """Return an error string if tracing stopped being ~free or the exported
-    trace broke.
+    """Return an error string if the exported trace broke.
 
-    Observability's contract is that it never becomes the perturbation it
-    measures: the tracing-on serving run must stay within 10% tokens/s of
-    the tracing-off run (the ring buffer is one branch + a deque append),
-    and the exported Chrome trace must schema-validate and contain every
-    span kind the instrumentation promises (prefill chunk, decode step,
+    The exported Chrome trace must schema-validate and contain every span
+    kind the instrumentation promises (prefill chunk, decode step,
     preemption, spec round, ladder demotion) — a missing kind means some
-    scheduler path silently lost its spans."""
+    scheduler path silently lost its spans.  What tracing costs is measured
+    on the chip (``bench/phases.py``), not here: a host-CPU tokens/s ratio
+    says nothing about it."""
     import json
     from benchmarks.bench_serving import TRACE_REQUIRED_KINDS
     from repro.obs import validate_chrome_trace
     try:
         with open(path) as f:
             rows = {r["point"]: r for r in csv.DictReader(f)}
-        ratio = float(rows["obs_on"]["overhead_ratio"])
         dropped = int(rows["obs_on"]["trace_dropped"])
     except (OSError, KeyError, ValueError) as e:
         return f"obs gate: cannot read {path} ({e!r})"
-    if ratio < 0.9:
-        return (f"obs gate: tracing-on tokens/s is {ratio} of tracing-off — "
-                f"overhead exceeds the 10% budget ({path})")
     try:
         with open(trace_path) as f:
             obj = json.load(f)
@@ -277,9 +271,9 @@ def main() -> None:
         if err:
             failures += 1
             print(err, file=sys.stderr)
-        # tracing must stay ~free and the exported Chrome trace must be
-        # schema-valid with every promised span kind present
-        err = obs_overhead_gate()
+        # the exported Chrome trace must be schema-valid with every
+        # promised span kind present
+        err = obs_trace_gate()
         if err:
             failures += 1
             print(err, file=sys.stderr)

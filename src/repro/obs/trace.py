@@ -22,15 +22,22 @@ module is the single timing authority for the hot path:
   * ``validate_chrome_trace(obj)`` — the schema check the benchmark gate
     and the tier-1 tests share.
 
-Span taxonomy (``SCHED_SPANS``): ``schedule`` (host admission + scheduling
-decisions), ``device_step`` (async dispatch of the fused jitted step),
-``consume`` (blocking on device results + sampling/retirement),
+Span taxonomy (``SCHED_SPANS``), one set per engine step, every span
+tagged ``step=<ordinal>``: ``schedule`` (host admission + scheduling,
+holding ``stage``: the step's host arrays built and put on the device),
+``device_step`` (async dispatch of the fused jitted step), ``consume``
+(holding ``device_wait``: the host blocked on device results, ``publish``:
+prefix-index publishing, and ``retire``: the rest of consume's host work),
 ``decode_step`` / ``spec_round`` / ``prefill_chunk`` (the step's work items,
 spanning dispatch -> consumed).  Lifecycle events (``LIFECYCLE_EVENTS``)
 mark request milestones on the slot tracks: ``enqueue`` -> ``admit`` ->
 ``prefix_hit``/``partial_hit`` -> ``first_token`` -> ``preempt``/``resume``
 -> ``finish``, plus allocator traffic (``cow_copy``, ``demote``,
 ``promote``, ``draft_prefill``, ``draft_bootstrap``).
+
+With ``Tracer(jax_profiler=True)`` every ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` over the same interval, so a device
+profile captured around the run holds the phases on its own clock.
 """
 from __future__ import annotations
 
@@ -39,10 +46,14 @@ import json
 import os
 import time
 from collections import deque
+from itertools import islice
 from typing import Any, Dict, List, Optional
 
+from repro.obs import compiles
+
 # scheduler-phase span kinds (duration spans on the scheduler track)
-SCHED_SPANS = ("schedule", "device_step", "consume", "spec_round",
+SCHED_SPANS = ("schedule", "stage", "device_step", "consume",
+               "device_wait", "publish", "retire", "spec_round",
                "prefill_chunk", "decode_step")
 # instant lifecycle / allocator events (request slot tracks where lane >= 0)
 LIFECYCLE_EVENTS = ("enqueue", "admit", "prefix_hit", "partial_hit",
@@ -81,22 +92,54 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager for ``Tracer.span`` (reused object, no closure)."""
+    """Context manager for ``Tracer.span``: records the block as a span,
+    tags it ``compiles=n`` where JAX built executables inside it, opens a
+    profiler annotation over the same interval where the tracer asks for
+    one, and with ``rest`` records the parts of the block that no span
+    recorded inside it on the same track covers as ``rest`` spans."""
 
-    __slots__ = ("tr", "kind", "track", "lane", "args", "t0")
+    __slots__ = ("tr", "kind", "track", "lane", "args", "rest", "ann",
+                 "t0", "c0", "mark")
 
-    def __init__(self, tr, kind, track, lane, args):
+    def __init__(self, tr, kind, track, lane, args, rest, annotation):
         self.tr, self.kind, self.track, self.lane, self.args = \
             tr, kind, track, lane, args
+        self.rest = rest
+        self.ann = None
+        if tr.jax_profiler:
+            import jax
+            self.ann = jax.profiler.TraceAnnotation(annotation or kind)
 
     def __enter__(self):
+        tr = self.tr
+        self.mark = len(tr.events) + tr.dropped
+        self.c0 = compiles.count()
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = clock()
         return self
 
     def __exit__(self, *exc):
-        self.tr.add_span(self.kind, self.t0, clock() - self.t0,
-                         track=self.track, lane=self.lane,
-                         **(self.args or {}))
+        t1 = clock()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        tr, args = self.tr, dict(self.args or {})
+        n = compiles.count() - self.c0
+        if n:
+            args["compiles"] = n
+        if self.rest is not None:
+            inside = len(tr.events) + tr.dropped - self.mark
+            held = sorted((e.ts, e.ts + e.dur)
+                          for e in islice(reversed(tr.events), inside)
+                          if e.dur is not None and e.track == self.track)
+            t = self.t0
+            for a, b in held + [(t1, t1)]:
+                if a > t:
+                    tr.add_span(self.rest, t, a - t, track=self.track,
+                                lane=self.lane, **(self.args or {}))
+                t = max(t, b)
+        tr.add_span(self.kind, self.t0, t1 - self.t0, track=self.track,
+                    lane=self.lane, **args)
         return False
 
 
@@ -107,9 +150,9 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536, jax_profiler: bool = False):
         """``capacity``: ring size — oldest spans are dropped beyond it.
-        ``jax_profiler``: also wrap ``annotate()`` scopes in
-        ``jax.profiler.TraceAnnotation`` so the jitted step shows up inside
-        an XLA profile (no-op when jax's profiler is unavailable)."""
+        ``jax_profiler``: also open a ``jax.profiler.TraceAnnotation`` over
+        each ``span()``, so the phases show up inside an XLA profile on the
+        profiler's clock."""
         self.capacity = int(capacity)
         self.events: "deque[Span]" = deque(maxlen=self.capacity)
         self.jax_profiler = bool(jax_profiler)
@@ -135,21 +178,16 @@ class Tracer:
         self.events.append(Span(kind, t0, max(dur, 0.0), track, lane,
                                 args or None))
 
-    def span(self, kind: str, track: int = 0, lane: int = -1, **args):
-        """Context manager recording the wrapped block as a span."""
-        return _SpanCtx(self, kind, track, lane, args or None)
-
-    def annotate(self, name: str):
-        """Optional ``jax.profiler`` trace-context hook: a named annotation
-        around the jitted step dispatch, visible in an XLA device profile.
-        Returns a null context unless ``jax_profiler=True`` was requested."""
-        if not self.jax_profiler:
-            return contextlib.nullcontext()
-        try:
-            import jax
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:                 # profiler unavailable on this host
-            return contextlib.nullcontext()
+    def span(self, kind: str, track: int = 0, lane: int = -1, *,
+             annotation: Optional[str] = None, rest: Optional[str] = None,
+             **args):
+        """Context manager recording the wrapped block as a span.  With
+        ``jax_profiler`` it also opens a profiler annotation named
+        ``annotation`` (default: ``kind``) over the same interval.  ``rest``
+        names the spans recorded over the parts of the block that no span
+        recorded inside it (on ``track``) covers."""
+        return _SpanCtx(self, kind, track, lane, args or None, rest,
+                        annotation)
 
     def clear(self) -> None:
         self.events.clear()
@@ -225,10 +263,8 @@ class _NullTracer(Tracer):
     def add_span(self, kind, t0, dur, track=0, lane=-1, **args):
         pass
 
-    def span(self, kind, track=0, lane=-1, **args):
-        return self._NULL_CTX
-
-    def annotate(self, name):
+    def span(self, kind, track=0, lane=-1, *, annotation=None, rest=None,
+             **args):
         return self._NULL_CTX
 
 

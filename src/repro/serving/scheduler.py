@@ -86,6 +86,7 @@ import numpy as np
 
 from repro.core.online import EmaScaleState
 from repro.obs import NULL_TRACER, SERVING_HISTS, MetricsRegistry, clock
+from repro.obs import compiles
 from repro.distributed import sharding as shd
 from repro.models.config import ModelConfig
 from repro.models.transformer import (forward_decode_paged,
@@ -525,6 +526,7 @@ class Scheduler:
         # observability: tracer (no-op singleton unless injected) + the
         # always-on latency histograms metrics() summarizes
         self.trace = tracer if tracer is not None else NULL_TRACER
+        compiles.install()
         self.track = int(trace_track)
         self.mreg = MetricsRegistry()
         # speculative decoding: the draft proposer holds one dense-cache lane
@@ -553,7 +555,16 @@ class Scheduler:
                       "spec_proposed": 0, "spec_accepted": 0,
                       "spec_emitted": 0, "snap_demotions": 0,
                       "snap_promotions": 0, "state_prefix_hits": 0,
-                      "score_requests": 0, "score_tokens": 0}
+                      "score_requests": 0, "score_tokens": 0,
+                      # per decode or verify step: block-table entries that
+                      # hold KV the step attends to, and entries handed to
+                      # the paged kernel
+                      "decode_kv_blocks_live": 0,
+                      "decode_kv_blocks_passed": 0,
+                      # executables built or loaded by this scheduler's
+                      # steps (repro.obs.compiles)
+                      "compiles": 0}
+        self._consuming = 0             # ordinal of the step being consumed
         self._score_lat_sum = 0.0       # summed per-request scoring latency
         self._util_sum = 0.0
         self._util_peak = 0.0
@@ -625,6 +636,7 @@ class Scheduler:
         instead of serializing through the host control loop.  Returns None
         when there is no work this step."""
         t0 = clock()
+        c0 = compiles.count()
         if self._t_start is None:
             self._t_start = t0
         if self.scfg.ladder:
@@ -646,6 +658,7 @@ class Scheduler:
                 # proposal and the wide verify entirely
                 vlens = None
         if not dec_slots and pf is None:
+            self.stats["compiles"] += compiles.count() - c0
             return None
         self.stats["steps"] += 1
         self._util_sum += self.alloc.utilization
@@ -659,72 +672,76 @@ class Scheduler:
         # chunk-logits head is a different — larger — jit specialization)
         pf_score = (pf is not None
                     and self.slots[pf[0]].score_from >= 0)
-        tr = self.trace
+        tr, step = self.trace, self.stats["steps"]
         if dec_slots and vlens:
             drafts = self._propose_drafts(dec_slots, vlens)
-            args = self._build_spec_args(dec_slots, vlens, drafts, pf)
-            t1 = clock()
-            if tr.enabled:
-                tr.add_span("schedule", t0, t1 - t0, track=self.track,
-                            decode=len(dec_slots), spec=True)
-            with tr.annotate("paged_spec_step"):
-                pf_logits, ver_logits, self.pool, self.spool = self._spec_fn(
-                    self.params, self.pool, self.spool, *args["device"],
-                    do_prefill=pf is not None, do_decode=True,
-                    pf_first=(pf is None or pf[1] == 0), pf_score=pf_score)
-            if tr.enabled:
-                tr.add_span("device_step", t1, clock() - t1, track=self.track)
-            return {"dec_slots": dec_slots, "vlens": vlens, "drafts": drafts,
-                    "pf": pf, "pf_logits": pf_logits,
-                    "ver_logits": ver_logits, "t0": t0, "t1": t1}
-        args = self._build_args(dec_slots, pf)
+            fn, name, sargs = self._spec_fn, "paged_spec_step", {"spec": True}
+        else:
+            drafts = vlens = None
+            fn, name, sargs = self._step_fn, "paged_step", \
+                {"prefill": pf is not None}
+        with tr.span("stage", track=self.track, step=step):
+            args = (self._build_spec_args(dec_slots, vlens, drafts, pf)
+                    if vlens else self._build_args(dec_slots, pf))
         t1 = clock()
         if tr.enabled:
-            tr.add_span("schedule", t0, t1 - t0, track=self.track,
-                        decode=len(dec_slots), prefill=pf is not None)
-        with tr.annotate("paged_step"):
-            pf_logits, dec_logits, self.pool, self.spool = self._step_fn(
+            held = compiles.count() - c0
+            if held:
+                sargs["compiles"] = held
+            tr.add_span("schedule", t0, t1 - t0, track=self.track, step=step,
+                        decode=len(dec_slots), **sargs)
+        with tr.span("device_step", track=self.track, annotation=name,
+                     step=step):
+            pf_logits, dec_logits, self.pool, self.spool = fn(
                 self.params, self.pool, self.spool, *args["device"],
                 do_prefill=pf is not None, do_decode=bool(dec_slots),
                 pf_first=(pf is None or pf[1] == 0), pf_score=pf_score)
-        if tr.enabled:
-            tr.add_span("device_step", t1, clock() - t1, track=self.track)
-        return {"dec_slots": dec_slots, "vlens": None, "drafts": None,
+        self.stats["compiles"] += compiles.count() - c0
+        return {"dec_slots": dec_slots, "vlens": vlens, "drafts": drafts,
                 "pf": pf, "pf_logits": pf_logits, "dec_logits": dec_logits,
-                "t0": t0, "t1": t1}
+                "t0": t0, "t1": t1, "step": step}
 
     def step_consume(self, launched: Optional[Dict[str, Any]]) -> bool:
         """Block on a ``step_launch`` context's logits and sample/retire."""
         if launched is None:
             return False
-        t2 = clock()
+        c0 = compiles.count()
         dec_slots, pf = launched["dec_slots"], launched["pf"]
-        if launched["vlens"] is not None:
-            self._consume_spec(dec_slots, launched["vlens"],
-                               launched["drafts"], launched["ver_logits"])
-        elif dec_slots:
-            self._consume_decode(dec_slots, launched["dec_logits"])
-        if pf is not None:
-            self._consume_prefill(pf, launched["pf_logits"])
+        tr, step = self.trace, launched["step"]
+        self._consuming = step
+        with tr.span("consume", track=self.track, rest="retire", step=step):
+            if launched["vlens"] is not None:
+                self._consume_spec(dec_slots, launched["vlens"],
+                                   launched["drafts"], launched["dec_logits"])
+            elif dec_slots:
+                self._consume_decode(dec_slots, launched["dec_logits"])
+            if pf is not None:
+                self._consume_prefill(pf, launched["pf_logits"])
         t3 = clock()
-        tr = self.trace
+        self.stats["compiles"] += compiles.count() - c0
         if tr.enabled:
             t1 = launched["t1"]
-            tr.add_span("consume", t2, t3 - t2, track=self.track)
             if launched["vlens"] is not None:
                 tr.add_span("spec_round", t1, t3 - t1, track=self.track,
-                            lanes=len(launched["vlens"]))
+                            step=step, lanes=len(launched["vlens"]))
             elif dec_slots:
                 tr.add_span("decode_step", t1, t3 - t1, track=self.track,
-                            batch=len(dec_slots))
+                            step=step, batch=len(dec_slots))
             if pf is not None:
                 run = self.slots[pf[0]]
                 tr.add_span("prefill_chunk", t1, t3 - t1, track=self.track,
-                            lane=pf[0], ctx=pf[1], tokens=pf[2],
+                            lane=pf[0], step=step, ctx=pf[1], tokens=pf[2],
                             uid=run.req.uid if run is not None else -1)
         self.mreg.observe("step_wall", t3 - launched["t0"])
         self._t_last = t3
         return True
+
+    def _fetch(self, x) -> np.ndarray:
+        """Bring a device result to the host: the only points at which
+        ``consume`` blocks on the device."""
+        with self.trace.span("device_wait", track=self.track,
+                             step=self._consuming):
+            return np.asarray(x)
 
     def run(self, max_steps: int = 10_000):
         steps = 0
@@ -964,9 +981,10 @@ class Scheduler:
             run.slot = slot
             self.block_tables[slot, :] = self.trash
             self.slots[slot] = run
-            self.mreg.observe("queue_wait", clock() - run.t_add)
+            waited = clock() - run.t_add
+            self.mreg.observe("queue_wait", waited)
             self.trace.event("admit", track=self.track, lane=slot,
-                             uid=run.req.uid)
+                             uid=run.req.uid, waited_ms=1e3 * waited)
             self._match_prefix(slot, run)
 
     def _match_cap(self, run: _Run) -> int:
@@ -1348,8 +1366,8 @@ class Scheduler:
         temps = np.zeros((self.scfg.max_batch,), np.float32)
         for s in dec_slots:
             temps[s] = self.slots[s].req.temperature
-        first = np.asarray(self._sample(ver_logits[:, 0], temps))
-        greedy = np.asarray(jnp.argmax(ver_logits, axis=-1))   # (B, G)
+        first = self._fetch(self._sample(ver_logits[:, 0], temps))
+        greedy = self._fetch(jnp.argmax(ver_logits, axis=-1))   # (B, G)
         self.stats["decode_steps"] += 1
         self.stats["spec_rounds"] += 1
         for s in dec_slots:
@@ -1522,11 +1540,26 @@ class Scheduler:
             dec_lens[s] = run.ctx
             if run.state_slot >= 0:
                 dec_sslots[s] = run.state_slot
+        if dec_slots:
+            # the kernel attends each lane's ctx tokens plus the one it
+            # appends
+            self._count_kv_blocks(dec_lens, 1)
 
         device = (jnp.asarray(dec_toks), jnp.asarray(dec_bt),
                   jnp.asarray(dec_lens), jnp.asarray(dec_sslots),
                   *self._build_pf_args(pf))
         return {"device": device}
+
+    def _count_kv_blocks(self, dec_lens: np.ndarray, appended) -> None:
+        """Count the blocks a decode or verify step's lanes attend to
+        (``ceil((ctx + appended) / block_size)`` over the decoding lanes;
+        idle lanes have ctx 0 and append nothing) against the block-table
+        entries handed to the paged kernel."""
+        bs = self.scfg.block_size
+        live = np.where(dec_lens > 0, dec_lens + appended + bs - 1, 0) // bs
+        self.stats["decode_kv_blocks_live"] += int(live.sum())
+        self.stats["decode_kv_blocks_passed"] += \
+            self.scfg.max_batch * self.scfg.max_blocks_per_req
 
     def _build_pf_args(self, pf):
         """Device args for the prefill half of a fused step (shared by the
@@ -1571,6 +1604,7 @@ class Scheduler:
             dec_bt[s] = self.block_tables[s]
             dec_lens[s] = run.ctx
             dec_vlens[s] = vlens[s]
+        self._count_kv_blocks(dec_lens, dec_vlens)
         device = (jnp.asarray(dec_toks), jnp.asarray(dec_bt),
                   jnp.asarray(dec_lens), jnp.asarray(dec_vlens),
                   *self._build_pf_args(pf))
@@ -1608,8 +1642,7 @@ class Scheduler:
         temps = np.zeros((self.scfg.max_batch,), np.float32)
         for s in dec_slots:
             temps[s] = self.slots[s].req.temperature
-        toks = self._sample(dec_logits, temps)
-        toks_np = np.asarray(toks)
+        toks_np = self._fetch(self._sample(dec_logits, temps))
         self.stats["decode_steps"] += 1
         for s in dec_slots:
             run = self.slots[s]
@@ -1635,7 +1668,9 @@ class Scheduler:
         self.stats["prefill_chunks"] += 1
         if run.score_from >= 0:
             self._score_chunk(run, ctx, c, pf_logits)
-        self._publish_full_blocks(s, run)
+        with self.trace.span("publish", track=self.track,
+                             step=self._consuming):
+            self._publish_full_blocks(s, run)
         if run.ctx < run.target.shape[-1]:
             return                             # more chunks to go
         if run.score_from >= 0:
@@ -1649,7 +1684,7 @@ class Scheduler:
                              uid=run.req.uid)
             return
         temps = np.asarray([run.req.temperature], np.float32)
-        tok = np.asarray(self._sample(pf_logits, temps))[0].tolist()
+        tok = self._fetch(self._sample(pf_logits, temps))[0].tolist()
         run.pending = tok
         self._emit(run, tok, first=True)
         if self._stopped(run, tok):
@@ -1669,7 +1704,7 @@ class Scheduler:
         t_hi = min(ctx + c, s_len - 1)         # inclusive
         if t_hi < t_lo:
             return
-        rows = np.asarray(pf_logits)[0, t_lo - 1 - ctx:t_hi - ctx]
+        rows = self._fetch(pf_logits)[0, t_lo - 1 - ctx:t_hi - ctx]
         golds = np.asarray(run.target[..., t_lo:t_hi + 1])
         from repro.eval.scoring import gold_logprobs
         lps = gold_logprobs(rows, golds)

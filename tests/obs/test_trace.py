@@ -54,11 +54,13 @@ def test_null_tracer_is_a_noop():
     before = len(NULL_TRACER)
     NULL_TRACER.event("enqueue", uid=1)
     NULL_TRACER.add_span("schedule", clock(), 0.001)
-    with NULL_TRACER.span("consume"):
+    with NULL_TRACER.span("consume", rest="retire"):
         pass
-    with NULL_TRACER.annotate("paged_step"):
+    with NULL_TRACER.span("device_step", annotation="paged_step"):
         pass
     assert len(NULL_TRACER) == before == 0
+    # the disabled span is the one shared null context: nothing is built
+    assert NULL_TRACER.span("stage", step=1) is NULL_TRACER.span("consume")
 
 
 def test_enabled_flag_gates_argument_construction():
@@ -69,10 +71,71 @@ def test_enabled_flag_gates_argument_construction():
 
 
 def test_annotate_without_profiler_is_null_context():
+    """Without ``jax_profiler`` a span names an annotation but opens none:
+    it records the span alone."""
     tr = Tracer(capacity=4)
-    with tr.annotate("paged_step"):
-        pass                                 # must not record anything
-    assert len(tr) == 0
+    ctx = tr.span("device_step", annotation="paged_step", step=3)
+    assert ctx.ann is None
+    with ctx:
+        pass
+    assert tr.kinds() == {"device_step": 1}
+    assert tr.events[0].args == {"step": 3}
+
+
+def test_span_opens_a_profiler_annotation():
+    """With ``jax_profiler`` each span holds a ``TraceAnnotation`` (the
+    names it gives them are read back from a real profile in
+    ``bench/tests/test_align.py``)."""
+    import jax
+    tr = Tracer(capacity=8, jax_profiler=True)
+    for ctx in (tr.span("consume"),
+                tr.span("device_step", annotation="paged_step")):
+        assert isinstance(ctx.ann, jax.profiler.TraceAnnotation)
+        with ctx:
+            pass
+    assert tr.kinds() == {"consume": 1, "device_step": 1}
+
+
+def test_rest_fills_the_uncovered_parts_of_a_span():
+    """``rest`` records the parts of a span that no span recorded inside
+    it on the same track covers: the children and the rest tile it."""
+    tr = Tracer(capacity=64)
+    with tr.span("consume", track=0, rest="retire", step=5):
+        with tr.span("device_wait", track=0, step=5):
+            pass
+        tr.event("finish", track=0, lane=1)          # instants hold nothing
+        tr.add_span("other_track", clock(), 1.0, track=1)
+        with tr.span("publish", track=0, step=5):
+            pass
+    spans = [e for e in tr.events if e.dur is not None and e.track == 0]
+    consume = [e for e in spans if e.kind == "consume"]
+    assert len(consume) == 1
+    c = consume[0]
+    inner = sorted((e.ts, e.ts + e.dur) for e in spans if e is not c)
+    assert {e.kind for e in spans} == {"consume", "device_wait", "publish",
+                                       "retire"}
+    assert all(e.args == {"step": 5} for e in spans)
+    # tiled: each piece starts where the last ended, first at the parent's
+    # start and last at its end
+    assert inner[0][0] == c.ts and inner[-1][1] == c.ts + c.dur
+    for (_, b), (a, _) in zip(inner, inner[1:]):
+        assert a == b
+
+
+def test_span_counts_the_compiles_it_held():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import compiles
+    compiles.install()
+    tr = Tracer(capacity=8)
+    f = jax.jit(lambda x: x * 3 + 1)
+    with tr.span("device_step", step=1):
+        f(jnp.ones((7, 3))).block_until_ready()
+    with tr.span("device_step", step=2):
+        f(jnp.ones((7, 3))).block_until_ready()
+    first, again = tr.events
+    assert first.args["compiles"] >= 1
+    assert again.args == {"step": 2}
 
 
 def test_chrome_trace_export_round_trip(tmp_path):

@@ -13,6 +13,7 @@ from repro.obs import (Histogram, MetricsRegistry, Tracer,
 from repro.serving.engine import PagedServeEngine, Request
 from repro.serving.replica import ReplicaConfig, ReplicatedServeEngine
 from repro.serving.scheduler import SchedulerConfig
+from repro.serving.spec_decode import SpecConfig
 
 CFG = ModelConfig(name="t", vocab_size=128, d_model=64, n_layers=2, n_heads=4,
                   n_kv_heads=2, d_ff=128, attn_chunk=16)
@@ -49,6 +50,107 @@ def test_tracing_on_matches_tracing_off_token_for_token():
     off = _drive(_paged(tracer=None))
     on = _drive(_paged(tracer=Tracer(capacity=4096)))
     assert on == off
+
+
+def test_tracing_on_matches_tracing_off_on_the_spec_path():
+    off = _drive(_paged(tracer=None, spec=SpecConfig(gamma=3)))
+    tr = Tracer(capacity=8192)
+    on = _drive(_paged(tracer=tr, spec=SpecConfig(gamma=3)))
+    assert on == off
+    assert tr.kinds().get("spec_round", 0) > 0
+
+
+# -- the step's phases ---------------------------------------------------------
+
+PARENT = {"stage": "schedule", "device_wait": "consume",
+          "publish": "consume", "retire": "consume"}
+
+
+@pytest.mark.parametrize("spec", [None, SpecConfig(gamma=3)],
+                         ids=["plain", "spec"])
+def test_step_phase_spans_nest_and_share_the_step(spec):
+    """Every phase span appears, each child lies inside its parent of the
+    same step, and every span of a step carries that step's ordinal; the
+    children and ``retire`` tile ``consume``."""
+    tr = Tracer(capacity=1 << 14)
+    eng = _paged(tracer=tr, spec=spec)
+    _drive(eng, max_new=6)
+    spans = [e for e in tr.events if e.dur is not None]
+    kinds = {e.kind for e in spans}
+    assert {"schedule", "stage", "device_step", "consume", "device_wait",
+            "publish", "retire"} <= kinds
+    assert all("step" in e.args for e in spans)
+    steps = eng.scheduler.stats["steps"]
+    by = {}
+    for e in spans:
+        by.setdefault(e.args["step"], []).append(e)
+    assert sorted(by) == list(range(1, steps + 1))
+    for step, group in by.items():
+        top = {k: [e for e in group if e.kind == k]
+               for k in ("schedule", "device_step", "consume")}
+        assert all(len(v) == 1 for v in top.values()), (step, top)
+        sch, dev, con = (top[k][0] for k in ("schedule", "device_step",
+                                             "consume"))
+        # the three phases follow one another
+        assert sch.ts + sch.dur <= dev.ts
+        assert dev.ts + dev.dur <= con.ts
+        for e in group:
+            if e.kind in PARENT:
+                p = top[PARENT[e.kind]][0]
+                assert p.ts <= e.ts and e.ts + e.dur <= p.ts + p.dur, \
+                    (step, e.kind)
+        inner = sorted((e.ts, e.ts + e.dur) for e in group
+                       if PARENT.get(e.kind) == "consume")
+        assert inner[0][0] == con.ts
+        assert inner[-1][1] == con.ts + con.dur
+        for (_, b), (a, _) in zip(inner, inner[1:]):
+            assert a == b
+
+
+def test_admit_carries_its_queue_wait():
+    tr = Tracer(capacity=4096)
+    _drive(_paged(tracer=tr, max_batch=2), max_new=4)
+    admits = [e for e in tr.events if e.kind == "admit"]
+    assert len(admits) == len(PROMPTS)
+    waits = [e.args["waited_ms"] for e in admits]
+    assert all(w >= 0 for w in waits)
+    # two slots for four requests: the last two wait for a slot to free
+    assert max(waits) > min(waits)
+
+
+# -- counters ------------------------------------------------------------------
+
+def test_decode_kv_block_counters_match_a_hand_count():
+    """One 30-token prompt, chunks of 16, five new tokens: two prefill
+    steps (the second emits the first token), then four decode steps that
+    attend to ctx + 1 = 31, 32, 33, 34 tokens: 2 + 2 + 3 + 3 = 10 blocks
+    of 16, against 4 x max_batch 4 x 8 table entries passed."""
+    eng = _paged(prefill_chunk=16, token_budget=16)
+    prompt = (np.arange(30, dtype=np.int32) * 3) % 128
+    eng.add_request(Request(uid=0, prompt=prompt, max_new_tokens=5))
+    eng.run()
+    st = eng.scheduler.stats
+    assert st["prefill_chunks"] == 2 and st["decode_steps"] == 4
+    assert st["decode_kv_blocks_live"] == 10
+    assert st["decode_kv_blocks_passed"] == 4 * 4 * 8
+
+
+def test_scheduler_counts_the_compiles_of_its_steps():
+    """The first engine of a shape compiles its programs inside its steps;
+    a second engine of the same shapes finds them compiled."""
+    cfg = ModelConfig(name="t-compiles", vocab_size=96, d_model=64,
+                      n_layers=1, n_heads=4, n_kv_heads=2, d_ff=128,
+                      attn_chunk=16)
+    params = init_params(cfg, KEY)
+    counts = []
+    for _ in range(2):
+        eng = PagedServeEngine(params, cfg, _scfg())
+        eng.add_request(Request(uid=0, prompt=PROMPTS[1] % 96,
+                                max_new_tokens=3))
+        eng.run()
+        counts.append(eng.scheduler.stats["compiles"])
+    assert counts[0] > 0
+    assert counts[1] == 0
 
 
 # -- satellite (a): wall-clock guard ------------------------------------------
