@@ -21,13 +21,15 @@ from .common import emit, timeit
 
 
 def _paged_pool(b, kh, d, n, t, seed=0):
+    """Pool leaves in the stored layout: code rows (N, T, KH*D), V affine
+    (KH, T, N) with blocks as lanes."""
     rs = np.random.RandomState(seed)
-    k_vals = jnp.asarray(rs.randint(-128, 128, (n, t, kh, d)), jnp.int8)
-    v_vals = jnp.asarray(rs.randint(-128, 128, (n, t, kh, d)), jnp.int8)
+    k_vals = jnp.asarray(rs.randint(-128, 128, (n, t, kh * d)), jnp.int8)
+    v_vals = jnp.asarray(rs.randint(-128, 128, (n, t, kh * d)), jnp.int8)
     k_scale = jnp.asarray(rs.uniform(0.01, 0.05, (b, kh, d)), jnp.float32)
     k_zero = jnp.asarray(rs.uniform(-2, 2, (b, kh, d)), jnp.float32)
-    v_scale = jnp.asarray(rs.uniform(0.01, 0.05, (n, t, kh, 1)), jnp.float32)
-    v_zero = jnp.asarray(rs.uniform(-2, 2, (n, t, kh, 1)), jnp.float32)
+    v_scale = jnp.asarray(rs.uniform(0.01, 0.05, (kh, t, n)), jnp.float32)
+    v_zero = jnp.asarray(rs.uniform(-2, 2, (kh, t, n)), jnp.float32)
     return k_vals, k_scale, k_zero, v_vals, v_scale, v_zero
 
 
@@ -87,10 +89,12 @@ def paged_suite_rows(smoke: bool = False):
             # the replaced path: dense-gather + dequantize the whole prefix,
             # concatenate the chunk, run masked flash attention over it
             f32 = jnp.float32
-            k_pre = ((k_vals[block_row].astype(f32) - k_zero.astype(f32))
+            rows = lambda x: x[block_row].reshape(m, t, kh, d).astype(f32)
+            lanes = lambda x: x[..., block_row].transpose(2, 1, 0)[..., None]
+            k_pre = ((rows(k_vals) - k_zero.astype(f32))
                      * k_scale.astype(f32)).reshape(m * t, kh, d)
-            v_pre = ((v_vals[block_row].astype(f32) - v_zero[block_row])
-                     * v_scale[block_row]).reshape(m * t, kh, d)
+            v_pre = ((rows(v_vals) - lanes(v_zero))
+                     * lanes(v_scale)).reshape(m * t, kh, d)
             k_cat = jnp.concatenate([k_pre[None], k_chunk.astype(f32)], axis=1)
             v_cat = jnp.concatenate([v_pre[None], v_chunk.astype(f32)], axis=1)
             pre_pos = jnp.arange(m * t)

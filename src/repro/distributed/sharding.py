@@ -273,11 +273,11 @@ def mesh_fingerprint(mesh: Optional[Mesh],
 # SSM ssd leaves shard the head axis.  Block/slot/token axes never shard —
 # the host-side allocator indexes them freely.
 _POOL_RULES: Dict[str, Tuple[Optional[str], ...]] = {
-    # GQA paged KV pool
-    "k_vals":  (None, None, None, "kv_heads", None),
-    "v_vals":  (None, None, None, "kv_heads", None),
-    "v_scale": (None, None, None, "kv_heads", None),
-    "v_zero":  (None, None, None, "kv_heads", None),
+    # GQA paged KV pool: codes (R, N+1, T, KH*D), V affine (R, KH, T, N+1)
+    "k_vals":  (None, None, None, "kv_heads"),
+    "v_vals":  (None, None, None, "kv_heads"),
+    "v_scale": (None, "kv_heads", None, None),
+    "v_zero":  (None, "kv_heads", None, None),
     "k_scale": (None, None, "kv_heads", None),
     "k_zero":  (None, None, "kv_heads", None),
     # MLA latent pool: latent channel axis is replicated
@@ -294,8 +294,15 @@ _POOL_RULES: Dict[str, Tuple[Optional[str], ...]] = {
 }
 
 
-def pool_spec(mesh: Mesh, name: str, shape: Tuple[int, ...]) -> P:
-    """PartitionSpec for one pool leaf; drops non-divisible axes."""
+# GQA code leaves whose last dim packs the kv heads with their channels
+_HEAD_PACKED = ("k_vals", "v_vals")
+
+
+def pool_spec(mesh: Mesh, name: str, shape: Tuple[int, ...],
+              heads: Optional[int] = None) -> P:
+    """PartitionSpec for one pool leaf; drops non-divisible axes.  ``heads``
+    is the entry's kv-head count: a dim that packs heads with their channels
+    splits only into whole heads."""
     axes = _POOL_RULES.get(name, (None,) * len(shape))
     if len(axes) != len(shape):
         axes = (None,) * len(shape)
@@ -308,7 +315,8 @@ def pool_spec(mesh: Mesh, name: str, shape: Tuple[int, ...]) -> P:
             continue
         cand = tuple(a for a in rules.get(ax, ()) if a in mesh.axis_names)
         size = int(np.prod([mesh.shape[a] for a in cand])) if cand else 1
-        if size > 1 and dim % size == 0:
+        unit = heads if heads is not None and name in _HEAD_PACKED else dim
+        if size > 1 and unit % size == 0:
             parts.append(cand[0] if len(cand) == 1 else tuple(cand))
         else:
             parts.append(None)
@@ -316,16 +324,16 @@ def pool_spec(mesh: Mesh, name: str, shape: Tuple[int, ...]) -> P:
 
 
 def tree_pool_shardings(mesh: Mesh, pool) -> "jax.tree_util.PyTreeDef":
-    """NamedSharding pytree for a paged-cache / state-pool dict keyed by the
-    *last* path component (pool dicts nest as ``{"p0": {"k_vals": ...}}``)."""
-    def visit(path, leaf):
-        name = str(getattr(path[-1], "key", None)
-                   or getattr(path[-1], "name", None)
-                   or str(path[-1]).lstrip(".")) if path else ""
-        if hasattr(leaf, "shape"):
-            return NamedSharding(mesh, pool_spec(mesh, name, leaf.shape))
-        return NamedSharding(mesh, P())
-    return jax.tree_util.tree_map_with_path(visit, pool)
+    """NamedSharding pytree for a paged-cache / state-pool dict (pool dicts
+    nest as ``{"p0": {"k_vals": ...}}``); a GQA entry's kv-head count is its
+    ``k_scale``'s second-to-last dim."""
+    def entry(leaves):
+        ks = leaves.get("k_scale")
+        heads = None if ks is None else int(ks.shape[-2])
+        return {name: NamedSharding(mesh, pool_spec(mesh, name, leaf.shape,
+                                                    heads))
+                for name, leaf in leaves.items()}
+    return {pkey: entry(leaves) for pkey, leaves in pool.items()}
 
 
 def tree_param_shardings(mesh: Mesh, params,

@@ -150,15 +150,15 @@ def paged_decode_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
     dense engine's oracle — golden-parity contract)."""
     pk = _use_pallas()
     if pk is not None:
-        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        ax = _split_axis("kv_heads", k_scale.shape[-2], q.shape[-2])
         specs = None if ax is None else (
             P(None, ax, None),          # q (B,H,D)
-            P(None, None, ax, None),    # k_vals (N,T,KH,D)
+            P(None, None, ax),          # k_vals (N,T,KH*D): whole heads
             P(None, ax, None),          # k_scale (B,KH,D)
             P(None, ax, None),          # k_zero
-            P(None, None, ax, None),    # v_vals
-            P(None, None, ax, None),    # v_scale (N,T,KH,1)
-            P(None, None, ax, None),    # v_zero
+            P(None, None, ax),          # v_vals
+            P(ax, None, None),          # v_scale (KH,T,N)
+            P(ax, None, None),          # v_zero
             P(None, None), P(None))     # block_tables, lengths
         fn = _on_mesh(partial(paged_kv_decode_attention, **pk), specs,
                       P(None, ax, None))
@@ -177,15 +177,15 @@ def paged_verify_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
     the greedy spec-decode golden contract.  q: (B,G,H,D) -> (B,G,H,D)."""
     pk = _use_pallas()
     if pk is not None:
-        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        ax = _split_axis("kv_heads", k_scale.shape[-2], q.shape[-2])
         specs = None if ax is None else (
             P(None, None, ax, None),    # q (B,G,H,D)
-            P(None, None, ax, None),    # k_vals
+            P(None, None, ax),          # k_vals (N,T,KH*D)
             P(None, ax, None),          # k_scale (B,KH,D)
             P(None, ax, None),          # k_zero
-            P(None, None, ax, None),    # v_vals
-            P(None, None, ax, None),    # v_scale
-            P(None, None, ax, None),    # v_zero
+            P(None, None, ax),          # v_vals
+            P(ax, None, None),          # v_scale (KH,T,N)
+            P(ax, None, None),          # v_zero
             P(None, None), P(None))     # block_tables, lengths
         fn = _on_mesh(partial(pa.paged_kv_verify_attention, **pk), specs,
                       P(None, None, ax, None))
@@ -231,15 +231,15 @@ def paged_prefix_chunk_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
     oracle elsewhere).  q: (1,C,H,D) -> (1,C,H,D) f32."""
     pk = _use_pallas()
     if pk is not None:
-        ax = _split_axis("kv_heads", k_vals.shape[-2], q.shape[-2])
+        ax = _split_axis("kv_heads", k_scale.shape[-2], q.shape[-2])
         specs = None if ax is None else (
             P(None, None, ax, None),    # q (1,C,H,D)
-            P(None, None, ax, None),    # k_vals (N,T,KH,D)
+            P(None, None, ax),          # k_vals (N,T,KH*D)
             P(ax, None),                # k_scale[slot] (KH,D)
             P(ax, None),                # k_zero[slot]
-            P(None, None, ax, None),    # v_vals
-            P(None, None, ax, None),    # v_scale
-            P(None, None, ax, None),    # v_zero
+            P(None, None, ax),          # v_vals
+            P(ax, None, None),          # v_scale (KH,T,N)
+            P(ax, None, None),          # v_zero
             P(None, None, ax, None),    # k_chunk (1,C,KH,D)
             P(None, None, ax, None),    # v_chunk
             P(None), P())               # block_row, ctx

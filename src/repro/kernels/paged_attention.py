@@ -39,19 +39,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.qtensor import unpack_nibbles
+from .kv_decode_attention import codes_f32, pick_lane, scale_lanes
 
 NEG_INF = -2.0e38
 
 
-def _codes_f32(raw: jax.Array, bits: int) -> jax.Array:
-    """Carrier bytes -> f32 code values.  Packed int4 (``bits == 4``)
-    unpacks nibbles with the same integer ops as the jnp oracles, so the
-    dequantized floats — and the whole attention output — stay bitwise equal
-    to the dense-gather reference for either codec."""
-    if bits == 4:
-        return unpack_nibbles(raw).astype(jnp.float32)
-    return raw.astype(jnp.float32)
+def _head(rows: jax.Array, hh, d: int) -> jax.Array:
+    """Head ``hh``'s ``d`` lanes of a (T, KH*d) block of pool rows, ``hh``
+    a grid index: a select over the static head slices, which is exact."""
+    out = rows[:, :d]
+    for i in range(1, rows.shape[1] // d):
+        out = jnp.where(hh == i, rows[:, i * d:(i + 1) * d], out)
+    return out
+
+
+def _dequant_block(k_ref, v_ref, vs_ref, vz_ref, ks, kz, hh, blk, *,
+                   d: int, lanes: int, bits: int):
+    """Head ``hh`` of one pool block, dequantized: K with the slot's frozen
+    per-channel affine, V with its per-token affine picked from the block's
+    lane of the scale tiles.  Nibble unpacking uses the same integer ops as
+    the jnp oracles, so the floats — and the whole attention output — stay
+    bitwise equal to the dense-gather reference for either codec."""
+    lane = blk % lanes
+    k = (_head(codes_f32(k_ref[0], bits), hh, d) - kz) * ks
+    v = ((_head(codes_f32(v_ref[0], bits), hh, d) - pick_lane(vz_ref[0], lane))
+         * pick_lane(vs_ref[0], lane))
+    return k, v
 
 
 def _softmax_rows(s: jax.Array) -> jax.Array:
@@ -118,14 +131,16 @@ def one_shot_vmem_bytes(rows: int, cols: int, scratch_widths,
 
 def _verify_kernel(bt_ref, len_ref, q_ref, ks_ref, kz_ref, k_ref, v_ref,
                    vs_ref, vz_ref, o_ref, kf_ref, vf_ref, *, n_blk: int,
-                   t: int, group: int, bits: int):
+                   t: int, group: int, d: int, lanes: int, bits: int):
     b_idx = pl.program_id(0)
+    hh = pl.program_id(1)
     m_idx = pl.program_id(2)
 
     # stream + dequantize this block once, shared by all G*group query rows
-    k = (_codes_f32(k_ref[0, 0], bits) - kz_ref[0, 0]) * ks_ref[0, 0]
+    k, v = _dequant_block(k_ref, v_ref, vs_ref, vz_ref, ks_ref[0, 0],
+                          kz_ref[0, 0], hh, bt_ref[b_idx, m_idx], d=d,
+                          lanes=lanes, bits=bits)
     kf_ref[pl.ds(m_idx * t, t), :] = k
-    v = (_codes_f32(v_ref[0, 0], bits) - vz_ref[0, 0]) * vs_ref[0, 0]
     vf_ref[pl.ds(m_idx * t, t), :] = v
 
     @pl.when(m_idx == n_blk - 1)
@@ -152,30 +167,28 @@ def paged_kv_verify_attention(q: jax.Array,
     """All G verify positions against the paged pool in one launch.
 
     q: (B, G, H, D); pool leaves as in ``paged_kv_decode_attention``
-    (k_vals/v_vals (N, T, KH, D/pack) codes, v_scale/v_zero (N, T, KH, 1),
+    (k_vals/v_vals (N, T, KH*D/pack) codes, v_scale/v_zero (KH, T, N),
     k_scale/k_zero (B, KH, D) per-slot); block_tables: (B, M);
     lengths: (B,) pre-verify context lengths -> (B, G, H, D) f32.
     """
     b, gq, h, d = q.shape
-    t, kh = k_vals.shape[1], k_vals.shape[2]
-    dp = k_vals.shape[3]                                  # carrier width
+    kh = k_scale.shape[-2]
+    t = k_vals.shape[1]
+    dp = k_vals.shape[2] // kh                            # carrier width
     bits = 8 if dp == d else 4
     m = block_tables.shape[1]
     g = h // kh
     rows = gq * g
+    lanes = scale_lanes(v_scale.shape[-1])
 
     # row r = j * group + gi  <->  verify position j, grouped query head gi
     q_r = q.reshape(b, gq, kh, g, d).transpose(0, 2, 1, 3, 4)
     q_r = _prescale_q(q_r.reshape(b, kh, rows, d), d)
-    k_r = k_vals.transpose(0, 2, 1, 3)                    # (N, KH, T, D)
-    v_r = v_vals.transpose(0, 2, 1, 3)
-    vs_r = v_scale.transpose(0, 2, 1, 3)                  # (N, KH, T, 1)
-    vz_r = v_zero.transpose(0, 2, 1, 3)
     ks_r = k_scale[:, :, None, :]                         # (B, KH, 1, D)
     kz_r = k_zero[:, :, None, :]
 
-    kernel = functools.partial(_verify_kernel, n_blk=m, t=t, group=g,
-                               bits=bits)
+    kernel = functools.partial(_verify_kernel, n_blk=m, t=t, group=g, d=d,
+                               lanes=lanes, bits=bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_tables, lengths
         grid=(b, kh, m),
@@ -183,14 +196,14 @@ def paged_kv_verify_attention(q: jax.Array,
             pl.BlockSpec((1, 1, rows, d), lambda bb, hh, mm, bt, ln: (bb, hh, 0, 0)),
             pl.BlockSpec((1, 1, 1, d), lambda bb, hh, mm, bt, ln: (bb, hh, 0, 0)),
             pl.BlockSpec((1, 1, 1, d), lambda bb, hh, mm, bt, ln: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, dp),
-                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, dp),
-                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, 1),
-                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, 1),
-                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], hh, 0, 0)),
+            pl.BlockSpec((1, t, kh * dp),
+                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], 0, 0)),
+            pl.BlockSpec((1, t, kh * dp),
+                         lambda bb, hh, mm, bt, ln: (bt[bb, mm], 0, 0)),
+            pl.BlockSpec((1, t, lanes), lambda bb, hh, mm, bt, ln: (
+                hh, 0, bt[bb, mm] // lanes)),
+            pl.BlockSpec((1, t, lanes), lambda bb, hh, mm, bt, ln: (
+                hh, 0, bt[bb, mm] // lanes)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, d),
                                lambda bb, hh, mm, bt, ln: (bb, hh, 0, 0)),
@@ -202,7 +215,7 @@ def paged_kv_verify_attention(q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, rows, d), jnp.float32),
         interpret=interpret,
-    )(block_tables, lengths, q_r, ks_r, kz_r, k_r, v_r, vs_r, vz_r)
+    )(block_tables, lengths, q_r, ks_r, kz_r, k_vals, v_vals, v_scale, v_zero)
     return out.reshape(b, kh, gq, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, gq, h, d)
 
@@ -214,9 +227,9 @@ def _mla_verify_kernel(bt_ref, len_ref, ql_ref, qr_ref, cs_ref, cz_ref,
     b_idx = pl.program_id(0)
     m_idx = pl.program_id(1)
 
-    c = (_codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
+    c = (codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
     cf_ref[pl.ds(m_idx * t, t), :] = c
-    kr = (_codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
+    kr = (codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
     krf_ref[pl.ds(m_idx * t, t), :] = kr
 
     @pl.when(m_idx == n_blk - 1)
@@ -297,12 +310,14 @@ def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
 
 def _chunk_kernel(br_ref, ctx_ref, q_ref, ks_ref, kz_ref, k_ref, v_ref,
                   vs_ref, vz_ref, kc_ref, vc_ref, o_ref, kf_ref, vf_ref, *,
-                  n_blk: int, t: int, group: int, bits: int):
+                  n_blk: int, t: int, group: int, d: int, lanes: int,
+                  bits: int):
+    hh = pl.program_id(0)
     m_idx = pl.program_id(1)
 
-    k = (_codes_f32(k_ref[0, 0], bits) - kz_ref[0]) * ks_ref[0]
+    k, v = _dequant_block(k_ref, v_ref, vs_ref, vz_ref, ks_ref[0], kz_ref[0],
+                          hh, br_ref[m_idx], d=d, lanes=lanes, bits=bits)
     kf_ref[pl.ds(m_idx * t, t), :] = k
-    v = (_codes_f32(v_ref[0, 0], bits) - vz_ref[0, 0]) * vs_ref[0, 0]
     vf_ref[pl.ds(m_idx * t, t), :] = v
 
     @pl.when(m_idx == n_blk - 1)
@@ -338,26 +353,24 @@ def paged_prefix_chunk_attention(q: jax.Array,
     prefix length -> (1, C, H, D) f32.
     """
     c, h, d = q.shape[1], q.shape[2], q.shape[3]
-    t, kh = k_vals.shape[1], k_vals.shape[2]
-    dp = k_vals.shape[3]                                  # carrier width
+    kh = k_scale.shape[-2]
+    t = k_vals.shape[1]
+    dp = k_vals.shape[2] // kh                            # carrier width
     bits = 8 if dp == d else 4
     m = block_row.shape[0]
     g = h // kh
     rows = c * g
+    lanes = scale_lanes(v_scale.shape[-1])
 
     # row r = ci * group + gi  <->  chunk position ci, grouped head gi
     q_r = q[0].reshape(c, kh, g, d).transpose(1, 0, 2, 3).reshape(kh, rows, d)
     q_r = _prescale_q(q_r, d)
     kc_r = k_chunk[0].transpose(1, 0, 2)                  # (KH, C, D)
     vc_r = v_chunk[0].transpose(1, 0, 2)
-    k_r = k_vals.transpose(0, 2, 1, 3)                    # (N, KH, T, D)
-    v_r = v_vals.transpose(0, 2, 1, 3)
-    vs_r = v_scale.transpose(0, 2, 1, 3)                  # (N, KH, T, 1)
-    vz_r = v_zero.transpose(0, 2, 1, 3)
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape(1)
 
-    kernel = functools.partial(_chunk_kernel, n_blk=m, t=t, group=g,
-                               bits=bits)
+    kernel = functools.partial(_chunk_kernel, n_blk=m, t=t, group=g, d=d,
+                               lanes=lanes, bits=bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_row, ctx
         grid=(kh, m),
@@ -365,10 +378,12 @@ def paged_prefix_chunk_attention(q: jax.Array,
             pl.BlockSpec((1, rows, d), lambda hh, mm, br, cx: (hh, 0, 0)),
             pl.BlockSpec((1, 1, d), lambda hh, mm, br, cx: (hh, 0, 0)),
             pl.BlockSpec((1, 1, d), lambda hh, mm, br, cx: (hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, dp), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, dp), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, 1), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
-            pl.BlockSpec((1, 1, t, 1), lambda hh, mm, br, cx: (br[mm], hh, 0, 0)),
+            pl.BlockSpec((1, t, kh * dp), lambda hh, mm, br, cx: (br[mm], 0, 0)),
+            pl.BlockSpec((1, t, kh * dp), lambda hh, mm, br, cx: (br[mm], 0, 0)),
+            pl.BlockSpec((1, t, lanes),
+                         lambda hh, mm, br, cx: (hh, 0, br[mm] // lanes)),
+            pl.BlockSpec((1, t, lanes),
+                         lambda hh, mm, br, cx: (hh, 0, br[mm] // lanes)),
             pl.BlockSpec((1, c, d), lambda hh, mm, br, cx: (hh, 0, 0)),
             pl.BlockSpec((1, c, d), lambda hh, mm, br, cx: (hh, 0, 0)),
         ],
@@ -381,8 +396,8 @@ def paged_prefix_chunk_attention(q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kh, rows, d), jnp.float32),
         interpret=interpret,
-    )(block_row, ctx_arr, q_r, k_scale[:, None], k_zero[:, None], k_r, v_r,
-      vs_r, vz_r, kc_r, vc_r)
+    )(block_row, ctx_arr, q_r, k_scale[:, None], k_zero[:, None], k_vals,
+      v_vals, v_scale, v_zero, kc_r, vc_r)
     return out.reshape(kh, c, g, d).transpose(1, 0, 2, 3).reshape(1, c, h, d)
 
 
@@ -392,9 +407,9 @@ def _mla_chunk_kernel(br_ref, ctx_ref, ql_ref, qr_ref, cs_ref, cz_ref,
                       heads: int, dn: int, dr: int, bits: int):
     m_idx = pl.program_id(0)
 
-    c = (_codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
+    c = (codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
     cf_ref[pl.ds(m_idx * t, t), :] = c
-    kr = (_codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
+    kr = (codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
     krf_ref[pl.ds(m_idx * t, t), :] = kr
 
     @pl.when(m_idx == n_blk - 1)

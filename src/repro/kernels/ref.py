@@ -22,6 +22,19 @@ def _unpack_pool(pool: jax.Array, full_dim: int) -> jax.Array:
     return unpack_nibbles(pool)
 
 
+def _pool_heads(pool: jax.Array, kh: int, full_dim: int) -> jax.Array:
+    """(N, T, KH*Dp) code rows -> (N, T, KH, D) codes, nibble-unpacked when
+    the codec packed them."""
+    return _unpack_pool(pool.reshape(*pool.shape[:-1], kh, -1), full_dim)
+
+
+def _lane_rows(pool: jax.Array, ids: jax.Array) -> jax.Array:
+    """Blocks ``ids`` of a (KH, T, N) V scale/zero pool, whose blocks are its
+    lanes, as (*ids.shape, T, KH, 1): the layout of the gathered codes."""
+    x = pool[:, :, ids]                                   # (KH, T, *ids)
+    return jnp.moveaxis(x, (0, 1), (-1, -2))[..., None]
+
+
 def fused_quant_ref(x: jax.Array, eps: float = 1e-8):
     """Row-wise dynamic symmetric INT8 quantization (paper Alg. 1 lines 2+5).
 
@@ -80,19 +93,21 @@ def paged_kv_decode_attention_ref(q: jax.Array,
     dense oracle (identical float path — the scheduler's golden-parity tests
     rely on this).
 
-    q: (B,H,D); k_vals/v_vals: (N,T,KH,Dp) code pool (Dp == D for INT8,
-    D // 2 nibble-packed for INT4); v_scale/v_zero: (N,T,KH,1); k_scale/
+    q: (B,H,D); k_vals/v_vals: (N,T,KH*Dp) code pool (Dp == D for INT8,
+    D // 2 nibble-packed for INT4); v_scale/v_zero: (KH,T,N); k_scale/
     k_zero: (B,KH,D) per-slot; block_tables: (B,M); lengths: (B,) -> (B,H,D).
     """
     b, m = block_tables.shape
     t = k_vals.shape[1]
-    d = q.shape[-1]
-    k_vals = _unpack_pool(k_vals, d)
-    v_vals = _unpack_pool(v_vals, d)
+    kh, d = k_scale.shape[-2], q.shape[-1]
+    k_vals = _pool_heads(k_vals, kh, d)
+    v_vals = _pool_heads(v_vals, kh, d)
     gather = lambda pool: pool[block_tables].reshape(b, m * t, *pool.shape[2:])
+    scales = lambda pool: _lane_rows(pool, block_tables).reshape(
+        b, m * t, kh, 1)
     return kv_decode_attention_ref(
         q, gather(k_vals), k_scale[:, None], k_zero[:, None],
-        gather(v_vals), gather(v_scale), gather(v_zero), lengths)
+        gather(v_vals), scales(v_scale), scales(v_zero), lengths)
 
 
 def paged_kv_verify_attention_ref(q: jax.Array,
@@ -114,12 +129,14 @@ def paged_kv_verify_attention_ref(q: jax.Array,
     b, m = block_tables.shape
     t = k_vals.shape[1]
     g = q.shape[1]
-    d = q.shape[-1]
-    k_vals = _unpack_pool(k_vals, d)
-    v_vals = _unpack_pool(v_vals, d)
+    kh, d = k_scale.shape[-2], q.shape[-1]
+    k_vals = _pool_heads(k_vals, kh, d)
+    v_vals = _pool_heads(v_vals, kh, d)
     gather = lambda pool: pool[block_tables].reshape(b, m * t, *pool.shape[2:])
+    scales = lambda pool: _lane_rows(pool, block_tables).reshape(
+        b, m * t, kh, 1)
     kg, vg = gather(k_vals), gather(v_vals)
-    vsg, vzg = gather(v_scale), gather(v_zero)
+    vsg, vzg = scales(v_scale), scales(v_zero)
     ks, kz = k_scale[:, None], k_zero[:, None]
     outs = [kv_decode_attention_ref(q[:, j], kg, ks, kz, vg, vsg, vzg,
                                     lengths + j + 1)
@@ -174,8 +191,8 @@ def paged_prefix_chunk_attention_ref(q: jax.Array,
     chunk was already written into the pool before attention runs), padding
     query lanes just see their causal prefix and are never read.
 
-    q: (1,C,H,D); k_vals/v_vals: (N,T,KH,D) int8 pool; k_scale/k_zero:
-    (KH,D) the slot's frozen affine; v_scale/v_zero: (N,T,KH,1);
+    q: (1,C,H,D); k_vals/v_vals: (N,T,KH*D) int8 pool; k_scale/k_zero:
+    (KH,D) the slot's frozen affine; v_scale/v_zero: (KH,T,N);
     k_chunk/v_chunk: (1,C,KH,D) fp; block_row: (M,); ctx: () int32
     -> (1,C,H,D) f32.
     """
@@ -183,13 +200,13 @@ def paged_prefix_chunk_attention_ref(q: jax.Array,
     kh = k_chunk.shape[2]
     g = h // kh
     m, t = block_row.shape[0], k_vals.shape[1]
-    k_vals = _unpack_pool(k_vals, d)
-    v_vals = _unpack_pool(v_vals, d)
+    k_vals = _pool_heads(k_vals, kh, d)
+    v_vals = _pool_heads(v_vals, kh, d)
     f32 = jnp.float32
     k_pre = ((k_vals[block_row].astype(f32) - k_zero.astype(f32))
              * k_scale.astype(f32)).reshape(m * t, kh, d)
-    v_pre = ((v_vals[block_row].astype(f32) - v_zero[block_row])
-             * v_scale[block_row]).reshape(m * t, kh, d)
+    v_pre = ((v_vals[block_row].astype(f32) - _lane_rows(v_zero, block_row))
+             * _lane_rows(v_scale, block_row)).reshape(m * t, kh, d)
     k_all = jnp.concatenate([k_pre, k_chunk[0].astype(f32)], axis=0)
     v_all = jnp.concatenate([v_pre, v_chunk[0].astype(f32)], axis=0)
     qg = q[0].reshape(c, kh, g, d).astype(f32) / jnp.sqrt(d).astype(f32)

@@ -650,7 +650,7 @@ def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
         if spec.mixer == "attn":
             entry = pool_blk[f"p{i}"]
             q, k, v = qkv_project(p["attn"], x, cfg, positions)
-            trash = entry["k_vals"].shape[0] - 1       # (N+1, T, KH, D)
+            trash = entry["k_vals"].shape[0] - 1       # (N+1, T, KH*D)
             for j in range(g):
                 bt_j = jnp.where((j < vlens)[:, None], block_tables, trash)
                 entry = pgc.gqa_paged_append(entry, k[:, j], v[:, j],

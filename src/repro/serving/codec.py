@@ -82,9 +82,20 @@ def get_codec(codec) -> CacheCodec:
 # ---------------------------------------------------------------------------
 
 # Pool-entry leaves holding integer codes (block axis 1) vs. the per-token
-# f32 affine rows that must survive demotion alongside them.
+# f32 affine rows that must survive demotion alongside them (block axis
+# last: ``paged_cache`` stores them (R, KH, T, N+1), blocks as lanes).
 CODE_LEAVES = ("k_vals", "v_vals", "c_vals", "kr_vals")
 PAIR_LEAVES = ("v_scale", "v_zero")
+
+
+def block_axis(name: str) -> int:
+    """Axis of stacked pool leaf ``name`` that indexes pool blocks."""
+    return -1 if name in PAIR_LEAVES else 1
+
+
+def block_index(name: str, ids):
+    """Index expression selecting pool block(s) ``ids`` of leaf ``name``."""
+    return (Ellipsis, ids) if name in PAIR_LEAVES else (slice(None), ids)
 
 _BF16_MAX = 3.0e38  # clamp before bf16 cast: keeps packed halves finite, so
                     # the f32 bit-carrier can never form a NaN pattern
@@ -162,8 +173,8 @@ def _demote_pair_impl(pool, src_a, src_b, dst):
         for name in PAIR_LEAVES:
             if name in entry:
                 arr = entry[name]
-                new[name] = arr.at[:, dst].set(
-                    pack_f32_pair(arr[:, src_a], arr[:, src_b]))
+                new[name] = arr.at[..., dst].set(
+                    pack_f32_pair(arr[..., src_a], arr[..., src_b]))
         out[pkey] = new
     return out
 
@@ -179,7 +190,8 @@ def _promote_impl(pool, src, half, dst):
         for name in PAIR_LEAVES:
             if name in entry:
                 arr = entry[name]
-                new[name] = arr.at[:, dst].set(unpack_f32_pair(arr[:, src], half))
+                new[name] = arr.at[..., dst].set(
+                    unpack_f32_pair(arr[..., src], half))
         out[pkey] = new
     return out
 

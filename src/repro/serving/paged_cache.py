@@ -9,10 +9,10 @@ CacheCodec` — ``int8`` (one code per byte, the layout below) or packed
 ``int4`` (two codes per byte: every ``*_vals`` last dim halves while the
 scale rows keep the full dim, which is how readers infer the codec):
 
-  GQA:  k_vals  codes (R, N+1, T, KH, D/pack)  block pool (last = trash)
-        v_vals  codes (R, N+1, T, KH, D/pack)
-        v_scale f32   (R, N+1, T, KH, 1)   per-token affine V (online)
-        v_zero  f32   (R, N+1, T, KH, 1)
+  GQA:  k_vals  codes (R, N+1, T, KH*D/pack)  block pool (last = trash)
+        v_vals  codes (R, N+1, T, KH*D/pack)
+        v_scale f32   (R, KH, T, N+1)      per-token affine V (online)
+        v_zero  f32   (R, KH, T, N+1)
         k_scale f32   (R, B,   KH, D)      per-*slot* per-channel K affine,
         k_zero  f32   (R, B,   KH, D)      frozen at the first prefill chunk
   MLA:  c_vals  codes (R, N+1, T, rkv/pack) + per-slot scale/zero (R, B, rkv)
@@ -20,7 +20,12 @@ scale rows keep the full dim, which is how readers infer the codec):
 
 ``R`` is the scan-repeat axis (pattern positions nest inside, exactly like
 the dense cache); ``N`` is the shared block count, ``T`` the tokens/block,
-``B`` the decode-batch width.  A request owns a row of a host-side block
+``B`` the decode-batch width.  The GQA leaves are shaped so that the TPU
+stores them row-major, exactly as the paged kernels DMA them: a token's
+heads sit side by side in one lane row of the value leaves, and the V
+scale/zero rows keep their blocks as lanes (the order the chip would
+otherwise pick for a ``(.., T, KH, 1)`` leaf, and then relayout around every
+kernel launch).  A request owns a row of a host-side block
 table mapping its logical block index -> pool block id; block ``N`` is a
 write-off trash block that absorbs stores from padded / inactive lanes so the
 jitted step needs no scatter masking.
@@ -52,14 +57,16 @@ import numpy as np
 
 from repro.core.qtensor import int_range, pack_nibbles, unpack_nibbles
 from repro.models.config import ModelConfig
-from repro.serving.codec import STORAGE_DTYPE, get_codec
+from repro.serving.codec import (STORAGE_DTYPE, block_axis, block_index,
+                                 get_codec)
 
 TRASH = -1  # host-side marker; resolved to the pool's trash block id on use
 
 # leaf-name partition of a pool entry: BLOCK_LEAVES are indexed by pool block
-# id on axis 1 (copied on CoW, shared on a prefix hit); SLOT_SCALE_LEAVES are
-# indexed by decode slot on axis 1 (snapshotted at publish / restored on hit,
-# since the frozen K affine travels with the request, not the block)
+# id (copied on CoW, shared on a prefix hit) where ``codec.block_index`` says;
+# SLOT_SCALE_LEAVES are indexed by decode slot on axis 1 (snapshotted at
+# publish / restored on hit, since the frozen K affine travels with the
+# request, not the block)
 BLOCK_LEAVES = ("k_vals", "v_vals", "v_scale", "v_zero", "c_vals", "kr_vals")
 SLOT_SCALE_LEAVES = ("k_scale", "k_zero", "c_scale", "c_zero",
                      "kr_scale", "kr_zero")
@@ -92,7 +99,7 @@ def init_paged_cache(cfg: ModelConfig, pcfg: PagedCacheConfig,
     conv/SSD state lives in the slot pool (``state_pool.init_state_pool``),
     so hybrid patterns simply skip those positions here.  ``codec`` picks
     the code layout: packed codecs shrink every ``*_vals`` last dim by the
-    pack factor while scale rows keep the full dim."""
+    pack factor while scale rows keep the full dim (per head)."""
     cd = get_codec(codec)
     r = cfg.n_repeats
     npool = pcfg.num_blocks + 1                     # + trash block
@@ -103,10 +110,10 @@ def init_paged_cache(cfg: ModelConfig, pcfg: PagedCacheConfig,
             kh, d = cfg.kv_heads, cfg.hd
             dp = cd.packed_dim(d)
             entries[f"p{i}"] = {
-                "k_vals": jnp.zeros((r, npool, t, kh, dp), STORAGE_DTYPE),
-                "v_vals": jnp.zeros((r, npool, t, kh, dp), STORAGE_DTYPE),
-                "v_scale": jnp.zeros((r, npool, t, kh, 1), jnp.float32),
-                "v_zero": jnp.zeros((r, npool, t, kh, 1), jnp.float32),
+                "k_vals": jnp.zeros((r, npool, t, kh * dp), STORAGE_DTYPE),
+                "v_vals": jnp.zeros((r, npool, t, kh * dp), STORAGE_DTYPE),
+                "v_scale": jnp.zeros((r, kh, t, npool), jnp.float32),
+                "v_zero": jnp.zeros((r, kh, t, npool), jnp.float32),
                 "k_scale": jnp.ones((r, b, kh, d), jnp.float32),
                 "k_zero": jnp.zeros((r, b, kh, d), jnp.float32),
             }
@@ -126,9 +133,11 @@ def init_paged_cache(cfg: ModelConfig, pcfg: PagedCacheConfig,
 
 def _entry_bits(entry: Dict[str, jax.Array]) -> int:
     """Infer the codec bitwidth from leaf shapes: a packed value leaf's last
-    dim is half its scale row's (scales always keep the full channel dim)."""
+    dim is half its scale rows' (scales always keep the full channel dim;
+    the GQA value row holds every kv head's channels)."""
     if "k_vals" in entry:
-        return 8 if entry["k_vals"].shape[-1] == entry["k_scale"].shape[-1] else 4
+        kh, d = entry["k_scale"].shape[-2:]
+        return 8 if entry["k_vals"].shape[-1] == kh * d else 4
     return 8 if entry["c_vals"].shape[-1] == entry["c_scale"].shape[-1] else 4
 
 
@@ -607,10 +616,23 @@ def gqa_chunk_write(entry: Dict[str, jax.Array], k: jax.Array, v: jax.Array, *,
 
     trash = entry["k_vals"].shape[0] - 1
     bids, offs = _scatter_ids(block_row, ctx, chunk_len, c, block_size, trash)
-    new["k_vals"] = entry["k_vals"].at[bids, offs].set(k_q)
-    new["v_vals"] = entry["v_vals"].at[bids, offs].set(v_q)
-    new["v_scale"] = entry["v_scale"].at[bids, offs].set(v_scale.astype(jnp.float32))
-    new["v_zero"] = entry["v_zero"].at[bids, offs].set(v_zero.astype(jnp.float32))
+    return _store_tokens(new, bids, offs, k_q, v_q, v_scale, v_zero)
+
+
+def _store_tokens(entry, bids, offs, k_q, v_q, v_scale, v_zero):
+    """Write L tokens' codes (L, KH, D/pack) and V affine (L, KH, 1) at pool
+    blocks ``bids`` / offsets ``offs`` (L,): one lane row per token in the
+    value leaves, one lane per block in the scale leaves."""
+    n, kh = k_q.shape[:2]
+    new = dict(entry)
+    new["k_vals"] = entry["k_vals"].at[bids, offs].set(k_q.reshape(n, -1))
+    new["v_vals"] = entry["v_vals"].at[bids, offs].set(v_q.reshape(n, -1))
+    # one scalar per (head, token): a scatter with no window, which XLA runs
+    # on the leaf as stored (a window over KH would relayout it heads-minor)
+    heads = jnp.arange(kh)[:, None]
+    for name, x in (("v_scale", v_scale), ("v_zero", v_zero)):
+        new[name] = entry[name].at[heads, offs, bids].set(
+            x[..., 0].astype(jnp.float32).T)
     return new
 
 
@@ -642,24 +664,20 @@ def gqa_paged_append(entry: Dict[str, jax.Array], k_t: jax.Array, v_t: jax.Array
     safe = jnp.clip(lengths // block_size, 0, block_tables.shape[1] - 1)
     bids = block_tables[bidx, safe]
     offs = lengths % block_size
-    new = dict(entry)
-    new["k_vals"] = entry["k_vals"].at[bids, offs].set(k_q)
-    new["v_vals"] = entry["v_vals"].at[bids, offs].set(v_q)
-    new["v_scale"] = entry["v_scale"].at[bids, offs].set(v_scale)
-    new["v_zero"] = entry["v_zero"].at[bids, offs].set(v_zero)
-    return new
+    return _store_tokens(entry, bids, offs, k_q, v_q, v_scale, v_zero)
 
 
 def gqa_gather_prefix(entry: Dict[str, jax.Array], block_row: jax.Array,
                       slot: jax.Array, dtype):
     """Dequantize one request's cached prefix: -> k, v (M*T, KH, D)."""
-    k_q = entry["k_vals"][block_row]                 # (M,T,KH,D/pack)
-    v_q = entry["v_vals"][block_row]
+    kh = entry["k_scale"].shape[-2]
+    m, t = block_row.shape[0], entry["k_vals"].shape[1]
+    k_q = entry["k_vals"][block_row].reshape(m, t, kh, -1)   # (M,T,KH,D/pack)
+    v_q = entry["v_vals"][block_row].reshape(m, t, kh, -1)
     if _entry_bits(entry) == 4:
         k_q, v_q = unpack_nibbles(k_q), unpack_nibbles(v_q)
-    vs = entry["v_scale"][block_row]
-    vz = entry["v_zero"][block_row]
-    m, t = k_q.shape[0], k_q.shape[1]
+    vs = entry["v_scale"][..., block_row].transpose(2, 1, 0)[..., None]
+    vz = entry["v_zero"][..., block_row].transpose(2, 1, 0)[..., None]
     ks = entry["k_scale"][slot]                      # (KH,D)
     kz = entry["k_zero"][slot]
     k = ((k_q.astype(jnp.float32) - kz) * ks).reshape(m * t, *k_q.shape[2:])
@@ -802,7 +820,8 @@ def copy_pool_block(pool, src, dst):
         new = dict(entry)
         for name in BLOCK_LEAVES:
             if name in entry:
-                new[name] = entry[name].at[:, dst].set(entry[name][:, src])
+                at_dst, at_src = block_index(name, dst), block_index(name, src)
+                new[name] = entry[name].at[at_dst].set(entry[name][at_src])
         out[pkey] = new
     return out
 
@@ -853,7 +872,7 @@ def per_block_nbytes(pool) -> int:
         for name in BLOCK_LEAVES:
             if name in entry:
                 leaf = entry[name]
-                total += int(leaf.nbytes) // int(leaf.shape[1])
+                total += int(leaf.nbytes) // int(leaf.shape[block_axis(name)])
     return total
 
 

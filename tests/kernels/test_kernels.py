@@ -75,17 +75,21 @@ def test_kv_decode_attention_sweep(b, s, h, kh, d, chunk):
 
 @pytest.mark.parametrize("b,h,kh,d,n,t,m", [(2, 8, 4, 32, 10, 16, 4),
                                             (3, 4, 1, 64, 6, 8, 3),
-                                            (1, 6, 2, 16, 5, 4, 5)])
+                                            (1, 6, 2, 16, 5, 4, 5),
+                                            (3, 4, 2, 16, 300, 4, 6)])
 def test_paged_kv_decode_attention_sweep(b, h, kh, d, n, t, m):
-    """Gather-by-block-table Pallas kernel vs the dense-gather oracle."""
+    """Gather-by-block-table Pallas kernel vs the dense-gather oracle, on the
+    stored pool layout: code rows (N, T, KH*D), V affine (KH, T, N) whose
+    lanes are the blocks (n = 300 spans three 128-lane tiles, the last one
+    partial)."""
     q = jax.random.normal(KEY, (b, h, d))
     k_pool = jax.random.normal(jax.random.PRNGKey(1), (1, n * t, kh, d))
     v_pool = jax.random.normal(jax.random.PRNGKey(2), (1, n * t, kh, d))
     qk, qv = quantize_kv(k_pool, v_pool)
-    k_vals = qk.values.reshape(n, t, kh, d)
-    v_vals = qv.values.reshape(n, t, kh, d)
-    v_scale = qv.scale.reshape(n, t, kh, 1)
-    v_zero = qv.zero.reshape(n, t, kh, 1)
+    k_vals = qk.values.reshape(n, t, kh * d)
+    v_vals = qv.values.reshape(n, t, kh * d)
+    v_scale = qv.scale.reshape(n, t, kh).T
+    v_zero = qv.zero.reshape(n, t, kh).T
     # per-slot frozen K affine (slightly different per batch row)
     k_scale = (jnp.broadcast_to(qk.scale[0], (b, kh, d))
                * jnp.linspace(0.9, 1.1, b)[:, None, None])

@@ -24,6 +24,16 @@ from repro.kernels.paged_attention import (mla_paged_prefix_chunk_attention,
 KEY = jax.random.PRNGKey(0)
 
 
+def _stored(k_codes, k_scale, k_zero, v_codes, v_scale, v_zero):
+    """Token-major pool leaves (N, T, KH, Dp) / (N, T, KH, 1) in the stored
+    layout: code rows (N, T, KH*Dp), V affine (KH, T, N) with blocks as
+    lanes."""
+    n, t = k_codes.shape[:2]
+    return (k_codes.reshape(n, t, -1), k_scale, k_zero,
+            v_codes.reshape(n, t, -1), v_scale.reshape(n, t, -1).T,
+            v_zero.reshape(n, t, -1).T)
+
+
 def _gqa_pool(b, kh, d, n, t, seed=1):
     k_pool = jax.random.normal(jax.random.PRNGKey(seed), (1, n * t, kh, d))
     v_pool = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, n * t, kh, d))
@@ -31,9 +41,8 @@ def _gqa_pool(b, kh, d, n, t, seed=1):
     k_scale = (jnp.broadcast_to(qk.scale[0], (b, kh, d))
                * jnp.linspace(0.9, 1.1, b)[:, None, None])
     k_zero = jnp.broadcast_to(qk.zero[0], (b, kh, d))
-    return (qk.values.reshape(n, t, kh, d), k_scale, k_zero,
-            qv.values.reshape(n, t, kh, d), qv.scale.reshape(n, t, kh, 1),
-            qv.zero.reshape(n, t, kh, 1))
+    return _stored(qk.values.reshape(n, t, kh, d), k_scale, k_zero,
+                   qv.values.reshape(n, t, kh, d), qv.scale, qv.zero)
 
 
 def _mla_pool(b, rkv, dr, n, t, seed=3):
@@ -136,6 +145,56 @@ def test_paged_prefix_chunk_attention_exact(ctx_kind, b_unused, h, kh, d, n,
     np.testing.assert_array_equal(np.asarray(out), np.asarray(outr))
 
 
+def _relocate(kv, ids, n_wide, seed=10):
+    """The pool ``kv`` with its blocks moved to ids ``ids`` of an
+    ``n_wide``-block pool; every other block holds random codes and NaN V
+    affine, which no kernel may read."""
+    k_vals, k_scale, k_zero, v_vals, v_scale, v_zero = kv
+    rs = np.random.RandomState(seed)
+    codes = lambda x: jnp.asarray(
+        rs.randint(-128, 128, size=(n_wide,) + x.shape[1:]), x.dtype
+    ).at[ids].set(x)
+    lanes = lambda x: jnp.full(x.shape[:-1] + (n_wide,), jnp.nan,
+                               x.dtype).at[..., ids].set(x)
+    return (codes(k_vals), k_scale, k_zero, codes(v_vals), lanes(v_scale),
+            lanes(v_zero))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("kernel", ["decode", "verify", "chunk"])
+def test_paged_kernels_read_blocks_past_first_scale_tile(kernel, bits):
+    """The V scale/zero leaves keep blocks as lanes, read 128 to a tile: a
+    pool whose blocks sit past the first tile, one of them in a partial last
+    tile, gives bitwise the output of the same blocks at low ids."""
+    b, h, kh, d, n, t, m, g, c = 3, 4, 2, 16, 6, 4, 4, 2, 8
+    kv = (_gqa_pool if bits == 8 else _gqa_pool_int4)(b, kh, d, n, t)
+    ids = jnp.asarray([1, 127, 128, 200, 256, 299], jnp.int32)
+    wide = _relocate(kv, ids, 300)
+    rs = np.random.RandomState(11)
+    tables = jnp.asarray(rs.randint(0, n, size=(b, m)), jnp.int32)
+    lengths = jnp.asarray([t, t + t // 2, (m - 1) * t], jnp.int32)
+    if kernel == "decode":
+        run = lambda pool, tb: paged_kv_decode_attention(
+            jax.random.normal(KEY, (b, h, d)), *pool, tb, lengths,
+            interpret=True)
+    elif kernel == "verify":
+        run = lambda pool, tb: paged_kv_verify_attention(
+            jax.random.normal(KEY, (b, g, h, d)), *pool, tb, lengths,
+            interpret=True)
+    else:
+        def run(pool, tb):
+            k_vals, k_scale, k_zero, v_vals, v_scale, v_zero = pool
+            return paged_prefix_chunk_attention(
+                jax.random.normal(KEY, (1, c, h, d)), k_vals, k_scale[0],
+                k_zero[0], v_vals, v_scale, v_zero,
+                jax.random.normal(jax.random.PRNGKey(11), (1, c, kh, d)),
+                jax.random.normal(jax.random.PRNGKey(12), (1, c, kh, d)),
+                tb[0], jnp.asarray(t + 3, jnp.int32), interpret=True)
+    out = np.asarray(run(wide, ids[tables]))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, np.asarray(run(kv, tables)))
+
+
 @pytest.mark.parametrize("ctx_val", [5, 16, 44])
 def test_mla_paged_prefix_chunk_attention_exact(ctx_val):
     h, rkv, dn, dr, n, t, m, c = 4, 16, 16, 8, 8, 16, 3, 12
@@ -172,8 +231,8 @@ def _gqa_pool_int4(b, kh, d, n, t, seed=5):
     v_scale = jnp.asarray(rs.uniform(0.02, 0.06, size=(n, t, kh, 1)),
                           jnp.float32)
     v_zero = jnp.asarray(rs.uniform(-2, 2, size=(n, t, kh, 1)), jnp.float32)
-    return (pack_nibbles(k_codes), k_scale, k_zero,
-            pack_nibbles(v_codes), v_scale, v_zero)
+    return _stored(pack_nibbles(k_codes), k_scale, k_zero,
+                   pack_nibbles(v_codes), v_scale, v_zero)
 
 
 def _mla_pool_int4(b, rkv, dr, n, t, seed=6):
@@ -194,7 +253,7 @@ def _mla_pool_int4(b, rkv, dr, n, t, seed=6):
 def test_paged_decode_attention_int4_exact(b, h, kh, d, n, t, m):
     q = jax.random.normal(KEY, (b, h, d))
     kv = _gqa_pool_int4(b, kh, d, n, t)
-    assert kv[0].shape[-1] == d // 2            # really packed
+    assert kv[0].shape[-1] == kh * d // 2       # really packed
     rs = np.random.RandomState(5)
     tables = jnp.asarray(rs.randint(0, n, size=(b, m)), jnp.int32)
     lengths = jnp.asarray(rs.randint(1, m * t + 1, size=(b,)), jnp.int32)

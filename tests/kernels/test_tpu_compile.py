@@ -9,12 +9,15 @@ rope 32, 40 heads).  The topology is described inside a fixture, never at
 import: only one process may load the TPU library at a time.
 """
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import minicpm3_4b, qwen2_0_5b, qwen3_1_7b
+from repro.serving import paged_cache as pgc
 from repro.serving.scheduler import (SchedulerConfig, _kernel_vmem_bytes,
                                      ensure_kernels_fit)
 from repro.serving.spec_decode import SpecConfig
@@ -57,8 +60,8 @@ def shape(topo):
 def _gqa_pool(S, kh, d, bits, per_slot):
     dp = d if bits == 8 else d // 2
     scale = (B, kh, d) if per_slot else (kh, d)
-    return (S((N, T, kh, dp), I8), S(scale), S(scale), S((N, T, kh, dp), I8),
-            S((N, T, kh, 1)), S((N, T, kh, 1)))
+    return (S((N, T, kh * dp), I8), S(scale), S(scale),
+            S((N, T, kh * dp), I8), S((kh, T, N)), S((kh, T, N)))
 
 
 def _decode(S, model, bits, m=32):
@@ -182,3 +185,86 @@ def test_vmem_guard_agrees_with_compiler(kernel, cfg, fields, ok_blocks,
                 ensure_kernels_fit(cfg, scfg)
             with pytest.raises(Exception, match="(?i)vmem"):
                 jax.jit(fn).lower(*shapes).compile()
+
+
+# One scanned layer body of the paged step at qwen2-0.5b batch-decode's
+# shapes: 24 layers, 8,192 blocks + trash of 16 tokens, 32 slots with
+# 256-block tables, prefill chunk 64.  The pool leaves must reach the
+# kernels as stored: a copy or transpose the size of one layer's pool leaf
+# is a relayout of the whole pool around every attention launch.
+POOL = pgc.PagedCacheConfig(block_size=T, num_blocks=8192, max_batch=32,
+                            max_blocks_per_req=256)
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]"
+                     r"\S*\s+([\w\-]+)\(")
+
+
+def _pool_relayouts(hlo: str, pool) -> list:
+    """Copy / transpose instructions of the optimized HLO whose result has
+    the element count of one layer of a GQA pool leaf."""
+    sizes = {math.prod(leaf.shape[1:]) for name, leaf in pool.items()
+             if name in pgc.BLOCK_LEAVES}
+    hits = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        name, dims, opcode = m.groups()
+        relayout = any(x.startswith(("copy", "transpose"))
+                       for x in (name, opcode))
+        count = math.prod(int(x) for x in dims.split(",") if x)
+        if relayout and count in sizes:
+            hits.append(line.strip()[:160])
+    return hits
+
+
+def _layer_pool(S):
+    cfg = qwen2_0_5b.FULL
+    pool = jax.eval_shape(lambda: pgc.init_paged_cache(cfg, POOL))["p0"]
+    return cfg, {name: S(leaf.shape, leaf.dtype) for name, leaf in pool.items()}
+
+
+def _decode_layers(cfg, pool, S):
+    b, m = POOL.max_batch, POOL.max_blocks_per_req
+
+    def layers(pool, q, k_t, v_t, tables, lengths):
+        def body(q, entry):
+            entry = pgc.gqa_paged_append(entry, k_t, v_t, tables, lengths,
+                                         block_size=T)
+            out = kd.paged_kv_decode_attention(
+                q, entry["k_vals"], entry["k_scale"], entry["k_zero"],
+                entry["v_vals"], entry["v_scale"], entry["v_zero"], tables,
+                lengths + 1)
+            return out, entry
+        return jax.lax.scan(body, q, pool)
+    kv = S((b, cfg.kv_heads, cfg.hd))
+    return layers, (pool, S((b, cfg.n_heads, cfg.hd)), kv, kv,
+                    S((b, m), I32), S((b,), I32))
+
+
+def _chunk_layers(cfg, pool, S):
+    c, m = 64, POOL.max_blocks_per_req
+
+    def layers(pool, q, k, v, row, slot, ctx):
+        def body(q, entry):
+            entry = pgc.gqa_chunk_write(entry, k, v, slot=slot, block_row=row,
+                                        ctx=ctx, chunk_len=jnp.int32(c),
+                                        block_size=T, is_first=False)
+            out = pa.paged_prefix_chunk_attention(
+                q, entry["k_vals"], entry["k_scale"][slot],
+                entry["k_zero"][slot], entry["v_vals"], entry["v_scale"],
+                entry["v_zero"], k[None], v[None], row, ctx)
+            return out, entry
+        return jax.lax.scan(body, q, pool)
+    kv = S((c, cfg.kv_heads, cfg.hd))
+    return layers, (pool, S((1, c, cfg.n_heads, cfg.hd)), kv, kv,
+                    S((m,), I32), S((), I32), S((), I32))
+
+
+@pytest.mark.parametrize("body", ["decode", "chunk"])
+def test_scanned_layer_reads_pool_in_place(body, shape):
+    cfg, pool = _layer_pool(shape)
+    build = _decode_layers if body == "decode" else _chunk_layers
+    fn, args = build(cfg, pool, shape)
+    hlo = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_relayouts(hlo, pool) == []

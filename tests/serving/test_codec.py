@@ -127,11 +127,11 @@ def test_demote_promote_device_ops_roundtrip():
     shape1 = ent["k_vals"].shape[0:1] + ent["k_vals"].shape[2:]
     k1 = rs.randint(-128, 128, size=shape1).astype(np.int8)
     v1 = rs.randint(-128, 128, size=shape1).astype(np.int8)
-    vs_shape = ent["v_scale"].shape[0:1] + ent["v_scale"].shape[2:]
+    vs_shape = ent["v_scale"].shape[:-1]               # blocks are lanes
     vs1 = rs.uniform(0.01, 2.0, size=vs_shape).astype(np.float32)
     ent["k_vals"] = ent["k_vals"].at[:, 1].set(k1)
     ent["v_vals"] = ent["v_vals"].at[:, 1].set(v1)
-    ent["v_scale"] = ent["v_scale"].at[:, 1].set(vs1)
+    ent["v_scale"] = ent["v_scale"].at[..., 1].set(vs1)
     pool["p0"] = ent
     pool = demote_pair_blocks(pool, jnp.int32(1), jnp.int32(2), jnp.int32(1))
     pool = promote_block(pool, jnp.int32(1), jnp.int32(0), jnp.int32(3))
@@ -139,7 +139,7 @@ def test_demote_promote_device_ops_roundtrip():
     got_v = np.asarray(pool["p0"]["v_vals"][:, 3], np.int32)
     assert np.abs(got_k - k1.astype(np.int32)).max() <= 8
     assert np.abs(got_v - v1.astype(np.int32)).max() <= 8
-    np.testing.assert_allclose(np.asarray(pool["p0"]["v_scale"][:, 3]), vs1,
+    np.testing.assert_allclose(np.asarray(pool["p0"]["v_scale"][..., 3]), vs1,
                                rtol=0.01, atol=1e-4)
 
 

@@ -260,12 +260,12 @@ def test_copy_pool_block_copies_codes_not_scales():
     pool = init_paged_cache(CFG, pcfg)
     ent = dict(pool["p0"])
     ent["k_vals"] = ent["k_vals"].at[:, 1].set(7)
-    ent["v_scale"] = ent["v_scale"].at[:, 1].set(0.5)
+    ent["v_scale"] = ent["v_scale"].at[..., 1].set(0.5)   # blocks are lanes
     ent["k_scale"] = ent["k_scale"].at[:, 1].set(3.0)   # slot row, not block
     pool["p0"] = ent
     out = copy_pool_block(pool, 1, 2)
     assert int(jnp.sum(out["p0"]["k_vals"][:, 2] != 7)) == 0
-    assert float(jnp.min(out["p0"]["v_scale"][:, 2])) == 0.5
+    assert float(jnp.min(out["p0"]["v_scale"][..., 2])) == 0.5
     # slot-scale rows untouched by a block copy
     np.testing.assert_array_equal(np.asarray(out["p0"]["k_scale"]),
                                   np.asarray(pool["p0"]["k_scale"]))
@@ -283,9 +283,9 @@ def test_pool_shapes_and_trash_block():
     pool = init_paged_cache(CFG, pcfg)
     ent = pool["p0"]
     r = CFG.n_repeats
-    assert ent["k_vals"].shape == (r, 7, 8, 2, 16)     # num_blocks + trash
+    assert ent["k_vals"].shape == (r, 7, 8, 2 * 16)    # num_blocks + trash
     assert ent["k_vals"].dtype == jnp.int8
-    assert ent["v_scale"].shape == (r, 7, 8, 2, 1)
+    assert ent["v_scale"].shape == (r, 2, 8, 7)        # blocks are lanes
     assert ent["k_scale"].shape == (r, 3, 2, 16)       # per-slot frozen affine
     assert pcfg.trash_block == 6
     assert pcfg.tokens_per_req == 32
@@ -352,7 +352,8 @@ def test_chunk_write_matches_dense_prefill_codes():
                                   np.asarray(dense["k_scale"][0, 0]))
     np.testing.assert_array_equal(np.asarray(entry["k_zero"][0]),
                                   np.asarray(dense["k_zero"][0, 0]))
-    got_vs = np.asarray(entry["v_scale"][block_row]).reshape(s, kh, 1)
+    got_vs = np.asarray(entry["v_scale"][..., block_row]).transpose(
+        2, 1, 0).reshape(s, kh, 1)
     np.testing.assert_array_equal(got_vs, np.asarray(dense["v_scale"][0]))
 
 

@@ -226,3 +226,23 @@ def test_sharded_gqa_pallas_shard_map_parity():
         print("PALLAS_TP_OK")
     """, extra_env={"REPRO_FORCE_PALLAS": "1"})
     assert "PALLAS_TP_OK" in out
+
+
+def test_gqa_pool_shards_whole_heads_only():
+    """The GQA code leaves pack KH heads into their last dim (KH*D): they
+    shard over `model` only where the kv heads divide, like the V scales'
+    KH axis — never into part-heads, even when KH*D divides."""
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    from repro.distributed.sharding import tree_pool_shardings
+    from repro.models import ModelConfig
+    from repro.serving.paged_cache import PagedCacheConfig, init_paged_cache
+    cfg = ModelConfig(name="t", vocab_size=64, d_model=64, n_layers=2,
+                      n_heads=4, n_kv_heads=2, d_ff=128)
+    pool = init_paged_cache(cfg, PagedCacheConfig(block_size=4, num_blocks=6,
+                                                  max_batch=2))
+    for model, split in ((2, "model"), (4, None)):
+        mesh = AbstractMesh((1, model), ("data", "model"))
+        specs = {k: v.spec for k, v in tree_pool_shardings(mesh, pool)["p0"].items()}
+        assert specs["k_vals"] == specs["v_vals"] == P(None, None, None, split)
+        assert specs["v_scale"] == specs["v_zero"] == P(None, split, None, None)
+        assert specs["k_scale"] == P(None, None, split, None)
