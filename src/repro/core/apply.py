@@ -35,7 +35,8 @@ DEFAULT_EXCLUDE = (
 
 DEFAULT_INCLUDE = (
     "*wq*", "*wk*", "*wv*", "*wo*", "*w_in*", "*w_gate*", "*w_out*", "*w_up*",
-    "*wkv_a*", "*wkv_b*", "*q_a*", "*q_b*",                 # MLA projections
+    "*attn/kv_a", "*attn/kv_b", "*attn/q_a", "*attn/q_b",   # MLA projections
+    "*attn/q_proj",
     "*experts*", "*shared*",                                # MoE expert mats
     "*embed*", "*lm_head*",
 )
@@ -117,11 +118,14 @@ def quantize_tree(params, policy: QuantPolicy, *,
                 kw["stats"] = stats[ps]
             if calib_x is not None and ps in calib_x:
                 kw["calib_x"] = calib_x[ps]
-        # 3D+ expert stacks (n_exp, d_in, d_out): quantize per expert slice by
-        # folding the expert dim into channels — vmap the 2D quantizer.
-        if leaf.ndim == 3:
-            return jax.vmap(lambda w: method.quantize_weight(w, bits=bits, **kw))(leaf)
-        return method.quantize_weight(leaf, bits=bits, **kw)
+        # stacked leaves ((layers,) (experts,) d_in, d_out): quantize every
+        # (d_in, d_out) slice on its own — vmap the 2D quantizer over each
+        # leading dim, so each layer and expert gets its per-output-channel
+        # scales
+        quant = lambda w: method.quantize_weight(w, bits=bits, **kw)
+        for _ in range(leaf.ndim - 2):
+            quant = jax.vmap(quant)
+        return quant(leaf)
 
     return jax.tree_util.tree_map_with_path(visit, params)
 

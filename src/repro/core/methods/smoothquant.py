@@ -83,14 +83,20 @@ def apply_fold_to_model(params, taps_stats: dict, alpha: float = 0.5):
       norm_mix  -> (wq, wk, wv)   with one shared s (max over the fused QKV)
       norm_ffn  -> (w_gate, w_up) likewise.
     Stacked (R, d, f) leaves use per-repeat smoothing factors (taps are
-    stacked over scan repeats).  Returns a new params pytree; the runtime
-    then quantizes it with the plain symmetric W8A8 backend — zero extra ops
-    at inference (the paper's Table-5 argument).
+    stacked over scan repeats).  Leading layers (``params["lead"]``, taps
+    ``lead/...``) fold the same way.  MLA projections and MoE layers are
+    not folded: their norm outputs feed q / ``kv_a`` and the router, the
+    experts and the shared experts together, and the router's choice must
+    not move.  Returns a new params pytree; the runtime then quantizes it
+    with the plain symmetric W8A8 backend — zero extra ops at inference
+    (the paper's Table-5 argument).
     """
     import jax
 
     params = jax.tree_util.tree_map(lambda x: x, params)      # shallow copy
     layers = dict(params["layers"])
+    if "lead" in params:
+        layers["lead"] = params["lead"]
     for pos_name, blk in layers.items():
         blk = jax.tree_util.tree_map(lambda x: x, blk)
         attn_tag = f"{pos_name}/attn_in"
@@ -139,5 +145,7 @@ def apply_fold_to_model(params, taps_stats: dict, alpha: float = 0.5):
             blk["ffn"] = ffn
             blk["norm_ffn"] = gamma
         layers[pos_name] = blk
+    if "lead" in params:
+        params["lead"] = layers.pop("lead")
     params["layers"] = layers
     return params

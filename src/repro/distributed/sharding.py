@@ -149,7 +149,7 @@ _PARAM_RULES = [
     (r"shared.*w_(gate|up)",       ("fsdp", "expert_ffn")),
     (r"shared.*w_out",             ("expert_ffn", "fsdp")),
     (r"router|gate_w",             (None, None)),
-    (r"\bwq\b|wq$|q_b",            ("fsdp", "heads")),
+    (r"\bwq\b|wq$|q_b|q_proj",     ("fsdp", "heads")),
     (r"wk|wv|kv_b",                ("fsdp", "kv_heads")),
     (r"\bwo\b|wo$",                ("heads", "fsdp")),
     (r"q_a|wkv_a|kv_a",            ("fsdp", None)),
@@ -280,13 +280,12 @@ _POOL_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "v_zero":  (None, "kv_heads", None, None),
     "k_scale": (None, None, "kv_heads", None),
     "k_zero":  (None, None, "kv_heads", None),
-    # MLA latent pool: latent channel axis is replicated
+    # MLA latent pool: codes (R, N+1, T, rkv), per-token scales (R, T, N+1),
+    # replicated
     "c_vals":  (None, None, None, None),
     "kr_vals": (None, None, None, None),
     "c_scale": (None, None, None),
-    "c_zero":  (None, None, None),
     "kr_scale": (None, None, None),
-    "kr_zero": (None, None, None),
     # SSM state pool
     "conv":      (None, None, None, None),
     "ssd_vals":  (None, None, "heads", None, None),

@@ -33,6 +33,7 @@ from repro.core.qtensor import QTensor
 from . import ref
 from .fused_quant import fused_quant
 from .w8a8_matmul import w8a8_matmul
+from .expert_gemm import expert_gemm as _expert_gemm
 from .kv_decode_attention import kv_decode_attention, paged_kv_decode_attention
 from . import paged_attention as pa
 
@@ -97,6 +98,33 @@ def _w8a8(x2d: jax.Array, qw: QTensor, out_dtype):
     ax = _split_axis("ffn", qw.values.shape[1])
     specs = None if ax is None else (P(), P(None, ax), P(None, ax))
     return _on_mesh(launch, specs, P(None, ax))(x2d, qw.values, w_scale)
+
+
+def quantize_rows(x2d: jax.Array):
+    """Dynamic per-row symmetric INT8 activations: (M, K) -> (int8 (M, K),
+    f32 scale (M, 1)) — the fused-quant kernel on TPU, its oracle
+    elsewhere (the same quantization ``qdot``'s W8A8 path applies)."""
+    pk = _use_pallas()
+    if pk is None:
+        return ref.fused_quant_ref(x2d)
+    return _on_mesh(partial(fused_quant, **pk), None, None)(x2d)
+
+
+def expert_gemm(x_q: jax.Array, x_scale: jax.Array, qw: QTensor,
+                tile_expert: jax.Array, used: jax.Array, *, tm: int,
+                out_dtype=jnp.float32) -> jax.Array:
+    """The held experts' grouped W8A8 GEMM over rows sorted by expert
+    (``kernels/expert_gemm.py`` on TPU, ``ref.expert_gemm_ref`` elsewhere).
+    ``qw``: per-output-channel int8 expert codes, values (E, K, N), scale
+    (E, 1, N)."""
+    pk = _use_pallas()
+    if pk is None:
+        return ref.expert_gemm_ref(x_q, x_scale, qw.values, qw.scale,
+                                   tile_expert, used, tm=tm,
+                                   out_dtype=out_dtype)
+    fn = partial(_expert_gemm, tm=tm, out_dtype=out_dtype, **pk)
+    return _on_mesh(fn, None, None)(x_q, x_scale, qw.values, qw.scale,
+                                    tile_expert, used)
 
 
 def qdot(x: jax.Array, w, out_dtype=None) -> jax.Array:
@@ -197,8 +225,7 @@ def paged_verify_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
 
 
 def mla_paged_verify_attention(q_nope, q_rope, w_uk, w_uv, c_vals, c_scale,
-                               c_zero, kr_vals, kr_scale, kr_zero,
-                               block_tables, lengths):
+                               kr_vals, kr_scale, block_tables, lengths):
     """MLA multi-token verify (absorbed).  q_nope: (B,G,H,dn), q_rope:
     (B,G,H,dr) -> (B,G,H,dv).  The kernel path folds W_uk/W_uv per position
     with the exact per-j einsums of ``mla_decode_ref`` so its float path
@@ -213,15 +240,36 @@ def mla_paged_verify_attention(q_nope, q_rope, w_uk, w_uv, c_vals, c_scale,
         fn = partial(pa.mla_paged_verify_attention,
                      qk_nope_dim=q_nope.shape[-1], **pk)
         o_lat = _on_mesh(fn, None, None)(
-            q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, block_tables, lengths)
+            q_lat, q_rope, c_vals, c_scale, kr_vals, kr_scale, block_tables,
+            lengths)
         return jnp.stack(
             [jnp.einsum("bhr,rhd->bhd", o_lat[:, j], w_uv.astype(f32))
              for j in range(g)], axis=1)
     return ref.mla_paged_verify_attention_ref(q_nope, q_rope, w_uk, w_uv,
-                                              c_vals, c_scale, c_zero,
-                                              kr_vals, kr_scale, kr_zero,
-                                              block_tables, lengths)
+                                              c_vals, c_scale, kr_vals,
+                                              kr_scale, block_tables, lengths)
+
+
+def mla_paged_decode_attention(q_nope, q_rope, w_uk, w_uv, c_vals, c_scale,
+                               kr_vals, kr_scale, block_tables, lengths):
+    """MLA paged decode (absorbed): each lane's query attends its first
+    ``lengths[b]`` pool tokens through its block table.  q_nope: (B,H,dn),
+    q_rope: (B,H,dr) -> (B,H,dv).  The kernel streams the live latent
+    blocks (Pallas on TPU); the oracle gathers whole tables."""
+    pk = _use_pallas()
+    if pk is not None:
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope.astype(f32),
+                           w_uk.astype(f32))
+        fn = partial(pa.mla_paged_decode_attention,
+                     qk_nope_dim=q_nope.shape[-1], **pk)
+        o_lat = _on_mesh(fn, None, None)(
+            q_lat, q_rope, c_vals, c_scale, kr_vals, kr_scale, block_tables,
+            lengths)
+        return jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(f32))
+    return ref.mla_paged_decode_attention_ref(q_nope, q_rope, w_uk, w_uv,
+                                              c_vals, c_scale, kr_vals,
+                                              kr_scale, block_tables, lengths)
 
 
 def paged_prefix_chunk_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
@@ -253,10 +301,9 @@ def paged_prefix_chunk_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,
                                                 ctx)
 
 
-def mla_paged_prefix_chunk_attention(q_lat, q_rope, c_vals, c_scale, c_zero,
-                                     kr_vals, kr_scale, kr_zero, c_chunk,
-                                     kr_chunk, block_row, ctx, *,
-                                     qk_nope_dim: int):
+def mla_paged_prefix_chunk_attention(q_lat, q_rope, c_vals, c_scale,
+                                     kr_vals, kr_scale, c_chunk, kr_chunk,
+                                     block_row, ctx, *, qk_nope_dim: int):
     """MLA chunk-prefill attention in absorbed latent space.
     q_lat: (1,C,H,rkv) -> o_lat (1,C,H,rkv) f32 (caller applies W_uv)."""
     pk = _use_pallas()
@@ -264,11 +311,11 @@ def mla_paged_prefix_chunk_attention(q_lat, q_rope, c_vals, c_scale, c_zero,
         fn = partial(pa.mla_paged_prefix_chunk_attention,
                      qk_nope_dim=qk_nope_dim, **pk)
         return _on_mesh(fn, None, None)(
-            q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, c_chunk, kr_chunk, block_row, ctx)
+            q_lat, q_rope, c_vals, c_scale, kr_vals, kr_scale, c_chunk,
+            kr_chunk, block_row, ctx)
     return ref.mla_paged_prefix_chunk_attention_ref(
-        q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero,
-        c_chunk, kr_chunk, block_row, ctx, qk_nope_dim=qk_nope_dim)
+        q_lat, q_rope, c_vals, c_scale, kr_vals, kr_scale, c_chunk, kr_chunk,
+        block_row, ctx, qk_nope_dim=qk_nope_dim)
 
 
 def flash_decode_ref(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,

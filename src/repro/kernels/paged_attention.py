@@ -67,6 +67,17 @@ def _dequant_block(k_ref, v_ref, vs_ref, vz_ref, ks, kz, hh, blk, *,
     return k, v
 
 
+def _latent_block(c_ref, kr_ref, cs_ref, krs_ref, blk, *, lanes: int,
+                  bits: int):
+    """One pool block's latent (T, rkv) and rope key (T, dr), dequantized
+    with their per-token scales picked from the block's lane of the scale
+    tiles (the same float path as the oracles' gathered scale columns)."""
+    lane = blk % lanes
+    c = codes_f32(c_ref[0], bits) * pick_lane(cs_ref[...], lane)
+    kr = codes_f32(kr_ref[0], bits) * pick_lane(krs_ref[...], lane)
+    return c, kr
+
+
 def _softmax_rows(s: jax.Array) -> jax.Array:
     """One-shot softmax over the last axis, op-for-op ``jax.nn.softmax``."""
     mx = jnp.max(s, axis=-1, keepdims=True)
@@ -89,12 +100,12 @@ def _prescale_q(q: jax.Array, d: int) -> jax.Array:
     return jax.lax.optimization_barrier(q.astype(jnp.float32) / rsqrt)
 
 
-def _chunk_live(shape, mt: int, ctx, group: int) -> jax.Array:
+def _chunk_live(shape, mt: int, ctx, group: int, row0=0) -> jax.Array:
     """Live score columns of a chunk-prefill launch: pool columns below
     ``ctx``, and chunk columns causal within the chunk (row ``r`` is chunk
-    position ``r // group``).  Written as and/or of comparisons: Mosaic
-    cannot lower a select between two boolean vectors."""
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    position ``(row0 + r) // group``).  Written as and/or of comparisons:
+    Mosaic cannot lower a select between two boolean vectors."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + row0
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return (((col < mt) & (col < ctx))
             | ((col >= mt) & (col - mt <= row // group)))
@@ -220,16 +231,16 @@ def paged_kv_verify_attention(q: jax.Array,
         b, gq, h, d)
 
 
-def _mla_verify_kernel(bt_ref, len_ref, ql_ref, qr_ref, cs_ref, cz_ref,
-                       krs_ref, krz_ref, c_ref, kr_ref, o_ref, cf_ref,
-                       krf_ref, *, n_blk: int, t: int, heads: int, dn: int,
-                       dr: int, bits: int):
+def _mla_verify_kernel(bt_ref, len_ref, ql_ref, qr_ref, cs_ref, krs_ref,
+                       c_ref, kr_ref, o_ref, cf_ref, krf_ref, *, n_blk: int,
+                       t: int, heads: int, dn: int, dr: int, lanes: int,
+                       bits: int):
     b_idx = pl.program_id(0)
     m_idx = pl.program_id(1)
 
-    c = (codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
+    c, kr = _latent_block(c_ref, kr_ref, cs_ref, krs_ref,
+                          bt_ref[b_idx, m_idx], lanes=lanes, bits=bits)
     cf_ref[pl.ds(m_idx * t, t), :] = c
-    kr = (codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
     krf_ref[pl.ds(m_idx * t, t), :] = kr
 
     @pl.when(m_idx == n_blk - 1)
@@ -252,17 +263,16 @@ def _mla_verify_kernel(bt_ref, len_ref, ql_ref, qr_ref, cs_ref, cz_ref,
 @functools.partial(jax.jit, static_argnames=("qk_nope_dim", "interpret"))
 def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
                                c_vals: jax.Array, c_scale: jax.Array,
-                               c_zero: jax.Array, kr_vals: jax.Array,
-                               kr_scale: jax.Array, kr_zero: jax.Array,
+                               kr_vals: jax.Array, kr_scale: jax.Array,
                                block_tables: jax.Array, lengths: jax.Array, *,
                                qk_nope_dim: int,
                                interpret: bool = False) -> jax.Array:
     """MLA verify in absorbed latent space, one launch for all G positions.
 
     q_lat: (B, G, H, rkv) absorbed queries (q_nope @ W_uk); q_rope:
-    (B, G, H, dr); c_vals: (N, T, rkv) int8 latent pool with per-slot affine
-    c_scale/c_zero (B, rkv); kr_vals: (N, T, dr) with kr_scale/kr_zero
-    (B, dr); -> o_lat (B, G, H, rkv) f32 (caller applies W_uv).
+    (B, G, H, dr); c_vals: (N, T, rkv) int8 latent pool with per-token
+    scales c_scale (T, N), blocks as lanes; kr_vals: (N, T, dr) with
+    kr_scale (T, N); -> o_lat (B, G, H, rkv) f32 (caller applies W_uv).
     """
     b, gq, h, rkv = q_lat.shape
     dr = q_rope.shape[-1]
@@ -271,22 +281,23 @@ def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
     bits = 8 if rkv_p == rkv else 4
     m = block_tables.shape[1]
     rows = gq * h
+    lanes = scale_lanes(c_scale.shape[-1])
 
     ql_r = q_lat.astype(jnp.float32).reshape(b, rows, rkv)
     qr_r = q_rope.astype(jnp.float32).reshape(b, rows, dr)
 
     kernel = functools.partial(_mla_verify_kernel, n_blk=m, t=t, heads=h,
-                               dn=qk_nope_dim, dr=dr, bits=bits)
+                               dn=qk_nope_dim, dr=dr, lanes=lanes, bits=bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_tables, lengths
         grid=(b, m),
         in_specs=[
             pl.BlockSpec((1, rows, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
             pl.BlockSpec((1, rows, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, 1, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, 1, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, 1, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, 1, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((t, lanes),
+                         lambda bb, mm, bt, ln: (0, bt[bb, mm] // lanes)),
+            pl.BlockSpec((t, lanes),
+                         lambda bb, mm, bt, ln: (0, bt[bb, mm] // lanes)),
             pl.BlockSpec((1, t, rkv_p), lambda bb, mm, bt, ln: (bt[bb, mm], 0, 0)),
             pl.BlockSpec((1, t, dr_p), lambda bb, mm, bt, ln: (bt[bb, mm], 0, 0)),
         ],
@@ -299,9 +310,129 @@ def mla_paged_verify_attention(q_lat: jax.Array, q_rope: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rkv), jnp.float32),
         interpret=interpret,
-    )(block_tables, lengths, ql_r, qr_r, c_scale[:, None], c_zero[:, None],
-      kr_scale[:, None], kr_zero[:, None], c_vals, kr_vals)
+    )(block_tables, lengths, ql_r, qr_r, c_scale, kr_scale, c_vals, kr_vals)
     return o_lat.reshape(b, gq, h, rkv)
+
+
+# ---------------------------------------------------------------------------
+# MLA decode: one query per lane, streamed over the lane's live blocks
+# ---------------------------------------------------------------------------
+
+def _last_block(length, t: int):
+    """Index of a lane's last live block (0 for an empty lane)."""
+    return jnp.maximum(length - 1, 0) // t
+
+
+def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qr_ref, cs_ref, krs_ref,
+                       c_ref, kr_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                       n_blk: int, t: int, dn: int, dr: int, lanes: int,
+                       bits: int):
+    b_idx = pl.program_id(0)
+    m_idx = pl.program_id(1)
+    length = len_ref[b_idx]
+    scale = 1.0 / jnp.sqrt(dn + dr)
+
+    @pl.when(m_idx == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(m_idx <= _last_block(length, t))
+    def _block():
+        c, kr = _latent_block(c_ref, kr_ref, cs_ref, krs_ref,
+                              bt_ref[b_idx, m_idx], lanes=lanes, bits=bits)
+        s_lat = jax.lax.dot_general(ql_ref[0], c, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        s_rope = jax.lax.dot_general(qr_ref[0], kr, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        s = (s_lat + s_rope) * scale                                # (H, T)
+        pos = m_idx * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+
+    @pl.when(m_idx == n_blk - 1)
+    def _finish():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+# VMEM of one MLA decode program: it streams one block at a time, so the
+# working set does not grow with the block table (scheduler's guard)
+def mla_decode_vmem_bytes(heads: int, t: int, rkv: int, dr: int) -> int:
+    """Double-buffered query, scale and code blocks, the (H, rkv) output,
+    and the online-softmax scratch of one ``mla_paged_decode_attention``
+    program (the scale tiles at their widest, 128 blocks of lanes)."""
+    io = (_tile_bytes(heads, rkv) + _tile_bytes(heads, dr)
+          + 2 * _tile_bytes(t, 128)
+          + _tile_bytes(t, rkv) + _tile_bytes(t, dr)
+          + _tile_bytes(heads, rkv))
+    scratch = 2 * _tile_bytes(heads, 1) + _tile_bytes(heads, rkv)
+    live = 2 * _tile_bytes(t, rkv) + 3 * _tile_bytes(heads, t)
+    return 2 * io + scratch + live
+
+
+@functools.partial(jax.jit, static_argnames=("qk_nope_dim", "interpret"))
+def mla_paged_decode_attention(q_lat: jax.Array, q_rope: jax.Array,
+                               c_vals: jax.Array, c_scale: jax.Array,
+                               kr_vals: jax.Array, kr_scale: jax.Array,
+                               block_tables: jax.Array, lengths: jax.Array, *,
+                               qk_nope_dim: int,
+                               interpret: bool = False) -> jax.Array:
+    """MLA decode in absorbed latent space: lane b's one query attends its
+    first ``lengths[b]`` pool tokens, read block by block through its
+    table row with an online softmax.  Grid steps past a lane's last live
+    block fetch nothing (the block index repeats) and compute nothing.
+
+    q_lat: (B, H, rkv) absorbed queries; q_rope: (B, H, dr); pool and
+    per-token scales as in :func:`mla_paged_verify_attention` -> o_lat
+    (B, H, rkv) f32 (caller applies W_uv)."""
+    b, h, rkv = q_lat.shape
+    dr = q_rope.shape[-1]
+    t = c_vals.shape[1]
+    rkv_p, dr_p = c_vals.shape[-1], kr_vals.shape[-1]
+    bits = 8 if rkv_p == rkv else 4
+    m = block_tables.shape[1]
+    lanes = scale_lanes(c_scale.shape[-1])
+
+    def blk(bb, mm, bt, ln):
+        return bt[bb, jnp.minimum(mm, _last_block(ln[bb], t))]
+
+    kernel = functools.partial(_mla_decode_kernel, n_blk=m, t=t,
+                               dn=qk_nope_dim, dr=dr, lanes=lanes, bits=bits)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # block_tables, lengths
+        grid=(b, m),
+        in_specs=[
+            pl.BlockSpec((1, h, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((1, h, dr), lambda bb, mm, bt, ln: (bb, 0, 0)),
+            pl.BlockSpec((t, lanes), lambda bb, mm, bt, ln: (
+                0, blk(bb, mm, bt, ln) // lanes)),
+            pl.BlockSpec((t, lanes), lambda bb, mm, bt, ln: (
+                0, blk(bb, mm, bt, ln) // lanes)),
+            pl.BlockSpec((1, t, rkv_p),
+                         lambda bb, mm, bt, ln: (blk(bb, mm, bt, ln), 0, 0)),
+            pl.BlockSpec((1, t, dr_p),
+                         lambda bb, mm, bt, ln: (blk(bb, mm, bt, ln), 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, h, rkv), lambda bb, mm, bt, ln: (bb, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, rkv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rkv), jnp.float32),
+        interpret=interpret,
+    )(block_tables, lengths, q_lat.astype(jnp.float32),
+      q_rope.astype(jnp.float32), c_scale, kr_scale, c_vals, kr_vals)
 
 
 # ---------------------------------------------------------------------------
@@ -401,15 +532,16 @@ def paged_prefix_chunk_attention(q: jax.Array,
     return out.reshape(kh, c, g, d).transpose(1, 0, 2, 3).reshape(1, c, h, d)
 
 
-def _mla_chunk_kernel(br_ref, ctx_ref, ql_ref, qr_ref, cs_ref, cz_ref,
-                      krs_ref, krz_ref, c_ref, kr_ref, cc_ref, krc_ref,
-                      o_ref, cf_ref, krf_ref, *, n_blk: int, t: int,
-                      heads: int, dn: int, dr: int, bits: int):
-    m_idx = pl.program_id(0)
+def _mla_chunk_kernel(br_ref, ctx_ref, ql_ref, qr_ref, cs_ref, krs_ref,
+                      c_ref, kr_ref, cc_ref, krc_ref, o_ref, cf_ref, krf_ref,
+                      *, n_blk: int, t: int, heads: int, dn: int, dr: int,
+                      lanes: int, bits: int):
+    m_idx = pl.program_id(1)
+    row0 = pl.program_id(0) * ql_ref.shape[0]
 
-    c = (codes_f32(c_ref[0], bits) - cz_ref[0]) * cs_ref[0]
+    c, kr = _latent_block(c_ref, kr_ref, cs_ref, krs_ref, br_ref[m_idx],
+                          lanes=lanes, bits=bits)
     cf_ref[pl.ds(m_idx * t, t), :] = c
-    kr = (codes_f32(kr_ref[0], bits) - krz_ref[0]) * krs_ref[0]
     krf_ref[pl.ds(m_idx * t, t), :] = kr
 
     @pl.when(m_idx == n_blk - 1)
@@ -424,17 +556,42 @@ def _mla_chunk_kernel(br_ref, ctx_ref, ql_ref, qr_ref, cs_ref, cz_ref,
         s_rope = jax.lax.dot_general(qr_ref[...], krf, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
         s = (s_lat + s_rope) * scale
-        live = _chunk_live(s.shape, mt, ctx_ref[0], heads)
+        live = _chunk_live(s.shape, mt, ctx_ref[0], heads, row0)
         w = _softmax_rows(jnp.where(live, s, NEG_INF))
         o_ref[...] = jax.lax.dot_general(w, cf, (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
 
+def mla_chunk_vmem_bytes(tokens: int, heads: int, cols: int, rkv: int,
+                         dr: int) -> int:
+    """VMEM of one MLA chunk-prefill program serving ``tokens`` chunk
+    positions (``tokens * heads`` query rows) over ``cols`` key
+    positions: the one-shot working set, with the latent and rope score
+    parts and the weights live beside the scores (three (rows, cols)
+    values; the compiler's verdict, tests/kernels/test_tpu_compile.py)."""
+    rows = tokens * heads
+    return (one_shot_vmem_bytes(rows, cols, (rkv, dr), (rkv, dr, rkv))
+            + 2 * _tile_bytes(rows, cols))
+
+
+def mla_chunk_tile(c: int, heads: int, cols: int, rkv: int, dr: int) -> int:
+    """Chunk positions per MLA chunk-prefill program: all ``c`` where they
+    fit VMEM, else the largest power-of-two share of them that does (each
+    program buffers the whole prefix again), else ``c`` (which the
+    compiler refuses, and ``Scheduler`` at build)."""
+    tile = c
+    while tile > 1 and (c % tile or mla_chunk_vmem_bytes(
+            tile, heads, cols, rkv, dr) > VMEM_LIMIT_BYTES):
+        tile //= 2
+    fits = mla_chunk_vmem_bytes(tile, heads, cols, rkv, dr) \
+        <= VMEM_LIMIT_BYTES
+    return tile if fits and c % tile == 0 else c
+
+
 @functools.partial(jax.jit, static_argnames=("qk_nope_dim", "interpret"))
 def mla_paged_prefix_chunk_attention(q_lat: jax.Array, q_rope: jax.Array,
                                      c_vals: jax.Array, c_scale: jax.Array,
-                                     c_zero: jax.Array, kr_vals: jax.Array,
-                                     kr_scale: jax.Array, kr_zero: jax.Array,
+                                     kr_vals: jax.Array, kr_scale: jax.Array,
                                      c_chunk: jax.Array, kr_chunk: jax.Array,
                                      block_row: jax.Array, ctx: jax.Array, *,
                                      qk_nope_dim: int,
@@ -442,10 +599,14 @@ def mla_paged_prefix_chunk_attention(q_lat: jax.Array, q_rope: jax.Array,
     """MLA chunk-prefill attention in absorbed latent space.
 
     q_lat: (1, C, H, rkv); q_rope: (1, C, H, dr); c_vals: (N, T, rkv) int8
-    latent pool with per-slot affine c_scale/c_zero (rkv,); kr_vals:
-    (N, T, dr) with kr_scale/kr_zero (dr,); c_chunk: (1, C, rkv) /
+    latent pool with per-token scales c_scale (T, N), blocks as lanes;
+    kr_vals: (N, T, dr) with kr_scale (T, N); c_chunk: (1, C, rkv) /
     kr_chunk: (1, C, dr) fresh fp chunk latent; block_row: (M,); ctx: ()
     -> o_lat (1, C, H, rkv) f32 (caller applies W_uv).
+
+    The grid's first axis splits the chunk's query rows into the tiles
+    ``mla_chunk_tile`` sizes to VMEM; each row's softmax stays one-shot,
+    so the tiling changes no float.
     """
     c, h, rkv = q_lat.shape[1], q_lat.shape[2], q_lat.shape[3]
     dr = q_rope.shape[-1]
@@ -453,39 +614,40 @@ def mla_paged_prefix_chunk_attention(q_lat: jax.Array, q_rope: jax.Array,
     rkv_p, dr_p = c_vals.shape[-1], kr_vals.shape[-1]     # carrier widths
     bits = 8 if rkv_p == rkv else 4
     m = block_row.shape[0]
-    rows = c * h
+    rows = mla_chunk_tile(c, h, m * t + c, rkv, dr) * h
+    lanes = scale_lanes(c_scale.shape[-1])
 
-    ql_r = q_lat[0].astype(jnp.float32).reshape(rows, rkv)
-    qr_r = q_rope[0].astype(jnp.float32).reshape(rows, dr)
+    ql_r = q_lat[0].astype(jnp.float32).reshape(c * h, rkv)
+    qr_r = q_rope[0].astype(jnp.float32).reshape(c * h, dr)
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape(1)
 
     kernel = functools.partial(_mla_chunk_kernel, n_blk=m, t=t, heads=h,
-                               dn=qk_nope_dim, dr=dr, bits=bits)
+                               dn=qk_nope_dim, dr=dr, lanes=lanes, bits=bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_row, ctx
-        grid=(m,),
+        grid=(c * h // rows, m),
         in_specs=[
-            pl.BlockSpec((rows, rkv), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((rows, dr), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((1, rkv), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((1, rkv), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((1, dr), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((1, dr), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((1, t, rkv_p), lambda mm, br, cx: (br[mm], 0, 0)),
-            pl.BlockSpec((1, t, dr_p), lambda mm, br, cx: (br[mm], 0, 0)),
-            pl.BlockSpec((c, rkv), lambda mm, br, cx: (0, 0)),
-            pl.BlockSpec((c, dr), lambda mm, br, cx: (0, 0)),
+            pl.BlockSpec((rows, rkv), lambda i, mm, br, cx: (i, 0)),
+            pl.BlockSpec((rows, dr), lambda i, mm, br, cx: (i, 0)),
+            pl.BlockSpec((t, lanes),
+                         lambda i, mm, br, cx: (0, br[mm] // lanes)),
+            pl.BlockSpec((t, lanes),
+                         lambda i, mm, br, cx: (0, br[mm] // lanes)),
+            pl.BlockSpec((1, t, rkv_p),
+                         lambda i, mm, br, cx: (br[mm], 0, 0)),
+            pl.BlockSpec((1, t, dr_p), lambda i, mm, br, cx: (br[mm], 0, 0)),
+            pl.BlockSpec((c, rkv), lambda i, mm, br, cx: (0, 0)),
+            pl.BlockSpec((c, dr), lambda i, mm, br, cx: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((rows, rkv), lambda mm, br, cx: (0, 0)),
+        out_specs=pl.BlockSpec((rows, rkv), lambda i, mm, br, cx: (i, 0)),
         scratch_shapes=[pltpu.VMEM((m * t + c, rkv), jnp.float32),
                         pltpu.VMEM((m * t + c, dr), jnp.float32)],
     )
     o_lat = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, rkv), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((c * h, rkv), jnp.float32),
         interpret=interpret,
-    )(block_row, ctx_arr, ql_r, qr_r, c_scale.reshape(1, rkv),
-      c_zero.reshape(1, rkv), kr_scale.reshape(1, dr), kr_zero.reshape(1, dr),
-      c_vals, kr_vals, c_chunk[0], kr_chunk[0])
+    )(block_row, ctx_arr, ql_r, qr_r, c_scale, kr_scale, c_vals, kr_vals,
+      c_chunk[0], kr_chunk[0])
     return o_lat.reshape(1, c, h, rkv)

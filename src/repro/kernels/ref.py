@@ -62,6 +62,26 @@ def w8a8_matmul_ref(q_x: jax.Array, x_scale: jax.Array,
     return (acc.astype(jnp.float32) * x_scale * w_scale).astype(out_dtype)
 
 
+def expert_gemm_ref(x: jax.Array, x_scale: jax.Array, w: jax.Array,
+                    w_scale: jax.Array, tile_expert: jax.Array,
+                    used: jax.Array, *, tm: int,
+                    out_dtype=jnp.float32) -> jax.Array:
+    """Grouped INT8 GEMM over rows sorted by expert (``expert_gemm``'s
+    contract): row tile i (``tm`` rows) of ``x`` (R, K) int8 times expert
+    ``tile_expert[i]``'s (K, N) codes of ``w`` (E, K, N) into int32, then
+    the row scale and the expert's (1, N) scale; rows past ``used * tm``
+    are zero."""
+    e = w.shape[0]
+    live = jnp.arange(tile_expert.shape[0]) < used[0]
+    sizes = tm * jnp.sum((tile_expert[None, :] == jnp.arange(e)[:, None])
+                         & live[None, :], axis=1).astype(jnp.int32)
+    acc = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.int32)
+    rows = jnp.repeat(tile_expert, tm)
+    out = acc.astype(jnp.float32) * x_scale * w_scale[rows, 0]
+    keep = jnp.repeat(live, tm)[:, None]
+    return jnp.where(keep, out, 0.0).astype(out_dtype)
+
+
 def quant_gemm_fused_ref(x: jax.Array, q_w: jax.Array, w_scale: jax.Array,
                          out_dtype=jnp.float32) -> jax.Array:
     """End-to-end fused path: dynamic act quant + INT8 GEMM (Alg. 1 + Alg. 2)."""
@@ -144,35 +164,65 @@ def paged_kv_verify_attention_ref(q: jax.Array,
     return jnp.stack(outs, axis=1)
 
 
+def _token_rows(pool: jax.Array, ids: jax.Array) -> jax.Array:
+    """Blocks ``ids`` of a (T, N) MLA per-token scale pool, whose blocks are
+    its lanes, as (*ids.shape, T, 1): the layout of the gathered codes."""
+    return jnp.moveaxis(pool[:, ids], 0, -1)[..., None]
+
+
+def _gather_latent(c_vals, c_scale, kr_vals, kr_scale, block_tables,
+                   rkv: int, dr: int):
+    """Every lane's block table gathered into dense rows: codes (B, M*T,
+    width) and their per-token scales (B, M*T, 1)."""
+    b, m = block_tables.shape
+    t = c_vals.shape[1]
+    codes = lambda pool, w: _unpack_pool(pool, w)[block_tables].reshape(
+        b, m * t, w)
+    scales = lambda pool: _token_rows(pool, block_tables).reshape(b, m * t, 1)
+    return (codes(c_vals, rkv), scales(c_scale), codes(kr_vals, dr),
+            scales(kr_scale))
+
+
 def mla_paged_verify_attention_ref(q_nope: jax.Array, q_rope: jax.Array,
                                    w_uk: jax.Array, w_uv: jax.Array,
                                    c_vals: jax.Array, c_scale: jax.Array,
-                                   c_zero: jax.Array, kr_vals: jax.Array,
-                                   kr_scale: jax.Array, kr_zero: jax.Array,
+                                   kr_vals: jax.Array, kr_scale: jax.Array,
                                    block_tables: jax.Array,
                                    lengths: jax.Array) -> jax.Array:
     """MLA multi-token verify oracle (absorbed latent-space attention).
 
     q_nope: (B,G,H,dn); q_rope: (B,G,H,dr); c_vals: (N,T,rkv) int8 latent
-    pool with per-slot affine c_scale/c_zero: (B,rkv); kr_vals: (N,T,dr)
-    int8 rope keys with kr_scale/kr_zero: (B,dr); block_tables: (B,M);
-    lengths: (B,) -> (B,G,H,dv).  Same hoisted-gather construction as the
-    GQA verify oracle, delegating per position to ``mla_decode_ref``.
+    pool with per-token scales c_scale: (T,N); kr_vals: (N,T,dr) int8 rope
+    keys with kr_scale: (T,N); block_tables: (B,M); lengths: (B,) ->
+    (B,G,H,dv).  Same hoisted-gather construction as the GQA verify oracle,
+    delegating per position to ``mla_decode_ref``.
     """
     from repro.models.mla import mla_decode_ref
-    b, m = block_tables.shape
-    t = c_vals.shape[1]
     g = q_nope.shape[1]
-    c_vals = _unpack_pool(c_vals, c_scale.shape[-1])
-    kr_vals = _unpack_pool(kr_vals, kr_scale.shape[-1])
-    gather = lambda pool: pool[block_tables].reshape(b, m * t, pool.shape[-1])
-    cg, krg = gather(c_vals), gather(kr_vals)
-    cs, cz = c_scale[:, None], c_zero[:, None]
-    krs, krz = kr_scale[:, None], kr_zero[:, None]
-    outs = [mla_decode_ref(q_nope[:, j], q_rope[:, j], cg, cs, cz,
-                           krg, krs, krz, w_uk, w_uv, lengths + j + 1, None)
+    cg, csg, krg, krsg = _gather_latent(c_vals, c_scale, kr_vals, kr_scale,
+                                        block_tables, w_uk.shape[0],
+                                        q_rope.shape[-1])
+    outs = [mla_decode_ref(q_nope[:, j], q_rope[:, j], cg, csg, krg, krsg,
+                           w_uk, w_uv, lengths + j + 1, None)
             for j in range(g)]
     return jnp.stack(outs, axis=1)
+
+
+def mla_paged_decode_attention_ref(q_nope: jax.Array, q_rope: jax.Array,
+                                   w_uk: jax.Array, w_uv: jax.Array,
+                                   c_vals: jax.Array, c_scale: jax.Array,
+                                   kr_vals: jax.Array, kr_scale: jax.Array,
+                                   block_tables: jax.Array,
+                                   lengths: jax.Array) -> jax.Array:
+    """MLA paged decode oracle: gather every lane's block table into dense
+    rows, then ``mla_decode_ref``.  q_nope: (B,H,dn); q_rope: (B,H,dr);
+    lengths: (B,) tokens attended -> (B,H,dv)."""
+    from repro.models.mla import mla_decode_ref
+    cg, csg, krg, krsg = _gather_latent(c_vals, c_scale, kr_vals, kr_scale,
+                                        block_tables, w_uk.shape[0],
+                                        q_rope.shape[-1])
+    return mla_decode_ref(q_nope, q_rope, cg, csg, krg, krsg, w_uk, w_uv,
+                          lengths, None)
 
 
 def paged_prefix_chunk_attention_ref(q: jax.Array,
@@ -224,16 +274,15 @@ def paged_prefix_chunk_attention_ref(q: jax.Array,
 
 def mla_paged_prefix_chunk_attention_ref(q_lat: jax.Array, q_rope: jax.Array,
                                          c_vals: jax.Array, c_scale: jax.Array,
-                                         c_zero: jax.Array, kr_vals: jax.Array,
-                                         kr_scale: jax.Array, kr_zero: jax.Array,
+                                         kr_vals: jax.Array, kr_scale: jax.Array,
                                          c_chunk: jax.Array, kr_chunk: jax.Array,
                                          block_row: jax.Array, ctx: jax.Array,
                                          *, qk_nope_dim: int) -> jax.Array:
     """MLA chunk-prefill attention in absorbed latent space.
 
     q_lat: (1,C,H,rkv) absorbed queries (q_nope @ W_uk); q_rope: (1,C,H,dr);
-    c_vals: (N,T,rkv) int8 latent pool with per-slot affine c_scale/c_zero:
-    (rkv,); kr_vals: (N,T,dr) with kr_scale/kr_zero: (dr,); c_chunk:
+    c_vals: (N,T,rkv) int8 latent pool with per-token scales c_scale:
+    (T,N); kr_vals: (N,T,dr) with kr_scale: (T,N); c_chunk:
     (1,C,rkv) / kr_chunk: (1,C,dr) the chunk's fresh fp latent; block_row:
     (M,); ctx: () -> o_lat (1,C,H,rkv) f32 (caller applies W_uv).  Same
     masking rules as the GQA chunk oracle; the softmax scale is the expanded
@@ -246,9 +295,9 @@ def mla_paged_prefix_chunk_attention_ref(q_lat: jax.Array, q_rope: jax.Array,
     kr_vals = _unpack_pool(kr_vals, dr)
     f32 = jnp.float32
     scale = 1.0 / jnp.sqrt(qk_nope_dim + dr)
-    c_pre = ((c_vals[block_row].astype(f32) - c_zero) * c_scale
+    c_pre = (c_vals[block_row].astype(f32) * _token_rows(c_scale, block_row)
              ).reshape(m * t, rkv)
-    kr_pre = ((kr_vals[block_row].astype(f32) - kr_zero) * kr_scale
+    kr_pre = (kr_vals[block_row].astype(f32) * _token_rows(kr_scale, block_row)
               ).reshape(m * t, dr)
     c_all = jnp.concatenate([c_pre, c_chunk[0].astype(f32)], axis=0)
     kr_all = jnp.concatenate([kr_pre, kr_chunk[0].astype(f32)], axis=0)

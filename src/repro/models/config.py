@@ -1,10 +1,13 @@
 """ModelConfig — one dataclass covering all assigned architecture families.
 
-A model is a repeating ``layer_pattern`` of (mixer, ffn) pairs:
+A model is ``first_k_dense_replace`` leading layers (the pattern's mixer
+with a dense FFN, DeepSeek-V3's ``first_k_dense_replace``) followed by a
+repeating ``layer_pattern`` of (mixer, ffn) pairs:
   mixer ∈ {"attn", "mla", "ssm"};  ffn ∈ {"dense", "moe"}.
-``n_layers`` must be a multiple of ``len(layer_pattern)``; the stack is
-executed as ``lax.scan`` over ``n_layers / P`` repeats with the P pattern
-positions unrolled inside the block (small HLO even for 64-layer models,
+The leading layers run one by one; the remaining ``n_layers -
+first_k_dense_replace`` must be a multiple of ``len(layer_pattern)`` and
+execute as ``lax.scan`` over their repeats with the P pattern positions
+unrolled inside the block (small HLO even for 64-layer models,
 heterogeneous patterns like Jamba's attn:ssm 1:7 / MoE-every-2 supported).
 """
 from __future__ import annotations
@@ -43,18 +46,27 @@ class ModelConfig:
     prefix_lm: bool = False                   # bidirectional prefix (PaliGemma)
 
     # MLA (MiniCPM3 / DeepSeek-style)
-    q_lora_rank: int = 0                      # 0 = standard attention
+    q_lora_rank: int = 0                      # 0 = one direct q projection
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0                        # experts held by this chip
     n_experts_active: int = 0                 # top-k
     moe_d_ff: int = 0                         # expert hidden dim (0 -> d_ff)
-    n_shared_experts: int = 0                 # Llama-4 shared expert
-    capacity_factor: float = 1.25
+    n_shared_experts: int = 0                 # shared experts (one SwiGLU of
+                                              # n_shared * moe_d_ff)
+    n_routed_experts: int = 0                 # router width (0 -> n_experts):
+                                              # experts over all chips
+    expert_offset: int = 0                    # id of the first held expert
+    moe_score: str = "softmax"                # "softmax" | "sigmoid" router
+    moe_score_bias: bool = False              # select top-k on score plus a
+                                              # learned correction bias
+                                              # (DeepSeek-V3 noaux_tc)
+    moe_routed_scale: float = 1.0             # routed weights' scale
+    capacity_factor: float = 1.25             # training dispatch; 0 = dropless
     router_aux_coef: float = 0.01
     moe_group_size: int = 4096                # GShard routing-group size
 
@@ -68,6 +80,7 @@ class ModelConfig:
 
     # Layer pattern (defaults to all-(attn,dense))
     layer_pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
+    first_k_dense_replace: int = 0            # leading (mixer, dense) layers
 
     # Multimodal stubs
     n_codebooks: int = 0                      # MusicGen EnCodec streams (K)
@@ -86,6 +99,18 @@ class ModelConfig:
     remat: bool = True
     remat_policy: str = "nothing"             # nothing | dots_nobatch | everything
 
+    def __post_init__(self):
+        # moe_apply, the capacity dispatch, routes a softmax over the held
+        # experts alone; anything else would train silently on the wrong
+        # router or fail far from the cause
+        if self.capacity_factor > 0 and self.n_experts and (
+                self.moe_score != "softmax" or self.moe_score_bias
+                or self.moe_routed_scale != 1.0 or self.expert_offset
+                or self.router_width != self.n_experts):
+            raise ValueError(
+                f"{self.name}: a sigmoid, biased, scaled or partly held "
+                "router runs dropless only: set capacity_factor=0")
+
     # --- derived -----------------------------------------------------------
     @property
     def kv_heads(self) -> int:
@@ -101,10 +126,25 @@ class ModelConfig:
 
     @property
     def n_repeats(self) -> int:
-        assert self.n_layers % self.pattern_len == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
+        scanned = self.n_layers - self.first_k_dense_replace
+        assert scanned % self.pattern_len == 0, (
+            f"{self.name}: n_layers={self.n_layers} less "
+            f"{self.first_k_dense_replace} leading layers not divisible by "
             f"pattern length {self.pattern_len}")
-        return self.n_layers // self.pattern_len
+        return scanned // self.pattern_len
+
+    @property
+    def lead_spec(self) -> LayerSpec:
+        """The leading layers' (mixer, ffn): the pattern's mixer, dense."""
+        return LayerSpec(self.layer_pattern[0].mixer, "dense")
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def has_moe(self) -> bool:
+        return any(s.ffn == "moe" for s in self.layer_pattern)
 
     @property
     def d_inner(self) -> int:                 # SSM inner width
@@ -150,7 +190,10 @@ class ModelConfig:
         if self.n_codebooks:
             total += (self.n_codebooks - 1) * v * d      # extra codebook embeds
             total += (self.n_codebooks - 1) * d * v      # extra heads
-        for spec in self.layer_pattern:
+        lead = ((self.lead_spec, self.first_k_dense_replace),) \
+            if self.first_k_dense_replace else ()
+        for spec, times in lead + tuple((s, self.n_repeats)
+                                        for s in self.layer_pattern):
             cnt = 2 * d                                  # 2 norms (approx; ssm has 1+)
             if spec.mixer == "attn":
                 h, kh, hd = self.n_heads, self.kv_heads, self.hd
@@ -163,7 +206,8 @@ class ModelConfig:
                 r_q, r_kv = self.q_lora_rank, self.kv_lora_rank
                 h = self.n_heads
                 qd = self.qk_nope_head_dim + self.qk_rope_head_dim
-                cnt += d * r_q + r_q * h * qd            # q_a, q_b
+                cnt += (d * r_q + r_q * h * qd if r_q     # q_a, q_b
+                        else d * h * qd)                 # direct q
                 cnt += d * (r_kv + self.qk_rope_head_dim)  # kv_a
                 cnt += r_kv * h * (self.qk_nope_head_dim + self.v_head_dim)  # kv_b
                 cnt += h * self.v_head_dim * d           # wo
@@ -179,10 +223,12 @@ class ModelConfig:
                 cnt += 3 * d * self.d_ff                 # SwiGLU
             elif spec.ffn == "moe":
                 f = self.expert_d_ff
-                cnt += d * self.n_experts                # router
+                cnt += d * self.router_width             # router
+                if self.moe_score_bias:
+                    cnt += self.router_width
                 cnt += self.n_experts * 3 * d * f
                 cnt += self.n_shared_experts * 3 * d * f
-            total += cnt * self.n_repeats
+            total += cnt * times
         total += d                                       # final norm
         return int(total)
 
@@ -193,7 +239,7 @@ class ModelConfig:
         full = self.param_count()
         f = self.expert_d_ff
         n_moe_layers = sum(1 for s in self.layer_pattern if s.ffn == "moe") * self.n_repeats
-        inactive = (self.n_experts - self.n_experts_active) * 3 * self.d_model * f
+        inactive = max(self.n_experts - self.n_experts_active, 0) * 3 * self.d_model * f
         return int(full - n_moe_layers * inactive)
 
 

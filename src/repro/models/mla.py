@@ -31,10 +31,14 @@ def mla_init(key, cfg: ModelConfig):
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 5)
     dt = jnp.dtype(cfg.param_dtype)
+    if rq:
+        q = {"q_a": dense_init(ks[0], (d, rq), dt),
+             "q_a_norm": jnp.ones((rq,), dt),
+             "q_b": dense_init(ks[1], (rq, h * (dn + dr)), dt)}
+    else:                                   # DeepSeek-V3 without q-LoRA
+        q = {"q_proj": dense_init(ks[0], (d, h * (dn + dr)), dt)}
     return {
-        "q_a": dense_init(ks[0], (d, rq), dt),
-        "q_a_norm": jnp.ones((rq,), dt),
-        "q_b": dense_init(ks[1], (rq, h * (dn + dr)), dt),
+        **q,
         "kv_a": dense_init(ks[2], (d, rkv + dr), dt),
         "kv_a_norm": jnp.ones((rkv,), dt),
         "kv_b": dense_init(ks[3], (rkv, h * (dn + dv)), dt),
@@ -48,8 +52,12 @@ def mla_queries(p, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     h = cfg.n_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     dt = x.dtype
-    q = rms_norm(qdot(x, p["q_a"]), p["q_a_norm"], cfg.norm_eps)
-    q = qdot(q, p["q_b"]).reshape(b, s, h, dn + dr)
+    if "q_proj" in p:
+        q = qdot(x, p["q_proj"])
+    else:
+        q = qdot(rms_norm(qdot(x, p["q_a"]), p["q_a_norm"], cfg.norm_eps),
+                 p["q_b"])
+    q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     # TP boundary: q_b is column-parallel over `heads`, so the paged MLA
@@ -107,22 +115,23 @@ def mla_absorbed_weights(p, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
 
 
 def mla_decode_ref(q_nope: jax.Array, q_rope: jax.Array,
-                   c_vals: jax.Array, c_scale: jax.Array, c_zero: jax.Array,
-                   kr_vals: jax.Array, kr_scale: jax.Array, kr_zero: jax.Array,
+                   c_vals: jax.Array, c_scale: jax.Array,
+                   kr_vals: jax.Array, kr_scale: jax.Array,
                    w_uk: jax.Array, w_uv: jax.Array,
                    length: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Absorbed MLA decode over the quantized latent cache (jnp oracle).
 
-    q_nope: (B,H,dn), q_rope: (B,H,dr); c_vals: (B,Smax,rkv) int8 latent with
-    per-channel affine (c_scale/c_zero: (B,1,rkv)); kr_vals: (B,Smax,dr)
-    quantized rope keys.  Returns (B, H, dv) pre-wo attention output.
+    q_nope: (B,H,dn), q_rope: (B,H,dr); c_vals: (B,Smax,rkv) int8 latent
+    codes with per-token scales c_scale: (B,Smax,1); kr_vals: (B,Smax,dr)
+    rope-key codes with kr_scale: (B,Smax,1).  Returns (B, H, dv) pre-wo
+    attention output.
     """
     b, h, dn = q_nope.shape
     dr = q_rope.shape[-1]
     smax = c_vals.shape[1]
     scale = 1.0 / jnp.sqrt(dn + dr)
-    c = (c_vals.astype(jnp.float32) - c_zero) * c_scale          # (B,S,rkv)
-    kr = (kr_vals.astype(jnp.float32) - kr_zero) * kr_scale      # (B,S,dr)
+    c = c_vals.astype(jnp.float32) * c_scale                     # (B,S,rkv)
+    kr = kr_vals.astype(jnp.float32) * kr_scale                  # (B,S,dr)
     # absorb: q_lat = q_nope @ W_uk  -> (B,H,rkv)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))

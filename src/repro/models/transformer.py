@@ -33,7 +33,7 @@ from .config import LayerSpec, ModelConfig
 from .layers import apply_rope, dense_init, embed_init, rms_norm, rms_norm_init, swiglu_apply, swiglu_init
 from .mla import (mla_absorbed_weights, mla_apply, mla_decode_ref, mla_init,
                   mla_latent, mla_queries)
-from .moe import moe_apply, moe_init
+from .moe import moe_apply, moe_dropless, moe_init
 from .ssm import (ssm_apply, ssm_decode_step, ssm_init, ssm_prefill_chunk,
                   ssm_state_entry, ssm_state_from_entry)
 
@@ -86,8 +86,69 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         stacked = jax.vmap(lambda k: _layer_init(k, cfg, spec))(rep_keys)
         layers[f"p{i}"] = stacked
     params["layers"] = layers
+    if cfg.first_k_dense_replace:
+        # leading layers: their own subtree, stacked over first_k, run one by
+        # one ahead of the scan (a key of their own leaves the pattern's
+        # draws as they were)
+        lead_keys = jax.random.split(jax.random.fold_in(key, 1 << 20),
+                                     cfg.first_k_dense_replace)
+        params["lead"] = jax.vmap(
+            lambda k: _layer_init(k, cfg, cfg.lead_spec))(lead_keys)
     params["final_norm"] = rms_norm_init(cfg.d_model, dt)
     return params
+
+
+# ---------------------------------------------------------------------------
+# Leading layers and MoE helpers shared by every path
+# ---------------------------------------------------------------------------
+
+def _pattern(cfg: ModelConfig):
+    """(key, spec) of each scanned pattern position."""
+    return tuple((f"p{i}", s) for i, s in enumerate(cfg.layer_pattern))
+
+
+def _lead_specs(cfg: ModelConfig):
+    return ((pgc.LEAD, cfg.lead_spec),)
+
+
+def _at(tree, i: int):
+    return jax.tree_util.tree_map(lambda a: a[i], tree)
+
+
+def _stack(trees):
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
+
+
+def _moe(p, y, cfg: ModelConfig, *, valid, gather: bool):
+    """Dropless MoE over y (..., D) -> (out (..., D), counts int32 in
+    ``moe.COUNTERS`` order);
+    ``valid`` (y's leading shape) marks real tokens."""
+    f, counts = moe_dropless(p, y.reshape(-1, y.shape[-1]), cfg,
+                             valid=valid.reshape(-1), gather=gather)
+    return f.reshape(y.shape), counts
+
+
+def _add_counts(acc, counts):
+    return counts if acc is None else acc + counts
+
+
+def _with_lead(new_pool, new_lead):
+    """The scanned entries plus the leading layers' entry, restacked."""
+    if new_lead:
+        new_pool = dict(new_pool)
+        new_pool[pgc.LEAD] = _stack(new_lead)
+    return new_pool
+
+
+def _with_stats(new_pool, pool, counts):
+    """Fold one step's MoE counters (stacked over the scanned repeats) into
+    the pool's running totals (uint32, wrapping: the scheduler reads
+    differences)."""
+    if pgc.STATS in pool:
+        new_pool = dict(new_pool)
+        new_pool[pgc.STATS] = {"moe": pool[pgc.STATS]["moe"]
+                               + jnp.sum(counts, axis=0).astype(jnp.uint32)}
+    return new_pool
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +228,7 @@ def logits_head(params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _block_full(p_blk, h, cfg: ModelConfig, *, positions, prefix_len: int,
-                mode: str, smax: int, capture: bool):
+                mode: str, smax: int, capture: bool, specs=None):
     """Full-sequence pass over one pattern repeat.
 
     Returns (h, aux, cache_entries, taps).  ``cache_entries``/{taps} are {}
@@ -178,11 +239,11 @@ def _block_full(p_blk, h, cfg: ModelConfig, *, positions, prefix_len: int,
     taps: Dict[str, Any] = {} if capture else None
     pos1d = positions[0] if positions.ndim > 1 else positions
 
-    for i, spec in enumerate(cfg.layer_pattern):
-        p = p_blk[f"p{i}"]
+    for key, spec in specs or _pattern(cfg):
+        p = p_blk[key]
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         if capture:
-            record_activation(taps, f"p{i}/attn_in", x)
+            record_activation(taps, f"{key}/attn_in", x)
         if spec.mixer == "attn":
             if mode == "prefill":
                 q, k, v = qkv_project(p["attn"], x, cfg, positions)
@@ -191,14 +252,14 @@ def _block_full(p_blk, h, cfg: ModelConfig, *, positions, prefix_len: int,
                 b, s, _, _ = q.shape
                 dtc = x.dtype
                 mix = qdot(out.reshape(b, s, -1), p["attn"]["wo"])
-                cache_entries[f"p{i}"] = kvc.gqa_cache_entry(k, v, smax)
+                cache_entries[key] = kvc.gqa_cache_entry(k, v, smax)
             else:
                 mix = attn_apply(p["attn"], x, cfg, positions=positions,
                                  prefix_len=prefix_len)
         elif spec.mixer == "mla":
             if mode == "prefill":
                 c_kv, k_rope = mla_latent(p["attn"], x, cfg, positions)
-                cache_entries[f"p{i}"] = kvc.mla_cache_entry(c_kv, k_rope, smax)
+                cache_entries[key] = kvc.mla_cache_entry(c_kv, k_rope, smax)
             mix = mla_apply(p["attn"], x, cfg, positions=positions,
                             prefix_len=prefix_len)
         else:  # ssm
@@ -207,7 +268,7 @@ def _block_full(p_blk, h, cfg: ModelConfig, *, positions, prefix_len: int,
                 # stored quantized (INT8 SSD codes + per-slot scales) — the
                 # same round-trip the paged state pool applies, so dense and
                 # paged hybrid serving stay token-for-token identical
-                cache_entries[f"p{i}"] = ssm_state_entry(state)
+                cache_entries[key] = ssm_state_entry(state)
             else:
                 mix = ssm_apply(p["ssm"], x, cfg)
         # constrain the mixer output to the residual's seq-sharding BEFORE the
@@ -220,27 +281,31 @@ def _block_full(p_blk, h, cfg: ModelConfig, *, positions, prefix_len: int,
         if spec.ffn != "none":
             y = rms_norm(h, p["norm_ffn"], cfg.norm_eps)
             if capture:
-                record_activation(taps, f"p{i}/ffn_in", y)
+                record_activation(taps, f"{key}/ffn_in", y)
             if spec.ffn == "dense":
                 f = swiglu_apply(p["ffn"], y, cfg.act_fn)
-            else:
+            elif mode == "train" and cfg.capacity_factor > 0:
                 f, aux_i = moe_apply(p["moe"], y, cfg)
                 aux = aux + aux_i
+            else:
+                f, _ = _moe(p["moe"], y, cfg, valid=jnp.ones(y.shape[:-1], bool),
+                            gather=False)
             f = constrain(f, "batch", "seq", "embed")
             h = h + f
             h = constrain(h, "batch", "seq", "embed")
     return h, aux, cache_entries, (taps if capture else {})
 
 
-def _block_decode(p_blk, h, cache_blk, cfg: ModelConfig, *, length):
+def _block_decode(p_blk, h, cache_blk, cfg: ModelConfig, *, length,
+                  specs=None):
     """One-token pass over one pattern repeat.  h: (B, D)."""
     new_cache: Dict[str, Any] = {}
     b = h.shape[0]
     positions = length[:, None]                           # (B,1)
 
-    for i, spec in enumerate(cfg.layer_pattern):
-        p = p_blk[f"p{i}"]
-        entry = cache_blk[f"p{i}"]
+    for key, spec in specs or _pattern(cfg):
+        p = p_blk[key]
+        entry = cache_blk[key]
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         if spec.mixer == "attn":
             q, k, v = qkv_project(p["attn"], x[:, None, :], cfg, positions)
@@ -256,15 +321,15 @@ def _block_decode(p_blk, h, cache_blk, cfg: ModelConfig, *, length):
             entry = kvc.mla_cache_append(entry, c_t[:, 0], kr_t[:, 0], length)
             w_uk, w_uv = mla_absorbed_weights(p["attn"], cfg)
             out = mla_decode_ref(q_nope[:, 0], q_rope[:, 0],
-                                 entry["c_vals"], entry["c_scale"], entry["c_zero"],
-                                 entry["kr_vals"], entry["kr_scale"], entry["kr_zero"],
+                                 entry["c_vals"], entry["c_scale"],
+                                 entry["kr_vals"], entry["kr_scale"],
                                  w_uk, w_uv, length + 1, cfg)
             mix = qdot(out.astype(x.dtype).reshape(b, -1), p["attn"]["wo"])
         else:
             work = ssm_state_from_entry(entry)
             mix, work = ssm_decode_step(p["ssm"], x, work, cfg)
             entry = ssm_state_entry(work)
-        new_cache[f"p{i}"] = entry
+        new_cache[key] = entry
         h = h + mix.astype(h.dtype)
 
         if spec.ffn != "none":
@@ -272,8 +337,8 @@ def _block_decode(p_blk, h, cache_blk, cfg: ModelConfig, *, length):
             if spec.ffn == "dense":
                 f = swiglu_apply(p["ffn"], y[:, None, :], cfg.act_fn)[:, 0]
             else:
-                f, _ = moe_apply(p["moe"], y[:, None, :], cfg)
-                f = f[:, 0]
+                f, _ = _moe(p["moe"], y, cfg, valid=jnp.ones((b,), bool),
+                            gather=False)
             h = h + f.astype(h.dtype)
     return h, new_cache
 
@@ -296,6 +361,16 @@ def _scan_full(params, h, cfg: ModelConfig, *, positions, prefix_len, mode,
             h_new = constrain(h_new, "batch", "seq_carry", "embed")
         return (h_new, aux + aux_i), (cache_i, taps_i)
 
+    aux = jnp.zeros((), jnp.float32)
+    lead_cache, lead_taps = [], []
+    for l in range(cfg.first_k_dense_replace):
+        h, aux_l, cache_l, taps_l = block({pgc.LEAD: _at(params["lead"], l)},
+                                          h, specs=_lead_specs(cfg))
+        if mode == "train":
+            h = constrain(h, "batch", "seq_carry", "embed")
+        aux = aux + aux_l
+        lead_cache.append(cache_l)
+        lead_taps.append(taps_l)
     if cfg.remat and mode == "train":
         policy = {
             "dots_nobatch": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
@@ -303,9 +378,12 @@ def _scan_full(params, h, cfg: ModelConfig, *, positions, prefix_len, mode,
             "everything": jax.checkpoint_policies.everything_saveable,
         }[cfg.remat_policy]
         body = jax.checkpoint(body, policy=policy)
-    (h, aux), (cache, taps) = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                                           params["layers"])
-    return h, aux, cache, taps
+    (h, aux_s), (cache, taps) = jax.lax.scan(body, (h, aux), params["layers"])
+    if lead_cache:
+        # stacked over the leading layers, as the scan stacks its repeats
+        cache = dict(cache, **_stack(lead_cache))
+        taps = dict(taps, **_stack(lead_taps))
+    return h, aux_s, cache, taps
 
 
 def forward_train(params, batch, cfg: ModelConfig, *, capture: bool = False):
@@ -349,13 +427,23 @@ def forward_decode(params, tokens_t, cache, cfg: ModelConfig):
         h = params["embed"]["tok"][tokens_t]
     h = h.astype(dt)                                       # (B, D)
     length = cache["length"]
+    entries = dict(cache["entries"])
+    lead = entries.pop(pgc.LEAD, None)
+    new_lead = []
+    for l in range(cfg.first_k_dense_replace):
+        h, new_l = _block_decode({pgc.LEAD: _at(params["lead"], l)}, h,
+                                 {pgc.LEAD: _at(lead, l)}, cfg,
+                                 length=length, specs=_lead_specs(cfg))
+        new_lead.append(new_l[pgc.LEAD])
 
     def body(h, xs):
         p_blk, cache_blk = xs
         h_new, cache_new = _block_decode(p_blk, h, cache_blk, cfg, length=length)
         return h_new, cache_new
 
-    h, new_entries = jax.lax.scan(body, h, (params["layers"], cache["entries"]))
+    h, new_entries = jax.lax.scan(body, h, (params["layers"], entries))
+    if new_lead:
+        new_entries = dict(new_entries, **{pgc.LEAD: _stack(new_lead)})
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = logits_head(params, h[:, None, :], cfg)[:, 0]
     return logits, {"entries": new_entries, "length": length + 1}
@@ -377,7 +465,8 @@ def _gather_heads(out):
 
 def _block_prefill_chunk(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
                          positions, slot, block_row, ctx, chunk_len,
-                         block_size: int, is_first: bool, state_slot):
+                         block_size: int, is_first: bool, state_slot,
+                         specs=None):
     """One pattern repeat of a prefill *chunk* (B=1) against the block pool.
 
     The chunk's queries attend to the request's cached prefix (read straight
@@ -387,17 +476,19 @@ def _block_prefill_chunk(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
     (static) skips the prefix read and freezes the per-channel K scales.  SSM layers carry
     conv/SSD state across chunk boundaries through the state pool
     (``state_slot``): read -> chunk-exact scan -> write back quantized.
+    Returns (h, new pool, new state pool, MoE counts or None).
     """
     new_pool: Dict[str, Any] = {}
     new_spool: Dict[str, Any] = {}
+    counts = None
     pos1d = positions[0] if positions.ndim > 1 else positions
     c = h.shape[1]
 
-    for i, spec in enumerate(cfg.layer_pattern):
-        p = p_blk[f"p{i}"]
+    for key, spec in specs or _pattern(cfg):
+        p = p_blk[key]
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         if spec.mixer == "attn":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q, k, v = qkv_project(p["attn"], x, cfg, positions)
             entry = pgc.gqa_chunk_write(
                 entry, k[0], v[0], slot=slot, block_row=block_row, ctx=ctx,
@@ -414,15 +505,14 @@ def _block_prefill_chunk(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
                     entry["v_zero"], k, v, block_row, ctx)
             mix = qdot(_gather_heads(out.astype(x.dtype).reshape(1, c, -1)),
                        p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         elif spec.mixer == "mla":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q_nope, q_rope = mla_queries(p["attn"], x, cfg, positions)
             c_kv, k_rope = mla_latent(p["attn"], x, cfg, positions)
             entry = pgc.mla_chunk_write(
-                entry, c_kv[0], k_rope[0], slot=slot, block_row=block_row,
-                ctx=ctx, chunk_len=chunk_len, block_size=block_size,
-                is_first=is_first)
+                entry, c_kv[0], k_rope[0], block_row=block_row, ctx=ctx,
+                chunk_len=chunk_len, block_size=block_size)
             h_heads = cfg.n_heads
             dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
             dv = cfg.v_head_dim
@@ -446,23 +536,22 @@ def _block_prefill_chunk(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
                                    q_nope.astype(jnp.float32),
                                    w_uk.astype(jnp.float32))
                 o_lat = ops.mla_paged_prefix_chunk_attention(
-                    q_lat, q_rope, entry["c_vals"], entry["c_scale"][slot],
-                    entry["c_zero"][slot], entry["kr_vals"],
-                    entry["kr_scale"][slot], entry["kr_zero"][slot],
-                    c_kv, k_rope, block_row, ctx, qk_nope_dim=dn)
+                    q_lat, q_rope, entry["c_vals"], entry["c_scale"],
+                    entry["kr_vals"], entry["kr_scale"], c_kv, k_rope,
+                    block_row, ctx, qk_nope_dim=dn)
                 out = jnp.einsum("bchr,rhd->bchd", o_lat,
                                  w_uv.astype(jnp.float32))
             mix = qdot(
                 _gather_heads(out.astype(x.dtype).reshape(1, c, h_heads * dv)),
                 p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         else:  # ssm: state pool carry across chunk boundaries
-            sentry = spool_blk[f"p{i}"]
+            sentry = spool_blk[key]
             carried = None if is_first else spl.read_state(sentry, state_slot)
             mix, work = ssm_prefill_chunk(p["ssm"], x, cfg, state=carried,
                                           chunk_len=chunk_len,
                                           is_first=is_first)
-            new_spool[f"p{i}"] = spl.write_state(sentry, state_slot, work)
+            new_spool[key] = spl.write_state(sentry, state_slot, work)
         # chunk/verify activations keep seq unsharded (the chunk is small;
         # sharding C over `model` would fight the TP head sharding) — the
         # constraint marks the row-parallel wo/w_out reduce-scatter boundary
@@ -472,9 +561,12 @@ def _block_prefill_chunk(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
             if spec.ffn == "dense":
                 f = swiglu_apply(p["ffn"], y, cfg.act_fn, gather=True)
             else:
-                f, _ = moe_apply(p["moe"], y, cfg, gather=True)
+                f, cnt = _moe(p["moe"], y, cfg,
+                              valid=(jnp.arange(c) < chunk_len)[None],
+                              gather=True)
+                counts = _add_counts(counts, cnt)
             h = h + constrain(f, "batch", None, "embed")
-    return h, new_pool, new_spool
+    return h, new_pool, new_spool, counts
 
 
 def forward_prefill_chunk(params, tokens, pool, cfg: ModelConfig, *,
@@ -508,14 +600,21 @@ def forward_prefill_chunk(params, tokens, pool, cfg: ModelConfig, *,
                     chunk_len=chunk_len, block_size=block_size,
                     is_first=is_first,
                     state_slot=jnp.asarray(state_slot, jnp.int32).reshape(1))
+    new_lead = []
+    for l in range(cfg.first_k_dense_replace):
+        h, new_l, _, _ = block({pgc.LEAD: _at(params["lead"], l)}, h,
+                               {pgc.LEAD: _at(pool[pgc.LEAD], l)}, {},
+                               specs=_lead_specs(cfg))
+        new_lead.append(new_l[pgc.LEAD])
 
     def body(h, xs):
         p_blk, pool_blk, spool_blk = xs
-        h, new_pool, new_spool = block(p_blk, h, pool_blk, spool_blk)
-        return h, (new_pool, new_spool)
+        h, new_pool, new_spool, counts = block(p_blk, h, pool_blk, spool_blk)
+        return h, (new_pool, new_spool, counts)
 
-    h, (new_pool, new_spool) = jax.lax.scan(body, h,
-                                            (params["layers"], pool, spool))
+    h, (new_pool, new_spool, counts) = jax.lax.scan(
+        body, h, (params["layers"], pgc.scanned(pool), spool))
+    new_pool = _with_stats(_with_lead(new_pool, new_lead), pool, counts)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     if chunk_logits:
         return logits_head(params, h, cfg), new_pool, new_spool
@@ -525,22 +624,26 @@ def forward_prefill_chunk(params, tokens, pool, cfg: ModelConfig, *,
 
 
 def _block_decode_paged(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
-                        block_tables, lengths, block_size: int, state_slots):
+                        block_tables, lengths, block_size: int, state_slots,
+                        specs=None):
     """One-token pass over one pattern repeat against the block pool.
 
     SSM layers step their recurrent state through the slot pool instead:
     gather + dequantize by ``state_slots``, one recurrent update, quantize +
-    scatter back (inactive lanes read/write the trash slot)."""
+    scatter back (inactive lanes read/write the trash slot).  Lanes with no
+    context (``lengths`` 0) are idle: their MoE rows route nowhere.
+    Returns (h, new pool, new state pool, MoE counts or None)."""
     new_pool: Dict[str, Any] = {}
     new_spool: Dict[str, Any] = {}
+    counts = None
     b = h.shape[0]
     positions = lengths[:, None]
 
-    for i, spec in enumerate(cfg.layer_pattern):
-        p = p_blk[f"p{i}"]
+    for key, spec in specs or _pattern(cfg):
+        p = p_blk[key]
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         if spec.mixer == "attn":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q, k, v = qkv_project(p["attn"], x[:, None, :], cfg, positions)
             entry = pgc.gqa_paged_append(entry, k[:, 0], v[:, 0],
                                          block_tables, lengths,
@@ -551,28 +654,27 @@ def _block_decode_paged(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
                 block_tables, lengths + 1)
             mix = qdot(_gather_heads(out.astype(x.dtype).reshape(b, -1)),
                        p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         elif spec.mixer == "mla":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q_nope, q_rope = mla_queries(p["attn"], x[:, None, :], cfg, positions)
             c_t, kr_t = mla_latent(p["attn"], x[:, None, :], cfg, positions)
             entry = pgc.mla_paged_append(entry, c_t[:, 0], kr_t[:, 0],
                                          block_tables, lengths,
                                          block_size=block_size)
-            gath = pgc.mla_gather_batch(entry, block_tables)
             w_uk, w_uv = mla_absorbed_weights(p["attn"], cfg)
-            out = mla_decode_ref(q_nope[:, 0], q_rope[:, 0],
-                                 gath["c_vals"], gath["c_scale"], gath["c_zero"],
-                                 gath["kr_vals"], gath["kr_scale"], gath["kr_zero"],
-                                 w_uk, w_uv, lengths + 1, cfg)
+            out = ops.mla_paged_decode_attention(
+                q_nope[:, 0], q_rope[:, 0], w_uk, w_uv,
+                entry["c_vals"], entry["c_scale"], entry["kr_vals"],
+                entry["kr_scale"], block_tables, lengths + 1)
             mix = qdot(_gather_heads(out.astype(x.dtype).reshape(b, -1)),
                        p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         else:  # ssm: O(1) recurrent update through the state slot pool
-            sentry = spool_blk[f"p{i}"]
+            sentry = spool_blk[key]
             work = spl.read_state(sentry, state_slots)
             mix, work = ssm_decode_step(p["ssm"], x, work, cfg)
-            new_spool[f"p{i}"] = spl.write_state(sentry, state_slots, work)
+            new_spool[key] = spl.write_state(sentry, state_slots, work)
         h = h + constrain(mix.astype(h.dtype), "batch", "embed")
 
         if spec.ffn != "none":
@@ -581,10 +683,11 @@ def _block_decode_paged(p_blk, h, pool_blk, spool_blk, cfg: ModelConfig, *,
                 f = swiglu_apply(p["ffn"], y[:, None, :], cfg.act_fn,
                                  gather=True)[:, 0]
             else:
-                f, _ = moe_apply(p["moe"], y[:, None, :], cfg, gather=True)
-                f = f[:, 0]
+                f, cnt = _moe(p["moe"], y, cfg, valid=lengths > 0,
+                              gather=True)
+                counts = _add_counts(counts, cnt)
             h = h + constrain(f.astype(h.dtype), "batch", "embed")
-    return h, new_pool, new_spool
+    return h, new_pool, new_spool, counts
 
 
 def forward_decode_paged(params, tokens_t, pool, block_tables, lengths,
@@ -608,23 +711,32 @@ def forward_decode_paged(params, tokens_t, pool, block_tables, lengths,
     else:
         h = params["embed"]["tok"][tokens_t]
     h = constrain(h.astype(dt), "batch", "embed")          # (B, D)
+    block = partial(_block_decode_paged, cfg=cfg, block_tables=block_tables,
+                    lengths=lengths, block_size=block_size,
+                    state_slots=state_slots)
+    new_lead = []
+    for l in range(cfg.first_k_dense_replace):
+        h, new_l, _, _ = block({pgc.LEAD: _at(params["lead"], l)}, h,
+                               {pgc.LEAD: _at(pool[pgc.LEAD], l)}, {},
+                               specs=_lead_specs(cfg))
+        new_lead.append(new_l[pgc.LEAD])
 
     def body(h, xs):
         p_blk, pool_blk, spool_blk = xs
-        h, new_pool, new_spool = _block_decode_paged(
-            p_blk, h, pool_blk, spool_blk, cfg, block_tables=block_tables,
-            lengths=lengths, block_size=block_size, state_slots=state_slots)
-        return h, (new_pool, new_spool)
+        h, new_pool, new_spool, counts = block(p_blk, h, pool_blk, spool_blk)
+        return h, (new_pool, new_spool, counts)
 
-    h, (new_pool, new_spool) = jax.lax.scan(body, h,
-                                            (params["layers"], pool, spool))
+    h, (new_pool, new_spool, counts) = jax.lax.scan(
+        body, h, (params["layers"], pgc.scanned(pool), spool))
+    new_pool = _with_stats(_with_lead(new_pool, new_lead), pool, counts)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = logits_head(params, h[:, None, :], cfg)[:, 0]
     return logits, new_pool, new_spool
 
 
 def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
-                        block_tables, lengths, vlens, block_size: int):
+                        block_tables, lengths, vlens, block_size: int,
+                        specs=None):
     """Multi-token verify pass over one pattern repeat (speculative decoding).
 
     h: (B, G, D) — position j of lane b sits at sequence position
@@ -641,14 +753,15 @@ def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
     invisible to earlier positions.
     """
     new_pool: Dict[str, Any] = {}
+    counts = None
     b, g = h.shape[0], h.shape[1]
     positions = lengths[:, None] + jnp.arange(g)[None, :]          # (B, G)
 
-    for i, spec in enumerate(cfg.layer_pattern):
-        p = p_blk[f"p{i}"]
+    for key, spec in specs or _pattern(cfg):
+        p = p_blk[key]
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         if spec.mixer == "attn":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q, k, v = qkv_project(p["attn"], x, cfg, positions)
             trash = entry["k_vals"].shape[0] - 1       # (N+1, T, KH*D)
             for j in range(g):
@@ -662,9 +775,9 @@ def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
                 block_tables, lengths)                             # (B,G,H,D)
             mix = qdot(_gather_heads(out.astype(x.dtype).reshape(b, g, -1)),
                        p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         elif spec.mixer == "mla":
-            entry = pool_blk[f"p{i}"]
+            entry = pool_blk[key]
             q_nope, q_rope = mla_queries(p["attn"], x, cfg, positions)
             c_t, kr_t = mla_latent(p["attn"], x, cfg, positions)
             trash = entry["c_vals"].shape[0] - 1       # (N+1, T, rkv)
@@ -676,12 +789,11 @@ def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
             w_uk, w_uv = mla_absorbed_weights(p["attn"], cfg)
             out = ops.mla_paged_verify_attention(
                 q_nope, q_rope, w_uk, w_uv,
-                entry["c_vals"], entry["c_scale"], entry["c_zero"],
-                entry["kr_vals"], entry["kr_scale"], entry["kr_zero"],
-                block_tables, lengths)                             # (B,G,H,dv)
+                entry["c_vals"], entry["c_scale"], entry["kr_vals"],
+                entry["kr_scale"], block_tables, lengths)          # (B,G,H,dv)
             mix = qdot(_gather_heads(out.astype(x.dtype).reshape(b, g, -1)),
                        p["attn"]["wo"])
-            new_pool[f"p{i}"] = entry
+            new_pool[key] = entry
         else:
             raise NotImplementedError(
                 "spec-decode verify has no SSM rewind path; gate via "
@@ -693,9 +805,12 @@ def _block_verify_paged(p_blk, h, pool_blk, cfg: ModelConfig, *,
             if spec.ffn == "dense":
                 f = swiglu_apply(p["ffn"], y, cfg.act_fn, gather=True)
             else:
-                f, _ = moe_apply(p["moe"], y, cfg, gather=True)
+                f, cnt = _moe(p["moe"], y, cfg,
+                              valid=jnp.arange(g)[None, :] < vlens[:, None],
+                              gather=True)
+                counts = _add_counts(counts, cnt)
             h = h + constrain(f.astype(h.dtype), "batch", None, "embed")
-    return h, new_pool
+    return h, new_pool, counts
 
 
 def forward_verify_paged(params, tokens, pool, block_tables, lengths, vlens,
@@ -720,15 +835,23 @@ def forward_verify_paged(params, tokens, pool, block_tables, lengths, vlens,
     dt = cfg.compute_dtype
     h = params["embed"]["tok"][tokens].astype(dt)          # (B, G, D)
     h = constrain(h, "batch", None, "embed")
+    block = partial(_block_verify_paged, cfg=cfg, block_tables=block_tables,
+                    lengths=lengths, vlens=vlens, block_size=block_size)
+    new_lead = []
+    for l in range(cfg.first_k_dense_replace):
+        h, new_l, _ = block({pgc.LEAD: _at(params["lead"], l)}, h,
+                            {pgc.LEAD: _at(pool[pgc.LEAD], l)},
+                            specs=_lead_specs(cfg))
+        new_lead.append(new_l[pgc.LEAD])
 
     def body(h, xs):
         p_blk, pool_blk = xs
-        h, new_pool = _block_verify_paged(
-            p_blk, h, pool_blk, cfg, block_tables=block_tables,
-            lengths=lengths, vlens=vlens, block_size=block_size)
-        return h, new_pool
+        h, new_pool, counts = block(p_blk, h, pool_blk)
+        return h, (new_pool, counts)
 
-    h, new_pool = jax.lax.scan(body, h, (params["layers"], pool))
+    h, (new_pool, counts) = jax.lax.scan(body, h,
+                                         (params["layers"], pgc.scanned(pool)))
+    new_pool = _with_stats(_with_lead(new_pool, new_lead), pool, counts)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = logits_head(params, h, cfg)                   # (B, G, V)
     return logits, new_pool

@@ -30,12 +30,12 @@ the block can enter any block table.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.qtensor import pack_nibbles, unpack_nibbles
+from repro.core.qtensor import int_range, pack_nibbles, unpack_nibbles
 
 # The pool's carrier dtype.  Packed codecs store several codes per carrier
 # element; this is the only module allowed to name the concrete dtype.
@@ -77,15 +77,30 @@ def get_codec(codec) -> CacheCodec:
         raise ValueError(f"unknown cache codec {codec!r}; have {sorted(CODECS)}")
 
 
+def latent_rows(x: jax.Array, bits: int) -> Tuple[jax.Array, jax.Array]:
+    """Symmetric per-token codes of MLA latent or rope-key rows (..., dim):
+    -> (codes f32 (..., dim), scale f32 (..., 1)).  The latent is RMS-normed
+    and the rope key is a rotation, so both centre on zero: one absmax scale
+    per token and no zero point.  A token's codes depend on that token
+    alone, so a later token never clips against an earlier token's range.
+    The dense cache and the paged pool both quantize through here."""
+    _, qmax = int_range(bits)
+    x = x.astype(jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True),
+                        1e-8) / qmax
+    return jnp.clip(jnp.round(x / scale), -qmax, qmax), scale
+
+
 # ---------------------------------------------------------------------------
 # Bit-ladder primitives (int8 pool only)
 # ---------------------------------------------------------------------------
 
 # Pool-entry leaves holding integer codes (block axis 1) vs. the per-token
 # f32 affine rows that must survive demotion alongside them (block axis
-# last: ``paged_cache`` stores them (R, KH, T, N+1), blocks as lanes).
+# last: ``paged_cache`` stores them (R, KH, T, N+1) and (R, T, N+1), blocks
+# as lanes).
 CODE_LEAVES = ("k_vals", "v_vals", "c_vals", "kr_vals")
-PAIR_LEAVES = ("v_scale", "v_zero")
+PAIR_LEAVES = ("v_scale", "v_zero", "c_scale", "kr_scale")
 
 
 def block_axis(name: str) -> int:

@@ -8,8 +8,8 @@ Layouts (per pattern-position, stacked over scan repeats on a leading axis):
         v_vals  int8 (R, B, Smax, KH, D)   per-token affine V
         v_scale f32  (R, B, Smax, KH, 1)   (computed online per appended token)
         v_zero  f32  (R, B, Smax, KH, 1)
-  MLA:  c_vals  int8 (R, B, Smax, rkv)  + per-channel scale/zero (R,B,1,rkv)
-        kr_vals int8 (R, B, Smax, dr)   + per-channel scale/zero (R,B,1,dr)
+  MLA:  c_vals  int8 (R, B, Smax, rkv)  + per-token scale (R,B,Smax,1)
+        kr_vals int8 (R, B, Smax, dr)   + per-token scale (R,B,Smax,1)
   SSM:  conv      bf16 (R, B, K-1, conv_dim)   causal-conv tail (x|B|C fused)
         ssd_vals  int8 (R, B, H, P, N)         quantized SSD state
         ssd_scale f32  (R, B, H)               per-slot per-head absmax scale
@@ -37,6 +37,7 @@ from repro.core.qtensor import int_range, storage_dtype
 from repro.core.methods.simquant import quantize_keys, quantize_values
 from repro.distributed.sharding import constrain
 from repro.models.config import ModelConfig
+from repro.serving.codec import latent_rows
 
 
 # ---------------------------------------------------------------------------
@@ -106,31 +107,27 @@ def gqa_cache_append(entry: Dict[str, jax.Array], k_t: jax.Array, v_t: jax.Array
 # ---------------------------------------------------------------------------
 
 def mla_cache_entry(c_kv: jax.Array, k_rope: jax.Array, smax: int) -> Dict[str, jax.Array]:
-    """Quantize the latent (B,S,rkv) + rope key (B,S,dr) per-channel."""
-    from repro.core.qtensor import minmax_scale_zero, quantize_affine
+    """Quantize the latent (B,S,rkv) + rope key (B,S,dr) per token."""
     out = {}
     for name, x in (("c", c_kv), ("kr", k_rope)):
-        scale, zero = minmax_scale_zero(x, bits=8, axis=(1,))     # reduce seq
-        q = quantize_affine(x, scale, zero, bits=8, axis=(1,))
+        q, scale = latent_rows(x, 8)
         pad = [(0, 0), (0, smax - x.shape[1]), (0, 0)]
-        out[f"{name}_vals"] = constrain_cache("", jnp.pad(q.values, pad))
-        out[f"{name}_scale"] = q.scale
-        out[f"{name}_zero"] = q.zero
+        out[f"{name}_vals"] = constrain_cache(
+            "", jnp.pad(q.astype(storage_dtype(8)), pad))
+        out[f"{name}_scale"] = jnp.pad(scale, pad, constant_values=1.0)
     return out
 
 
 def mla_cache_append(entry: Dict[str, jax.Array], c_t: jax.Array, kr_t: jax.Array,
                      pos: jax.Array) -> Dict[str, jax.Array]:
     """Append one token's latent (B,rkv) + rope key (B,dr) at ``pos``."""
-    qmin, qmax = int_range(8)
     new = dict(entry)
+    bidx = jnp.arange(c_t.shape[0])
     for name, x_t in (("c", c_t), ("kr", kr_t)):
-        scale = entry[f"{name}_scale"][:, 0]
-        zero = entry[f"{name}_zero"][:, 0]
-        q = jnp.clip(jnp.round(x_t.astype(jnp.float32) / scale) + zero,
-                     qmin, qmax).astype(storage_dtype(8))
-        bidx = jnp.arange(x_t.shape[0])
-        new[f"{name}_vals"] = entry[f"{name}_vals"].at[bidx, pos].set(q)
+        q, scale = latent_rows(x_t, 8)
+        new[f"{name}_vals"] = entry[f"{name}_vals"].at[bidx, pos].set(
+            q.astype(storage_dtype(8)))
+        new[f"{name}_scale"] = entry[f"{name}_scale"].at[bidx, pos].set(scale)
     return new
 
 

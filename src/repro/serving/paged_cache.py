@@ -15,8 +15,10 @@ scale rows keep the full dim, which is how readers infer the codec):
         v_zero  f32   (R, KH, T, N+1)
         k_scale f32   (R, B,   KH, D)      per-*slot* per-channel K affine,
         k_zero  f32   (R, B,   KH, D)      frozen at the first prefill chunk
-  MLA:  c_vals  codes (R, N+1, T, rkv/pack) + per-slot scale/zero (R, B, rkv)
-        kr_vals codes (R, N+1, T, dr/pack)  + per-slot scale/zero (R, B, dr)
+  MLA:  c_vals   codes (R, N+1, T, rkv/pack)
+        c_scale  f32   (R, T, N+1)         per-token symmetric scale
+        kr_vals  codes (R, N+1, T, dr/pack)
+        kr_scale f32   (R, T, N+1)
 
 ``R`` is the scan-repeat axis (pattern positions nest inside, exactly like
 the dense cache); ``N`` is the shared block count, ``T`` the tokens/block,
@@ -25,10 +27,10 @@ stores them row-major, exactly as the paged kernels DMA them: a token's
 heads sit side by side in one lane row of the value leaves, and the V
 scale/zero rows keep their blocks as lanes (the order the chip would
 otherwise pick for a ``(.., T, KH, 1)`` leaf, and then relayout around every
-kernel launch).  A request owns a row of a host-side block
-table mapping its logical block index -> pool block id; block ``N`` is a
-write-off trash block that absorbs stores from padded / inactive lanes so the
-jitted step needs no scatter masking.
+kernel launch); so do the MLA per-token scales.  A request owns a row of a
+host-side block table mapping its logical block index -> pool block id;
+block ``N`` is a write-off trash block that absorbs stores from padded /
+inactive lanes so the jitted step needs no scatter masking.
 
 Quantization math mirrors ``kv_cache.gqa_cache_entry`` / ``gqa_cache_append``
 op-for-op (same dtypes, same eps) so a single-chunk paged prefill produces
@@ -40,10 +42,12 @@ block, keeps a content-hash index over published full prefix blocks, and
 parks unreferenced-but-published blocks on an LRU cached list that is
 reclaimed under pressure.  One physical block can back many block tables
 (prefix sharing); a writer that would mutate a shared or published block
-copies it first (``copy_pool_block``).  Because the K affine is frozen
-per *slot*, a prefix hit also restores the publisher's scale rows into the
-matcher's slot (``snapshot_slot_scales`` / ``restore_slot_scales``) — shared
-int8 codes then dequantize bit-identically to the donor's run.
+copies it first (``copy_pool_block``).  Because the GQA K affine is
+frozen per *slot*, a prefix hit also restores the publisher's scale rows
+into the matcher's slot (``snapshot_slot_scales`` /
+``restore_slot_scales``) — shared int8 codes then dequantize
+bit-identically to the donor's run.  The MLA scales are per token and
+travel with their blocks.
 """
 from __future__ import annotations
 
@@ -58,18 +62,24 @@ import numpy as np
 from repro.core.qtensor import int_range, pack_nibbles, unpack_nibbles
 from repro.models.config import ModelConfig
 from repro.serving.codec import (STORAGE_DTYPE, block_axis, block_index,
-                                 get_codec)
+                                 get_codec, latent_rows)
 
 TRASH = -1  # host-side marker; resolved to the pool's trash block id on use
+
+# pool keys beside the scanned pattern positions "p{i}": the leading layers'
+# entry (leaves stacked over ``first_k_dense_replace``) and the step's
+# running counters (``{"moe": uint32 (5,)}``, ``models/moe.py`` COUNTERS)
+LEAD = "lead"
+STATS = "stats"
 
 # leaf-name partition of a pool entry: BLOCK_LEAVES are indexed by pool block
 # id (copied on CoW, shared on a prefix hit) where ``codec.block_index`` says;
 # SLOT_SCALE_LEAVES are indexed by decode slot on axis 1 (snapshotted at
 # publish / restored on hit, since the frozen K affine travels with the
 # request, not the block)
-BLOCK_LEAVES = ("k_vals", "v_vals", "v_scale", "v_zero", "c_vals", "kr_vals")
-SLOT_SCALE_LEAVES = ("k_scale", "k_zero", "c_scale", "c_zero",
-                     "kr_scale", "kr_zero")
+BLOCK_LEAVES = ("k_vals", "v_vals", "v_scale", "v_zero", "c_vals", "c_scale",
+                "kr_vals", "kr_scale")
+SLOT_SCALE_LEAVES = ("k_scale", "k_zero")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,24 +102,37 @@ class PagedCacheConfig:
 # Pool allocation
 # ---------------------------------------------------------------------------
 
+def scanned(pool) -> Dict[str, Any]:
+    """The pattern positions' entries, which the layer scan carries."""
+    return {k: v for k, v in pool.items() if k not in (LEAD, STATS)}
+
+
 def init_paged_cache(cfg: ModelConfig, pcfg: PagedCacheConfig,
                      codec="int8") -> Dict[str, Any]:
     """Zero-filled block pool pytree: {"p{i}": leaves (R, ...)} per pattern
-    position.  SSM mixers have no sequence axis to page — their fixed-size
-    conv/SSD state lives in the slot pool (``state_pool.init_state_pool``),
-    so hybrid patterns simply skip those positions here.  ``codec`` picks
-    the code layout: packed codecs shrink every ``*_vals`` last dim by the
-    pack factor while scale rows keep the full dim (per head)."""
+    position, plus {LEAD: leaves (first_k, ...)} for leading layers and
+    {STATS: ...} counters for MoE patterns.  SSM mixers have no sequence
+    axis to page — their fixed-size conv/SSD state lives in the slot pool
+    (``state_pool.init_state_pool``), so hybrid patterns simply skip those
+    positions here.  ``codec`` picks the code layout: packed codecs shrink
+    every ``*_vals`` last dim by the pack factor while scale rows keep the
+    full dim (per head)."""
     cd = get_codec(codec)
-    r = cfg.n_repeats
     npool = pcfg.num_blocks + 1                     # + trash block
     t, b = pcfg.block_size, pcfg.max_batch
     entries: Dict[str, Any] = {}
-    for i, spec in enumerate(cfg.layer_pattern):
+    positions = [(f"p{i}", spec, cfg.n_repeats)
+                 for i, spec in enumerate(cfg.layer_pattern)]
+    if cfg.first_k_dense_replace:
+        if cfg.lead_spec.mixer == "ssm":
+            raise NotImplementedError(
+                f"{cfg.name}: leading layers page attention only")
+        positions.append((LEAD, cfg.lead_spec, cfg.first_k_dense_replace))
+    for key, spec, r in positions:
         if spec.mixer == "attn":
             kh, d = cfg.kv_heads, cfg.hd
             dp = cd.packed_dim(d)
-            entries[f"p{i}"] = {
+            entries[key] = {
                 "k_vals": jnp.zeros((r, npool, t, kh * dp), STORAGE_DTYPE),
                 "v_vals": jnp.zeros((r, npool, t, kh * dp), STORAGE_DTYPE),
                 "v_scale": jnp.zeros((r, kh, t, npool), jnp.float32),
@@ -119,26 +142,24 @@ def init_paged_cache(cfg: ModelConfig, pcfg: PagedCacheConfig,
             }
         elif spec.mixer == "mla":
             rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-            entries[f"p{i}"] = {
+            entries[key] = {
                 "c_vals": jnp.zeros((r, npool, t, cd.packed_dim(rkv)), STORAGE_DTYPE),
-                "c_scale": jnp.ones((r, b, rkv), jnp.float32),
-                "c_zero": jnp.zeros((r, b, rkv), jnp.float32),
+                "c_scale": jnp.ones((r, t, npool), jnp.float32),
                 "kr_vals": jnp.zeros((r, npool, t, cd.packed_dim(dr)), STORAGE_DTYPE),
-                "kr_scale": jnp.ones((r, b, dr), jnp.float32),
-                "kr_zero": jnp.zeros((r, b, dr), jnp.float32),
+                "kr_scale": jnp.ones((r, t, npool), jnp.float32),
             }
         # ssm: no sequence axis — state_pool.py owns those positions
+    if cfg.has_moe:
+        entries[STATS] = {"moe": jnp.zeros((5,), jnp.uint32)}
     return entries
 
 
 def _entry_bits(entry: Dict[str, jax.Array]) -> int:
-    """Infer the codec bitwidth from leaf shapes: a packed value leaf's last
-    dim is half its scale rows' (scales always keep the full channel dim;
-    the GQA value row holds every kv head's channels)."""
-    if "k_vals" in entry:
-        kh, d = entry["k_scale"].shape[-2:]
-        return 8 if entry["k_vals"].shape[-1] == kh * d else 4
-    return 8 if entry["c_vals"].shape[-1] == entry["c_scale"].shape[-1] else 4
+    """Infer a GQA entry's codec bitwidth from leaf shapes: a packed value
+    leaf's last dim is half its K scale rows' (scales always keep the full
+    channel dim; the value row holds every kv head's channels)."""
+    kh, d = entry["k_scale"].shape[-2:]
+    return 8 if entry["k_vals"].shape[-1] == kh * d else 4
 
 
 class BlockPoolError(RuntimeError):
@@ -687,92 +708,40 @@ def gqa_gather_prefix(entry: Dict[str, jax.Array], block_row: jax.Array,
 
 # -- MLA latent pool ---------------------------------------------------------
 
-def mla_chunk_write(entry: Dict[str, jax.Array], c_kv: jax.Array, kr: jax.Array, *,
-                    slot: jax.Array, block_row: jax.Array, ctx: jax.Array,
-                    chunk_len: jax.Array, block_size: int, is_first: bool):
-    """Quantize one chunk's latent (C, rkv) + rope key (C, dr) into the pool."""
-    cl = c_kv.shape[0]
-    bits = _entry_bits(entry)
-    qmin, qmax = int_range(bits)
-    valid = (jnp.arange(cl) < chunk_len)[:, None]
-    trash = entry["c_vals"].shape[0] - 1
-    bids, offs = _scatter_ids(block_row, ctx, chunk_len, cl, block_size, trash)
+def _store_latent(entry: Dict[str, jax.Array], bids, offs, c_kv: jax.Array,
+                  kr: jax.Array) -> Dict[str, jax.Array]:
+    """Quantize L tokens' latent (L, rkv) and rope key (L, dr) per token
+    and write codes and scales at pool blocks ``bids`` / offsets ``offs``
+    (L,).  A packed code leaf is half the latent's width."""
+    bits = 8 if entry["c_vals"].shape[-1] == c_kv.shape[-1] else 4
     new = dict(entry)
     for name, x in (("c", c_kv), ("kr", kr)):
-        if is_first:
-            big = jnp.asarray(jnp.inf, x.dtype)
-            xmin = jnp.min(jnp.where(valid, x, big), axis=0)
-            xmax = jnp.max(jnp.where(valid, x, -big), axis=0)
-            delta = jnp.maximum((xmax - xmin) / (qmax - qmin), 1e-8)
-            zero = qmin - jnp.round(xmin / delta)
-            q = jnp.clip(jnp.round(x / delta) + zero, qmin, qmax)
-            new[f"{name}_scale"] = entry[f"{name}_scale"].at[slot].set(
-                delta.astype(jnp.float32))
-            new[f"{name}_zero"] = entry[f"{name}_zero"].at[slot].set(
-                zero.astype(jnp.float32))
-        else:
-            delta = entry[f"{name}_scale"][slot]
-            zero = entry[f"{name}_zero"][slot]
-            q = jnp.clip(jnp.round(x.astype(jnp.float32) / delta) + zero,
-                         qmin, qmax)
+        q, scale = latent_rows(x, bits)
         q = pack_nibbles(q) if bits == 4 else q.astype(STORAGE_DTYPE)
         new[f"{name}_vals"] = entry[f"{name}_vals"].at[bids, offs].set(q)
+        new[f"{name}_scale"] = entry[f"{name}_scale"].at[offs, bids].set(
+            scale[:, 0])
     return new
+
+
+def mla_chunk_write(entry: Dict[str, jax.Array], c_kv: jax.Array, kr: jax.Array, *,
+                    block_row: jax.Array, ctx: jax.Array,
+                    chunk_len: jax.Array, block_size: int):
+    """Quantize one chunk's latent (C, rkv) + rope key (C, dr) into the pool."""
+    trash = entry["c_vals"].shape[0] - 1
+    bids, offs = _scatter_ids(block_row, ctx, chunk_len, c_kv.shape[0],
+                              block_size, trash)
+    return _store_latent(entry, bids, offs, c_kv, kr)
 
 
 def mla_paged_append(entry: Dict[str, jax.Array], c_t: jax.Array, kr_t: jax.Array,
                      block_tables: jax.Array, lengths: jax.Array, *,
                      block_size: int):
     """Decode append of one token's latent (B, rkv) + rope key (B, dr)."""
-    bits = _entry_bits(entry)
-    qmin, qmax = int_range(bits)
-    b = c_t.shape[0]
-    bidx = jnp.arange(b)
+    bidx = jnp.arange(c_t.shape[0])
     safe = jnp.clip(lengths // block_size, 0, block_tables.shape[1] - 1)
-    bids = block_tables[bidx, safe]
-    offs = lengths % block_size
-    new = dict(entry)
-    for name, x_t in (("c", c_t), ("kr", kr_t)):
-        scale = entry[f"{name}_scale"]               # (B, dim)
-        zero = entry[f"{name}_zero"]
-        q = jnp.clip(jnp.round(x_t.astype(jnp.float32) / scale) + zero,
-                     qmin, qmax)
-        q = pack_nibbles(q) if bits == 4 else q.astype(STORAGE_DTYPE)
-        new[f"{name}_vals"] = entry[f"{name}_vals"].at[bids, offs].set(q)
-    return new
-
-
-def mla_gather_prefix(entry: Dict[str, jax.Array], block_row: jax.Array,
-                      slot: jax.Array, dtype):
-    """Dequantize one request's cached latent prefix -> c (M*T, rkv), kr (M*T, dr)."""
-    bits = _entry_bits(entry)
-    out = []
-    for name in ("c", "kr"):
-        q = entry[f"{name}_vals"][block_row]         # (M,T,dim/pack)
-        if bits == 4:
-            q = unpack_nibbles(q)
-        m, t, dim = q.shape
-        scale = entry[f"{name}_scale"][slot]
-        zero = entry[f"{name}_zero"][slot]
-        x = ((q.astype(jnp.float32) - zero) * scale).reshape(m * t, dim)
-        out.append(x.astype(dtype))
-    return tuple(out)
-
-
-def mla_gather_batch(entry: Dict[str, jax.Array], block_tables: jax.Array):
-    """Batched gather for decode: block pool -> dense (B, M*T, ...) views plus
-    per-slot scales shaped for ``mla_decode_ref``."""
-    b, m = block_tables.shape
-    bits = _entry_bits(entry)
-    out = {}
-    for name in ("c", "kr"):
-        q = entry[f"{name}_vals"][block_tables]      # (B,M,T,dim/pack)
-        if bits == 4:
-            q = unpack_nibbles(q)
-        out[f"{name}_vals"] = q.reshape(b, m * q.shape[2], q.shape[3])
-        out[f"{name}_scale"] = entry[f"{name}_scale"][:, None]   # (B,1,dim)
-        out[f"{name}_zero"] = entry[f"{name}_zero"][:, None]
-    return out
+    return _store_latent(entry, block_tables[bidx, safe],
+                         lengths % block_size, c_t, kr_t)
 
 
 def rewind_tail(alloc: "BlockAllocator", block_row: np.ndarray,
@@ -837,16 +806,23 @@ def snapshot_slot_scales(pool, slot: int) -> Dict[str, Dict[str, jax.Array]]:
     return snap
 
 
+@jax.jit
+def _set_slot_rows(leaves, slot, rows):
+    return {pkey: {name: leaf.at[:, slot].set(rows[pkey][name])
+                   for name, leaf in entry.items()}
+            for pkey, entry in leaves.items()}
+
+
 def restore_slot_scales(pool, slot: int, snap) -> Dict[str, Any]:
     """Write a snapshot back into slot ``slot``'s scale rows (prefix hit:
     the matcher adopts the donor's frozen affine, so shared int8 blocks and
-    its own suffix chunks dequantize/quantize identically)."""
+    its own suffix chunks dequantize/quantize identically).  One jitted
+    program for every slot, over the scale leaves alone."""
+    leaves = {pkey: {name: pool[pkey][name] for name in rows}
+              for pkey, rows in snap.items()}
     out = dict(pool)
-    for pkey, leaves in snap.items():
-        new = dict(out[pkey])
-        for name, row in leaves.items():
-            new[name] = new[name].at[:, slot].set(row)
-        out[pkey] = new
+    for pkey, new in _set_slot_rows(leaves, jnp.int32(slot), snap).items():
+        out[pkey] = {**out[pkey], **new}
     return out
 
 

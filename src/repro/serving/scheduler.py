@@ -94,7 +94,8 @@ from repro.models.transformer import (forward_decode_paged,
                                       forward_verify_paged)
 from repro.serving.codec import (demote_codes, demote_pair_blocks,
                                  promote_block, promote_codes_full)
-from repro.serving.paged_cache import (BlockAllocator, PagedCacheConfig,
+from repro.models.moe import COUNTERS as MOE_COUNTERS
+from repro.serving.paged_cache import (STATS, BlockAllocator, PagedCacheConfig,
                                        copy_pool_block, init_paged_cache,
                                        paged_cache_nbytes, per_block_nbytes,
                                        per_device_nbytes, restore_slot_scales,
@@ -132,7 +133,10 @@ def _kernel_vmem_bytes(cfg: ModelConfig, scfg: "SchedulerConfig",
     """VMEM per program of each one-shot paged kernel this engine launches
     (prefix-chunk prefill, plus verify under spec decoding) at a block-table
     width of ``blocks``."""
-    from repro.kernels.paged_attention import one_shot_vmem_bytes
+    from repro.kernels.paged_attention import (mla_chunk_tile,
+                                               mla_chunk_vmem_bytes,
+                                               mla_decode_vmem_bytes,
+                                               one_shot_vmem_bytes)
     kinds = {s.mixer for s in cfg.layer_pattern}
     cols = blocks * scfg.block_size
     c = scfg.prefill_chunk
@@ -148,8 +152,10 @@ def _kernel_vmem_bytes(cfg: ModelConfig, scfg: "SchedulerConfig",
     if "mla" in kinds:
         rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         h = cfg.n_heads
-        need["mla prefix-chunk"] = one_shot_vmem_bytes(
-            c * h, cols + c, (rkv, dr), (rkv, dr, rkv))
+        need["mla prefix-chunk"] = mla_chunk_vmem_bytes(
+            mla_chunk_tile(c, h, cols + c, rkv, dr), h, cols + c, rkv, dr)
+        need["mla decode"] = mla_decode_vmem_bytes(h, scfg.block_size, rkv,
+                                                   dr)
         if g1:
             need["mla verify"] = one_shot_vmem_bytes(
                 g1 * h, cols, (rkv, dr), (rkv, dr, rkv))
@@ -523,6 +529,14 @@ class Scheduler:
         self._step_fn = _step_fn_for(cfg, scfg.block_size, mesh, rules,
                                      codec=scfg.codec)
         self._cow_fn = _shared_cow_fn()
+        if self._prefix_on:
+            # build the prefix-hit programs now (a no-op copy of the trash
+            # block, a slot's scales written back over themselves), not at
+            # the first hit mid-serve
+            self.pool = self._cow_fn(self.pool, jnp.int32(self.trash),
+                                     jnp.int32(self.trash))
+            self.pool = restore_slot_scales(
+                self.pool, 0, snapshot_slot_scales(self.pool, 0))
         # observability: tracer (no-op singleton unless injected) + the
         # always-on latency histograms metrics() summarizes
         self.trace = tracer if tracer is not None else NULL_TRACER
@@ -564,6 +578,14 @@ class Scheduler:
                       # executables built or loaded by this scheduler's
                       # steps (repro.obs.compiles)
                       "compiles": 0}
+        if STATS in self.pool:
+            # MoE layers, summed on the device over every layer call of
+            # every step (models/moe.py COUNTERS): (token, expert) pairs
+            # routed over the whole router, pairs on held experts, the
+            # busiest held expert's rows, the layer calls counted, and the
+            # held experts that had rows
+            self.stats.update({k: 0 for k in MOE_COUNTERS})
+            self._moe_read = np.zeros((len(MOE_COUNTERS),), np.uint32)
         self._consuming = 0             # ordinal of the step being consumed
         self._score_lat_sum = 0.0       # summed per-request scoring latency
         self._util_sum = 0.0
@@ -699,7 +721,7 @@ class Scheduler:
         self.stats["compiles"] += compiles.count() - c0
         return {"dec_slots": dec_slots, "vlens": vlens, "drafts": drafts,
                 "pf": pf, "pf_logits": pf_logits, "dec_logits": dec_logits,
-                "t0": t0, "t1": t1, "step": step}
+                "t0": t0, "t1": t1, "step": step, "ctx": args.get("ctx", 0)}
 
     def step_consume(self, launched: Optional[Dict[str, Any]]) -> bool:
         """Block on a ``step_launch`` context's logits and sample/retire."""
@@ -726,7 +748,8 @@ class Scheduler:
                             step=step, lanes=len(launched["vlens"]))
             elif dec_slots:
                 tr.add_span("decode_step", t1, t3 - t1, track=self.track,
-                            step=step, batch=len(dec_slots))
+                            step=step, batch=len(dec_slots),
+                            ctx=launched["ctx"])
             if pf is not None:
                 run = self.slots[pf[0]]
                 tr.add_span("prefill_chunk", t1, t3 - t1, track=self.track,
@@ -738,10 +761,19 @@ class Scheduler:
 
     def _fetch(self, x) -> np.ndarray:
         """Bring a device result to the host: the only points at which
-        ``consume`` blocks on the device."""
+        ``consume`` blocks on the device.  A MoE pool's running counters
+        ride in the same transfer."""
         with self.trace.span("device_wait", track=self.track,
                              step=self._consuming):
-            return np.asarray(x)
+            if STATS not in self.pool:
+                return np.asarray(x)
+            x, moe = jax.device_get((x, self.pool[STATS]["moe"]))
+        # uint32 totals wrap: add the difference since the last read
+        delta = (np.asarray(moe, np.uint32) - self._moe_read).astype(np.int64)
+        self._moe_read = np.asarray(moe, np.uint32)
+        for k, d in zip(MOE_COUNTERS, delta.tolist()):
+            self.stats[k] += d
+        return np.asarray(x)
 
     def run(self, max_steps: int = 10_000):
         steps = 0
@@ -1548,7 +1580,8 @@ class Scheduler:
         device = (jnp.asarray(dec_toks), jnp.asarray(dec_bt),
                   jnp.asarray(dec_lens), jnp.asarray(dec_sslots),
                   *self._build_pf_args(pf))
-        return {"device": device}
+        # summed context of the decode lanes before this step's token
+        return {"device": device, "ctx": int(dec_lens.sum())}
 
     def _count_kv_blocks(self, dec_lens: np.ndarray, appended) -> None:
         """Count the blocks a decode or verify step's lanes attend to

@@ -87,6 +87,9 @@ def spec_unsupported_reason(cfg: ModelConfig) -> Optional[str]:
         return ("SSM state is a running reduction — rejected speculative "
                 "positions would need per-position state snapshots to rewind; "
                 "serve hybrid configs with spec=None (plain paged decode)")
+    if cfg.first_k_dense_replace:
+        return ("the draft's dense cache is seeded from the scanned pool "
+                "positions only; leading layers have no draft path")
     return None
 
 

@@ -49,11 +49,12 @@ def _mla_pool(b, rkv, dr, n, t, seed=3):
     rs = np.random.RandomState(seed)
     c_vals = jnp.asarray(rs.randint(-128, 128, size=(n, t, rkv)), jnp.int8)
     kr_vals = jnp.asarray(rs.randint(-128, 128, size=(n, t, dr)), jnp.int8)
-    c_scale = jnp.asarray(rs.uniform(0.01, 0.05, size=(b, rkv)), jnp.float32)
-    c_zero = jnp.asarray(rs.uniform(-2, 2, size=(b, rkv)), jnp.float32)
-    kr_scale = jnp.asarray(rs.uniform(0.01, 0.05, size=(b, dr)), jnp.float32)
-    kr_zero = jnp.asarray(rs.uniform(-2, 2, size=(b, dr)), jnp.float32)
-    return c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero
+    return c_vals, _token_scales(rs, n, t), kr_vals, _token_scales(rs, n, t)
+
+
+def _token_scales(rs, n, t):
+    """Per-token latent scales (T, N), blocks as lanes, one value each."""
+    return jnp.asarray(rs.uniform(0.01, 0.05, size=(t, n)), jnp.float32)
 
 
 # lengths exercise: lane 0 exactly at a block boundary (gamma spans the block
@@ -199,9 +200,6 @@ def test_paged_kernels_read_blocks_past_first_scale_tile(kernel, bits):
 def test_mla_paged_prefix_chunk_attention_exact(ctx_val):
     h, rkv, dn, dr, n, t, m, c = 4, 16, 16, 8, 8, 16, 3, 12
     pool = _mla_pool(1, rkv, dr, n, t)
-    c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero = pool
-    c_scale, c_zero = c_scale[0], c_zero[0]               # slot rows (rkv,)
-    kr_scale, kr_zero = kr_scale[0], kr_zero[0]
     q_lat = jax.random.normal(KEY, (1, c, h, rkv))
     q_rope = jax.random.normal(jax.random.PRNGKey(13), (1, c, h, dr))
     c_chunk = jax.random.normal(jax.random.PRNGKey(14), (1, c, rkv))
@@ -209,8 +207,7 @@ def test_mla_paged_prefix_chunk_attention_exact(ctx_val):
     rs = np.random.RandomState(4)
     block_row = jnp.asarray(rs.randint(0, n, size=(m,)), jnp.int32)
     ctx = jnp.asarray(ctx_val, jnp.int32)
-    args = (q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, c_chunk, kr_chunk, block_row, ctx)
+    args = (q_lat, q_rope, *pool, c_chunk, kr_chunk, block_row, ctx)
     out = mla_paged_prefix_chunk_attention(*args, qk_nope_dim=dn,
                                            interpret=True)
     outr = ref.mla_paged_prefix_chunk_attention_ref(*args, qk_nope_dim=dn)
@@ -241,11 +238,7 @@ def _mla_pool_int4(b, rkv, dr, n, t, seed=6):
                                       jnp.int8))
     kr_vals = pack_nibbles(jnp.asarray(rs.randint(-8, 8, size=(n, t, dr)),
                                        jnp.int8))
-    c_scale = jnp.asarray(rs.uniform(0.01, 0.05, size=(b, rkv)), jnp.float32)
-    c_zero = jnp.asarray(rs.uniform(-2, 2, size=(b, rkv)), jnp.float32)
-    kr_scale = jnp.asarray(rs.uniform(0.01, 0.05, size=(b, dr)), jnp.float32)
-    kr_zero = jnp.asarray(rs.uniform(-2, 2, size=(b, dr)), jnp.float32)
-    return c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero
+    return c_vals, _token_scales(rs, n, t), kr_vals, _token_scales(rs, n, t)
 
 
 @pytest.mark.parametrize("b,h,kh,d,n,t,m", [(3, 8, 4, 32, 10, 16, 4),
@@ -321,9 +314,6 @@ def test_mla_paged_verify_attention_int4_exact():
 def test_mla_paged_prefix_chunk_attention_int4_exact():
     h, rkv, dn, dr, n, t, m, c = 4, 16, 16, 8, 8, 16, 3, 12
     pool = _mla_pool_int4(1, rkv, dr, n, t)
-    c_vals, c_scale, c_zero, kr_vals, kr_scale, kr_zero = pool
-    c_scale, c_zero = c_scale[0], c_zero[0]
-    kr_scale, kr_zero = kr_scale[0], kr_zero[0]
     q_lat = jax.random.normal(KEY, (1, c, h, rkv))
     q_rope = jax.random.normal(jax.random.PRNGKey(13), (1, c, h, dr))
     c_chunk = jax.random.normal(jax.random.PRNGKey(14), (1, c, rkv))
@@ -331,8 +321,7 @@ def test_mla_paged_prefix_chunk_attention_int4_exact():
     rs = np.random.RandomState(9)
     block_row = jnp.asarray(rs.randint(0, n, size=(m,)), jnp.int32)
     ctx = jnp.asarray(16, jnp.int32)
-    args = (q_lat, q_rope, c_vals, c_scale, c_zero, kr_vals, kr_scale,
-            kr_zero, c_chunk, kr_chunk, block_row, ctx)
+    args = (q_lat, q_rope, *pool, c_chunk, kr_chunk, block_row, ctx)
     out = mla_paged_prefix_chunk_attention(*args, qk_nope_dim=dn,
                                            interpret=True)
     outr = ref.mla_paged_prefix_chunk_attention_ref(*args, qk_nope_dim=dn)

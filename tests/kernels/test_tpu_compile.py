@@ -4,9 +4,12 @@ Interpret mode checks neither the TPU tiling rules nor the VMEM limit; the
 TPU compiler, which is installed here, does — for a chip that is described
 and not attached.  Widths: qwen2-0.5b (14 heads over 2 KV heads,
 head_dim 64, d_ff 4864, vocab 151936), qwen3-1.7b (16 over 8, head_dim
-128, d_model 2048, d_ff 6144) and minicpm3-4b's MLA latent (rank 256,
-rope 32, 40 heads).  The topology is described inside a fixture, never at
-import: only one process may load the TPU library at a time.
+128, d_model 2048, d_ff 6144), minicpm3-4b's MLA latent (rank 256,
+rope 32, 40 heads) and Moonlight-16B-A3B's (rank 512, rope 64, nope 128,
+16 heads; 8 held experts of width 1408 at d_model 2048; 64 slots with
+160-block tables over a 10,240-block pool).  The topology is described
+inside a fixture, never at import: only one process may load the TPU
+library at a time.
 """
 import importlib
 import math
@@ -17,6 +20,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import minicpm3_4b, qwen2_0_5b, qwen3_1_7b
+from repro.models.config import LayerSpec, ModelConfig
+from repro.models.moe import ROW_TILE
 from repro.serving import paged_cache as pgc
 from repro.serving.scheduler import (SchedulerConfig, _kernel_vmem_bytes,
                                      ensure_kernels_fit)
@@ -26,11 +31,22 @@ kd = importlib.import_module("repro.kernels.kv_decode_attention")
 pa = importlib.import_module("repro.kernels.paged_attention")
 fq = importlib.import_module("repro.kernels.fused_quant")
 wm = importlib.import_module("repro.kernels.w8a8_matmul")
+eg = importlib.import_module("repro.kernels.expert_gemm")
 
 I8, I32, F32, BF16 = jnp.int8, jnp.int32, jnp.float32, jnp.bfloat16
 B, N, T, G, C = 8, 512, 16, 5, 64        # batch, pool blocks, block, verify, chunk
 WIDTHS = {"qwen2": (14, 2, 64), "qwen3": (16, 8, 128)}   # heads, kv heads, hd
 RKV, DR, DN, HM = 256, 32, 64, 40                          # minicpm3 MLA
+MOON = dict(rkv=512, dr=64, dn=128, h=16, d=2048, f=1408, e=8, b=64, m=160,
+            n=10240 + 1, c=128)
+MOONLIGHT = ModelConfig(
+    name="moonlight-16b-a3b", vocab_size=163840, d_model=2048, n_layers=27,
+    n_heads=16, d_ff=11264, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, n_experts=8, n_routed_experts=64,
+    n_experts_active=6, moe_d_ff=1408, n_shared_experts=2,
+    moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.446,
+    capacity_factor=0.0, first_k_dense_replace=1,
+    layer_pattern=(LayerSpec("mla", "moe"),))
 
 
 @pytest.fixture(scope="module")
@@ -89,8 +105,8 @@ def _mla_verify(S, bits, m=32):
     def fn(*a):
         return pa.mla_paged_verify_attention(*a, qk_nope_dim=DN)
     return (fn, S((B, G, HM, RKV)), S((B, G, HM, DR)), S((N, T, rp), I8),
-            S((B, RKV)), S((B, RKV)), S((N, T, drp), I8), S((B, DR)),
-            S((B, DR)), S((B, m), I32), S((B,), I32))
+            S((T, N)), S((N, T, drp), I8), S((T, N)), S((B, m), I32),
+            S((B,), I32))
 
 
 def _mla_chunk(S, bits, m=32, c=32):
@@ -99,8 +115,40 @@ def _mla_chunk(S, bits, m=32, c=32):
     def fn(*a):
         return pa.mla_paged_prefix_chunk_attention(*a, qk_nope_dim=DN)
     return (fn, S((1, c, HM, RKV)), S((1, c, HM, DR)), S((N, T, rp), I8),
-            S((RKV,)), S((RKV,)), S((N, T, drp), I8), S((DR,)), S((DR,)),
-            S((1, c, RKV)), S((1, c, DR)), S((m,), I32), S((), I32))
+            S((T, N)), S((N, T, drp), I8), S((T, N)), S((1, c, RKV)),
+            S((1, c, DR)), S((m,), I32), S((), I32))
+
+
+def _moon_chunk(S, m=MOON["m"], c=MOON["c"]):
+    rkv, dr, h = MOON["rkv"], MOON["dr"], MOON["h"]
+
+    def fn(*a):
+        return pa.mla_paged_prefix_chunk_attention(*a, qk_nope_dim=MOON["dn"])
+    return (fn, S((1, c, h, rkv)), S((1, c, h, dr)), S((N, T, rkv), I8),
+            S((T, N)), S((N, T, dr), I8), S((T, N)), S((1, c, rkv)),
+            S((1, c, dr)), S((m,), I32), S((), I32))
+
+
+def _moon_decode(S, m=MOON["m"]):
+    rkv, dr, h, b, n = (MOON[k] for k in ("rkv", "dr", "h", "b", "n"))
+
+    def fn(*a):
+        return pa.mla_paged_decode_attention(*a, qk_nope_dim=MOON["dn"])
+    return (fn, S((b, h, rkv)), S((b, h, dr)), S((n, T, rkv), I8),
+            S((T, n)), S((n, T, dr), I8), S((T, n)), S((b, m), I32),
+            S((b,), I32))
+
+
+def _expert(S, tokens, k, n):
+    """The grouped GEMM over a step's rows: every (token, expert) pair of
+    top-6 may land here, each held expert's group padded to the tile."""
+    e = MOON["e"]
+    r = ROW_TILE * -(-(tokens * 6 + e * (ROW_TILE - 1)) // ROW_TILE)
+
+    def fn(*a):
+        return eg.expert_gemm(*a, tm=ROW_TILE)
+    return (fn, S((r, k), I8), S((r, 1)), S((e, k, n), I8), S((e, 1, n)),
+            S((r // ROW_TILE,), I32), S((1,), I32))
 
 
 def _dense_decode(S, model, s=517):
@@ -135,6 +183,11 @@ CASES = {
     "w8a8_decode_qwen2_down": (_gemm, 8, 4864, 896),
     "w8a8_prefill_qwen2_vocab": (_gemm, 512, 896, 151936),
     "w8a8_decode_qwen3_up": (_gemm, 8, 2048, 6144),
+    "expert_gemm_moonlight_decode_up": (_expert, 64, 2048, 1408),
+    "expert_gemm_moonlight_decode_down": (_expert, 64, 1408, 2048),
+    "expert_gemm_moonlight_prefill_up": (_expert, 128, 2048, 1408),
+    "mla_decode_moonlight": (_moon_decode,),
+    "mla_prefix_chunk_moonlight": (_moon_chunk,),
 }
 
 
@@ -154,11 +207,16 @@ LINE = [
     ("prefix-chunk", qwen3_1_7b.FULL, dict(prefill_chunk=128), 256, 512),
     ("verify", qwen2_0_5b.FULL,
      dict(prefill_chunk=16, spec=SpecConfig(gamma=4)), 512, 1024),
-    ("mla prefix-chunk", minicpm3_4b.FULL, dict(prefill_chunk=32), 64, 128),
+    # the MLA chunk kernel splits its query rows into tiles that fit, so
+    # its line is where one chunk position's rows no longer do
+    ("mla prefix-chunk", minicpm3_4b.FULL, dict(prefill_chunk=32), 256, 1024),
+    ("mla prefix-chunk", MOONLIGHT, dict(prefill_chunk=128), 160, 512),
 ]
 
 
 def _launch(kernel, cfg, scfg, blocks, S):
+    if kernel == "mla prefix-chunk" and cfg is MOONLIGHT:
+        return _moon_chunk(S, blocks, scfg.prefill_chunk)
     if kernel == "mla prefix-chunk":
         return _mla_chunk(S, 8, blocks, scfg.prefill_chunk)
     model = "qwen2" if cfg.hd == 64 else "qwen3"
@@ -185,6 +243,20 @@ def test_vmem_guard_agrees_with_compiler(kernel, cfg, fields, ok_blocks,
                 ensure_kernels_fit(cfg, scfg)
             with pytest.raises(Exception, match="(?i)vmem"):
                 jax.jit(fn).lower(*shapes).compile()
+
+
+def test_mla_decode_guard_agrees_with_compiler(shape):
+    """The MLA decode kernel streams one block at a time: the engine's
+    estimate does not grow with the table, lies under the limit, and the
+    compiler takes the cell's table and one twice as long."""
+    needs = set()
+    for blocks in (MOON["m"], 2 * MOON["m"]):
+        scfg = SchedulerConfig(block_size=T, max_blocks_per_req=blocks,
+                               max_batch=MOON["b"], prefill_chunk=16)
+        needs.add(_kernel_vmem_bytes(MOONLIGHT, scfg, blocks)["mla decode"])
+        fn, *shapes = _moon_decode(shape, blocks)
+        jax.jit(fn).lower(*shapes).compile()
+    assert len(needs) == 1 and needs.pop() <= pa.VMEM_LIMIT_BYTES
 
 
 # One scanned layer body of the paged step at qwen2-0.5b batch-decode's
