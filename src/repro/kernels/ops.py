@@ -34,7 +34,8 @@ from . import ref
 from .fused_quant import fused_quant
 from .w8a8_matmul import w8a8_matmul
 from .expert_gemm import expert_gemm as _expert_gemm
-from .kv_decode_attention import kv_decode_attention, paged_kv_decode_attention
+from .kv_decode_attention import (decode_blocks_per_step, kv_decode_attention,
+                                  paged_kv_decode_attention)
 from . import paged_attention as pa
 
 
@@ -195,6 +196,18 @@ def paged_decode_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale, v_zero,
     return ref.paged_kv_decode_attention_ref(q, k_vals, k_scale, k_zero,
                                              v_vals, v_scale, v_zero,
                                              block_tables, lengths)
+
+
+def paged_decode_blocks_per_step(heads: int, kv_heads: int, dp: int, t: int,
+                                 m: int) -> int:
+    """Blocks P that the paged decode launch of ``paged_decode_attention``
+    scores a step, for these shapes under the active mesh: a kv-head split
+    hands each device's launch the code rows of its own heads."""
+    from repro.distributed import sharding as shd
+    ax = _split_axis("kv_heads", kv_heads, heads)
+    axes = () if ax is None else ax if isinstance(ax, tuple) else (ax,)
+    n = int(np.prod([shd.active_mesh().shape[a] for a in axes]))
+    return decode_blocks_per_step(t, kv_heads // n * dp, m)
 
 
 def paged_verify_attention(q, k_vals, k_scale, k_zero, v_vals, v_scale,

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kv_decode_attention import codes_f32, pick_lane, scale_lanes
+from .kv_decode_attention import (codes_f32, decode_blocks_per_step, pick_lane,
+                                  scale_lanes)
 
 NEG_INF = -2.0e38
 
@@ -134,6 +135,37 @@ def one_shot_vmem_bytes(rows: int, cols: int, scratch_widths,
     return (sum(_tile_bytes(cols, w) for w in scratch_widths)
             + _tile_bytes(rows, cols)
             + 2 * sum(_tile_bytes(rows, w) for w in io_widths))
+
+
+def decode_vmem_bytes(kv_heads: int, group: int, d: int, t: int, row: int,
+                      m: int, n: int) -> int:
+    """VMEM of one ``paged_kv_decode_attention`` launch over an ``n``-block
+    pool of (``t``, ``row``)-byte code blocks and ``m``-block tables, held
+    to ``DECODE_VMEM_LIMIT_BYTES`` by the engine (``Scheduler``).  The
+    group walk keeps the layer's V scale and zero leaves whole, one buffer
+    each, so it grows with the pool; besides them, two slots of P blocks of
+    K and V codes and their f32 staging.  The one-block walk of narrow rows
+    streams code and scale tiles through the pipeline's two buffers
+    instead.  Live in either: the dequantized (P*T, KH*D) K and V, one
+    head's sliced K and V, scores and probabilities, and for packed int4
+    the nibble unpack's lane-padded columns.  tests/kernels/
+    test_tpu_compile.py holds it to the compiler's verdict."""
+    p = decode_blocks_per_step(t, row, m)
+    rows = p * t
+    io = 2 * (2 * kv_heads * _tile_bytes(group, d)       # q, out
+              + 2 * _tile_bytes(kv_heads, d))             # K scale, zero
+    scratch = kv_heads * (2 * _tile_bytes(group, 1) + _tile_bytes(group, d))
+    live = (2 * _tile_bytes(rows, kv_heads * d) + 4 * _tile_bytes(rows, d)
+            + 2 * _tile_bytes(group, rows))
+    if row != kv_heads * d:
+        live += 2 * 4 * t * row * 128
+    if row % 128:
+        tiles = 2 * _tile_bytes(t, row) + 2 * kv_heads * _tile_bytes(t, 128)
+        return io + scratch + live + 2 * tiles
+    leaves = 2 * kv_heads * _tile_bytes(t, n)
+    codes = 2 * 2 * rows * row
+    return (io + scratch + live + leaves + codes
+            + 2 * _tile_bytes(rows, kv_heads * d))
 
 
 # ---------------------------------------------------------------------------

@@ -93,7 +93,7 @@ from repro.models.transformer import (forward_decode_paged,
                                       forward_prefill_chunk,
                                       forward_verify_paged)
 from repro.serving.codec import (demote_codes, demote_pair_blocks,
-                                 promote_block, promote_codes_full)
+                                 get_codec, promote_block, promote_codes_full)
 from repro.models.moe import COUNTERS as MOE_COUNTERS
 from repro.serving.paged_cache import (STATS, BlockAllocator, PagedCacheConfig,
                                        copy_pool_block, init_paged_cache,
@@ -162,11 +162,59 @@ def _kernel_vmem_bytes(cfg: ModelConfig, scfg: "SchedulerConfig",
     return need
 
 
+def _decode_vmem_bytes(cfg: ModelConfig, scfg: "SchedulerConfig",
+                       num_blocks: int) -> int:
+    """VMEM of the GQA paged decode launch over a pool of ``num_blocks``
+    blocks and the trash block (0 without GQA layers): it holds a layer's
+    V scale and zero leaves whole, so it grows with the pool."""
+    if "attn" not in {s.mixer for s in cfg.layer_pattern}:
+        return 0
+    from repro.kernels.paged_attention import decode_vmem_bytes
+    kh, d = cfg.kv_heads, cfg.hd
+    return decode_vmem_bytes(kh, cfg.n_heads // kh, d, scfg.block_size,
+                             kh * get_codec(scfg.codec).packed_dim(d),
+                             scfg.max_blocks_per_req, num_blocks + 1)
+
+
+def _decode_blocks_per_step(cfg: ModelConfig, scfg: "SchedulerConfig",
+                            mesh, rules) -> int:
+    """Blocks P that a decode step's attention launch scores together: the
+    GQA kernel's own choice for the model's shapes, per device under a
+    mesh; 1 for the MLA decode kernel, which walks one block a step."""
+    if "attn" not in {s.mixer for s in cfg.layer_pattern}:
+        return 1
+    from repro.kernels.ops import paged_decode_blocks_per_step
+    args = (cfg.n_heads, cfg.kv_heads,
+            get_codec(scfg.codec).packed_dim(cfg.hd), scfg.block_size,
+            scfg.max_blocks_per_req)
+    if mesh is None:
+        return paged_decode_blocks_per_step(*args)
+    with shd.axis_rules(mesh, rules):
+        return paged_decode_blocks_per_step(*args)
+
+
 def ensure_kernels_fit(cfg: ModelConfig, scfg: "SchedulerConfig") -> None:
-    """Refuse, at engine build, a block table the verify / prefix-chunk
-    kernels cannot buffer in VMEM — the TPU compiler would otherwise reject
-    the first step that launches them, mid-serve."""
+    """Refuse, at engine build, a pool the decode kernel or a block table
+    the verify / prefix-chunk kernels cannot buffer in VMEM — the TPU
+    compiler would otherwise reject the first step that launches them,
+    mid-serve."""
+    from repro.kernels.kv_decode_attention import DECODE_VMEM_LIMIT_BYTES
     from repro.kernels.paged_attention import VMEM_LIMIT_BYTES
+    nbytes = _decode_vmem_bytes(cfg, scfg, scfg.num_blocks)
+    if nbytes > DECODE_VMEM_LIMIT_BYTES:
+        lo, hi = 0, scfg.num_blocks
+        while lo < hi:
+            mid = (lo + hi + 1) // 2
+            if _decode_vmem_bytes(cfg, scfg, mid) <= DECODE_VMEM_LIMIT_BYTES:
+                lo = mid
+            else:
+                hi = mid - 1
+        raise ValueError(
+            f"{cfg.name}: num_blocks={scfg.num_blocks} needs "
+            f"{nbytes / 2**20:.1f} MiB of VMEM in the decode attention "
+            f"kernel, which holds a layer's V scale and zero leaves whole, "
+            f"over the {DECODE_VMEM_LIMIT_BYTES / 2**20:.0f} MiB it may "
+            f"use; at most num_blocks={lo} fits — lower num_blocks")
     need = _kernel_vmem_bytes(cfg, scfg, scfg.max_blocks_per_req)
     over = {k: v for k, v in need.items() if v > VMEM_LIMIT_BYTES}
     if not over:
@@ -528,6 +576,7 @@ class Scheduler:
         self.scale_state = EmaScaleState.init()
         self._step_fn = _step_fn_for(cfg, scfg.block_size, mesh, rules,
                                      codec=scfg.codec)
+        self._decode_p = _decode_blocks_per_step(cfg, scfg, mesh, rules)
         self._cow_fn = _shared_cow_fn()
         if self._prefix_on:
             # build the prefix-hit programs now (a no-op copy of the trash
@@ -571,10 +620,12 @@ class Scheduler:
                       "snap_promotions": 0, "state_prefix_hits": 0,
                       "score_requests": 0, "score_tokens": 0,
                       # per decode or verify step: block-table entries that
-                      # hold KV the step attends to, and entries handed to
-                      # the paged kernel
+                      # hold KV the step attends to, entries handed to the
+                      # paged kernel, and the kernel steps that score the
+                      # live ones, P blocks a step
                       "decode_kv_blocks_live": 0,
                       "decode_kv_blocks_passed": 0,
+                      "decode_kv_steps_live": 0,
                       # executables built or loaded by this scheduler's
                       # steps (repro.obs.compiles)
                       "compiles": 0}
@@ -1575,7 +1626,7 @@ class Scheduler:
         if dec_slots:
             # the kernel attends each lane's ctx tokens plus the one it
             # appends
-            self._count_kv_blocks(dec_lens, 1)
+            self._count_kv_blocks(dec_lens, 1, self._decode_p)
 
         device = (jnp.asarray(dec_toks), jnp.asarray(dec_bt),
                   jnp.asarray(dec_lens), jnp.asarray(dec_sslots),
@@ -1583,16 +1634,20 @@ class Scheduler:
         # summed context of the decode lanes before this step's token
         return {"device": device, "ctx": int(dec_lens.sum())}
 
-    def _count_kv_blocks(self, dec_lens: np.ndarray, appended) -> None:
+    def _count_kv_blocks(self, dec_lens: np.ndarray, appended,
+                         p: int) -> None:
         """Count the blocks a decode or verify step's lanes attend to
         (``ceil((ctx + appended) / block_size)`` over the decoding lanes;
         idle lanes have ctx 0 and append nothing) against the block-table
-        entries handed to the paged kernel."""
+        entries handed to the paged kernel, and the kernel steps that visit
+        them when it scores ``p`` blocks a step (``ceil(live / p)`` a
+        lane)."""
         bs = self.scfg.block_size
         live = np.where(dec_lens > 0, dec_lens + appended + bs - 1, 0) // bs
         self.stats["decode_kv_blocks_live"] += int(live.sum())
         self.stats["decode_kv_blocks_passed"] += \
             self.scfg.max_batch * self.scfg.max_blocks_per_req
+        self.stats["decode_kv_steps_live"] += int((-(-live // p)).sum())
 
     def _build_pf_args(self, pf):
         """Device args for the prefill half of a fused step (shared by the
@@ -1637,7 +1692,8 @@ class Scheduler:
             dec_bt[s] = self.block_tables[s]
             dec_lens[s] = run.ctx
             dec_vlens[s] = vlens[s]
-        self._count_kv_blocks(dec_lens, dec_vlens)
+        # the verify kernels walk one block a step
+        self._count_kv_blocks(dec_lens, dec_vlens, 1)
         device = (jnp.asarray(dec_toks), jnp.asarray(dec_bt),
                   jnp.asarray(dec_lens), jnp.asarray(dec_vlens),
                   *self._build_pf_args(pf))

@@ -9,7 +9,8 @@ from repro.core.methods.simquant import quantize_kv
 from repro.core.qtensor import quantize_symmetric
 from repro.kernels import ref
 from repro.kernels.fused_quant import fused_quant
-from repro.kernels.kv_decode_attention import (kv_decode_attention,
+from repro.kernels.kv_decode_attention import (decode_blocks_per_step,
+                                               kv_decode_attention,
                                                paged_kv_decode_attention)
 from repro.kernels.w8a8_matmul import w8a8_matmul
 
@@ -76,12 +77,17 @@ def test_kv_decode_attention_sweep(b, s, h, kh, d, chunk):
 @pytest.mark.parametrize("b,h,kh,d,n,t,m", [(2, 8, 4, 32, 10, 16, 4),
                                             (3, 4, 1, 64, 6, 8, 3),
                                             (1, 6, 2, 16, 5, 4, 5),
-                                            (3, 4, 2, 16, 300, 4, 6)])
+                                            (3, 4, 2, 16, 300, 4, 6),
+                                            (3, 14, 2, 64, 60, 16, 20),
+                                            (4, 8, 4, 32, 310, 16, 24),
+                                            (2, 4, 1, 128, 40, 8, 40)])
 def test_paged_kv_decode_attention_sweep(b, h, kh, d, n, t, m):
     """Gather-by-block-table Pallas kernel vs the dense-gather oracle, on the
     stored pool layout: code rows (N, T, KH*D), V affine (KH, T, N) whose
-    lanes are the blocks (n = 300 spans three 128-lane tiles, the last one
-    partial)."""
+    lanes are the blocks (n = 300 and 310 span three 128-lane tiles, the
+    last one partial).  Rows of 128 bytes take the group walk: 16 blocks a
+    step over tables of 20 and 24 entries (not a multiple of 16), 32 of 8
+    tokens over 40."""
     q = jax.random.normal(KEY, (b, h, d))
     k_pool = jax.random.normal(jax.random.PRNGKey(1), (1, n * t, kh, d))
     v_pool = jax.random.normal(jax.random.PRNGKey(2), (1, n * t, kh, d))
@@ -105,6 +111,38 @@ def test_paged_kv_decode_attention_sweep(b, h, kh, d, n, t, m):
                                              tables, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr),
                                rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("lengths", [(1, 255, 256, 257),
+                                     (100, 0, 300, 17),
+                                     (0, 320, 0, 511)])
+def test_paged_kv_decode_attention_group_edges(lengths):
+    """The group walk at its edges, 16 blocks of 16 tokens a step over
+    20-entry tables: lanes of 1, P*T - 1, P*T and P*T + 1 tokens, lanes
+    whose live blocks end mid-group (7 of 16; 19 = 16 + 3), and idle lanes
+    (0 tokens, which read zeros as the one-block walk does), first and
+    between lanes whose copies run ahead across them."""
+    b, h, kh, d, n, t, m = 4, 14, 2, 64, 90, 16, 20
+    assert decode_blocks_per_step(t, kh * d, m) == 16
+    q = jax.random.normal(KEY, (b, h, d))
+    k_pool = jax.random.normal(jax.random.PRNGKey(1), (1, n * t, kh, d))
+    v_pool = jax.random.normal(jax.random.PRNGKey(2), (1, n * t, kh, d))
+    qk, qv = quantize_kv(k_pool, v_pool)
+    k_scale = jnp.broadcast_to(qk.scale[0], (b, kh, d))
+    k_zero = jnp.broadcast_to(qk.zero[0], (b, kh, d))
+    pool = (qk.values.reshape(n, t, kh * d), k_scale, k_zero,
+            qv.values.reshape(n, t, kh * d), qv.scale.reshape(n, t, kh).T,
+            qv.zero.reshape(n, t, kh).T)
+    rs = np.random.RandomState(3)
+    tables = jnp.asarray(rs.permutation(n)[:b * m].reshape(b, m), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    out = np.asarray(paged_kv_decode_attention(q, *pool, tables, lengths,
+                                               interpret=True))
+    outr = np.asarray(ref.paged_kv_decode_attention_ref(q, *pool, tables,
+                                                        lengths))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(out[live], outr[live], rtol=3e-5, atol=3e-5)
+    np.testing.assert_array_equal(out[~live], 0.0)
 
 
 def test_kv_decode_quantization_fidelity():

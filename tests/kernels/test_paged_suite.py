@@ -196,6 +196,32 @@ def test_paged_kernels_read_blocks_past_first_scale_tile(kernel, bits):
     np.testing.assert_array_equal(out, np.asarray(run(kv, tables)))
 
 
+@pytest.mark.parametrize("bits", [8, 4])
+def test_paged_decode_never_reads_dead_table_entries(bits):
+    """Table entries past a lane's last live block point at blocks of
+    random codes whose V scale and zero are NaN: the decode kernel's group
+    walk (128-byte code rows, 16 blocks a step) fetches no dead group, so
+    the output is finite and bitwise the output with those entries at
+    block 0.  The 310-block pool puts live blocks in every 128-lane tile of
+    the V affine leaves."""
+    b, h, kh, n, t, m = 4, 8, 4, 310, 16, 40
+    d = 32 if bits == 8 else 64                 # 128-byte rows either way
+    kv = (_gqa_pool if bits == 8 else _gqa_pool_int4)(b, kh, d, n, t)
+    assert kv[0].shape[-1] == 128
+    poison = np.arange(n, n + 8)            # random codes, NaN V affine
+    pool = _relocate(kv, jnp.arange(n), n + 8)
+    lengths = jnp.asarray([1, 255, 257, 600], jnp.int32)
+    rs = np.random.RandomState(12)
+    live = rs.randint(0, n, size=(b, m))
+    dead = np.arange(m)[None, :] * t >= np.asarray(lengths)[:, None]
+    q = jax.random.normal(KEY, (b, h, d))
+    run = lambda tb: np.asarray(paged_kv_decode_attention(
+        q, *pool, jnp.asarray(tb, jnp.int32), lengths, interpret=True))
+    out = run(np.where(dead, rs.choice(poison, size=(b, m)), live))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, run(np.where(dead, 0, live)))
+
+
 @pytest.mark.parametrize("ctx_val", [5, 16, 44])
 def test_mla_paged_prefix_chunk_attention_exact(ctx_val):
     h, rkv, dn, dr, n, t, m, c = 4, 16, 16, 8, 8, 16, 3, 12
@@ -242,7 +268,8 @@ def _mla_pool_int4(b, rkv, dr, n, t, seed=6):
 
 
 @pytest.mark.parametrize("b,h,kh,d,n,t,m", [(3, 8, 4, 32, 10, 16, 4),
-                                            (2, 6, 2, 16, 5, 4, 5)])
+                                            (2, 6, 2, 16, 5, 4, 5),
+                                            (3, 8, 4, 64, 40, 16, 20)])
 def test_paged_decode_attention_int4_exact(b, h, kh, d, n, t, m):
     q = jax.random.normal(KEY, (b, h, d))
     kv = _gqa_pool_int4(b, kh, d, n, t)

@@ -4,7 +4,8 @@ Interpret mode checks neither the TPU tiling rules nor the VMEM limit; the
 TPU compiler, which is installed here, does — for a chip that is described
 and not attached.  Widths: qwen2-0.5b (14 heads over 2 KV heads,
 head_dim 64, d_ff 4864, vocab 151936), qwen3-1.7b (16 over 8, head_dim
-128, d_model 2048, d_ff 6144), minicpm3-4b's MLA latent (rank 256,
+128, d_model 2048, d_ff 6144), the GQA decode cells' slots, tables and
+pools, minicpm3-4b's MLA latent (rank 256,
 rope 32, 40 heads) and Moonlight-16B-A3B's (rank 512, rope 64, nope 128,
 16 heads; 8 held experts of width 1408 at d_model 2048; 64 slots with
 160-block tables over a 10,240-block pool).  The topology is described
@@ -23,8 +24,8 @@ from repro.configs import minicpm3_4b, qwen2_0_5b, qwen3_1_7b
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.moe import ROW_TILE
 from repro.serving import paged_cache as pgc
-from repro.serving.scheduler import (SchedulerConfig, _kernel_vmem_bytes,
-                                     ensure_kernels_fit)
+from repro.serving.scheduler import (SchedulerConfig, _decode_vmem_bytes,
+                                     _kernel_vmem_bytes, ensure_kernels_fit)
 from repro.serving.spec_decode import SpecConfig
 
 kd = importlib.import_module("repro.kernels.kv_decode_attention")
@@ -73,17 +74,18 @@ def shape(topo):
                                                      sharding=one_chip)
 
 
-def _gqa_pool(S, kh, d, bits, per_slot):
+def _gqa_pool(S, kh, d, bits, per_slot, b=B, n=N):
     dp = d if bits == 8 else d // 2
-    scale = (B, kh, d) if per_slot else (kh, d)
-    return (S((N, T, kh * dp), I8), S(scale), S(scale),
-            S((N, T, kh * dp), I8), S((kh, T, N)), S((kh, T, N)))
+    scale = (b, kh, d) if per_slot else (kh, d)
+    return (S((n, T, kh * dp), I8), S(scale), S(scale),
+            S((n, T, kh * dp), I8), S((kh, T, n)), S((kh, T, n)))
 
 
-def _decode(S, model, bits, m=32):
+def _decode(S, model, bits, m=32, b=B, n=N):
     h, kh, d = WIDTHS[model]
-    return (kd.paged_kv_decode_attention, S((B, h, d)),
-            *_gqa_pool(S, kh, d, bits, True), S((B, m), I32), S((B,), I32))
+    return (kd.paged_kv_decode_attention, S((b, h, d)),
+            *_gqa_pool(S, kh, d, bits, True, b, n), S((b, m), I32),
+            S((b,), I32))
 
 
 def _verify(S, model, bits, m=32):
@@ -167,9 +169,15 @@ def _gemm(S, m, k, n):
             S((1, n)))
 
 
+# the decode cells' launches: slots, table width and pool blocks (+ trash)
+DECODE_CELLS = {"qwen2_batch_decode": ("qwen2", 32, 256, 8193),
+                "qwen3_long_prompt": ("qwen3", 6, 256, 1537)}
+
 CASES = {
     **{f"paged_decode_{mo}_int{b}": (_decode, mo, b)
        for mo in WIDTHS for b in (8, 4)},
+    **{f"paged_decode_{c}_int{bits}": (_decode, mo, bits, m, b, n)
+       for c, (mo, b, m, n) in DECODE_CELLS.items() for bits in (8, 4)},
     **{f"verify_{mo}_int{b}": (_verify, mo, b)
        for mo in WIDTHS for b in (8, 4)},
     **{f"prefix_chunk_{mo}_int{b}": (_chunk, mo, b)
@@ -257,6 +265,39 @@ def test_mla_decode_guard_agrees_with_compiler(shape):
         fn, *shapes = _moon_decode(shape, blocks)
         jax.jit(fn).lower(*shapes).compile()
     assert len(needs) == 1 and needs.pop() <= pa.VMEM_LIMIT_BYTES
+
+
+# The GQA decode kernel holds a layer's V scale and zero leaves whole, so
+# its line is in pool blocks: the engine's estimate and the compiler agree
+# on a pool just under it and one just over it, at each decode cell's
+# widths and slots.
+DECODE_LINE = [
+    # config, slots, pool blocks accepted, pool blocks refused
+    (qwen2_0_5b.FULL, 32, 250_000, 262_144),
+    (qwen3_1_7b.FULL, 6, 58_000, 61_440),
+]
+
+
+@pytest.mark.parametrize("cfg,slots,ok_blocks,bad_blocks", DECODE_LINE,
+                         ids=[c.name for c, *_ in DECODE_LINE])
+def test_decode_guard_agrees_with_compiler(cfg, slots, ok_blocks,
+                                           bad_blocks, shape):
+    model = "qwen2" if cfg.hd == 64 else "qwen3"
+    for blocks, fits in ((ok_blocks, True), (bad_blocks, False)):
+        scfg = SchedulerConfig(block_size=T, num_blocks=blocks,
+                               max_batch=slots, max_blocks_per_req=256,
+                               prefill_chunk=16)
+        need = _decode_vmem_bytes(cfg, scfg, blocks)
+        assert (need <= kd.DECODE_VMEM_LIMIT_BYTES) == fits, (blocks, need)
+        fn, *shapes = _decode(shape, model, 8, 256, slots, blocks + 1)
+        if fits:
+            ensure_kernels_fit(cfg, scfg)
+            jax.jit(fn).lower(*shapes).compile()
+        else:
+            with pytest.raises(ValueError, match="num_blocks"):
+                ensure_kernels_fit(cfg, scfg)
+            with pytest.raises(Exception, match="(?i)vmem"):
+                jax.jit(fn).lower(*shapes).compile()
 
 
 # One scanned layer body of the paged step at qwen2-0.5b batch-decode's

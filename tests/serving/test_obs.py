@@ -133,6 +133,28 @@ def test_decode_kv_block_counters_match_a_hand_count():
     assert st["prefill_chunks"] == 2 and st["decode_steps"] == 4
     assert st["decode_kv_blocks_live"] == 10
     assert st["decode_kv_blocks_passed"] == 4 * 4 * 8
+    # head_dim 16: 32-byte code rows take the decode kernel's one-block walk
+    assert st["decode_kv_steps_live"] == 10
+
+
+@pytest.mark.parametrize("hd,p", [(64, 16), (16, 1)])
+def test_decode_kv_steps_live_match_a_hand_count(hd, p):
+    """The decode kernel scores P blocks a step, P from the shapes: 16 for
+    two kv heads of 64 (128-byte code rows) under 16-block tables, 1 for
+    32-byte rows.  Lanes at ctx 0, 15, 255 and 300 attend to ctx + 1
+    tokens: 0, 1, 16 and 19 blocks, so 0 + 1 + 1 + 2 = 4 steps of 16."""
+    cfg = ModelConfig(name=f"t-steps{hd}", vocab_size=128, d_model=4 * hd,
+                      n_layers=1, n_heads=4, n_kv_heads=2, d_ff=128,
+                      attn_chunk=16)
+    sch = PagedServeEngine(init_params(cfg, KEY), cfg,
+                           _scfg(num_blocks=64, max_blocks_per_req=16)
+                           ).scheduler
+    assert sch._decode_p == p
+    sch._count_kv_blocks(np.array([0, 15, 255, 300], np.int32), 1,
+                         sch._decode_p)
+    st = sch.stats
+    assert st["decode_kv_blocks_live"] == 36
+    assert st["decode_kv_steps_live"] == {16: 4, 1: 36}[p]
 
 
 def test_scheduler_counts_the_compiles_of_its_steps():

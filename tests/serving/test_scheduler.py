@@ -1,5 +1,7 @@
 """Paged scheduler: golden parity vs the dense engine, chunked prefill,
 preemption, sampling and engine-frontend behaviour."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +12,8 @@ from repro.models.config import LayerSpec
 from repro.serving.engine import (EngineConfig, PagedServeEngine, Request,
                                   ServeEngine)
 from repro.serving.kv_cache import cache_nbytes
-from repro.serving.scheduler import SchedulerConfig, _chunk_bucket
+from repro.serving.scheduler import (SchedulerConfig, _chunk_bucket,
+                                     ensure_kernels_fit)
 
 CFG = ModelConfig(name="t", vocab_size=128, d_model=64, n_layers=2, n_heads=4,
                   n_kv_heads=2, d_ff=128, attn_chunk=16)
@@ -124,6 +127,26 @@ def test_oversized_request_rejected_with_clear_error():
     with pytest.raises(ValueError, match="paged cache capacity"):
         eng.add_request(Request(uid=0, prompt=np.arange(40, dtype=np.int32) % 128,
                                 max_new_tokens=8))
+
+
+def test_pool_past_the_decode_kernels_vmem_refused_at_build():
+    """The GQA decode kernel holds a layer's V scale and zero leaves whole
+    in VMEM (128-byte code rows: two kv heads of 64), so a pool past its
+    line is refused when the engine is built, before any pool exists, and
+    the error names the largest pool that fits."""
+    cfg = ModelConfig(name="t-wide", vocab_size=128, d_model=256,
+                      n_layers=1, n_heads=4, n_kv_heads=2, d_ff=128,
+                      attn_chunk=16)
+    kw = dict(block_size=16, max_batch=4, max_blocks_per_req=8,
+              prefill_chunk=64, token_budget=128)
+    with pytest.raises(ValueError, match="decode attention") as err:
+        PagedServeEngine(init_params(cfg, KEY), cfg,
+                         SchedulerConfig(num_blocks=400_000, **kw))
+    fit = int(re.search(r"at most num_blocks=(\d+)", str(err.value))[1])
+    assert 100_000 < fit < 400_000
+    ensure_kernels_fit(cfg, SchedulerConfig(num_blocks=fit, **kw))
+    with pytest.raises(ValueError, match="decode attention"):
+        ensure_kernels_fit(cfg, SchedulerConfig(num_blocks=fit + 1, **kw))
 
 
 def test_streaming_callback_and_metrics():
